@@ -10,6 +10,13 @@ The `tta` suite additionally writes a ``BENCH_fed.json`` artifact
 ``dispatch`` section's sync AND async scan-vs-loop engine speedups) so
 the perf trajectory is tracked across PRs.
 
+The persistent compile cache is on (``repro.launch.compile_cache``): a
+run that finds its programs in the cache loads them instead of compiling,
+so the artifact's first-call fields (``scan_first_call_seconds``,
+``sweep_first_call_seconds``, ``grid_first_call_seconds``, the profile's
+``first_call_compile_s``) measure a compile only on a cold cache.  Point
+``JAX_COMPILATION_CACHE_DIR`` at an empty directory to measure compiles.
+
 NEVER run this concurrently with pytest or another bench in the same
 container: CPU contention collapses the CI-gated speedup ratios.
 """
@@ -31,6 +38,8 @@ def main() -> None:
                     help="path of the cross-PR perf artifact")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (dispatch_bench, fleet_scale, kernel_bench,
                             paper_tables, resilience, roofline,
                             scenario_matrix, time_to_accuracy)

@@ -41,20 +41,42 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-TILE_D = 1024        # lane-aligned (128 x 8) minimum streaming tile
-_MAX_TILE_D = 1 << 15   # (K, 32768) fp32 block ≈ 1.3 MB VMEM at K = 10
+TILE_D = 1024        # D padding unit: 8 lane-widths, the usual tile
+_MAX_TILE_D = 1 << 15   # longest tile; longer ones buy no bandwidth
+_MIN_TILE_D = 128       # one lane-width: the floor when K is very large
+# VMEM for the double-buffered (K, tile) input blocks of one kernel: half
+# of v5e's 16 MiB default scoped VMEM, leaving the rest for the (1, tile)
+# parameter/output blocks and the compiler's scratch.  The described-v5e
+# compiles in tests/test_tpu_compile.py pin that this fits.
+_VMEM_BLOCK_BUDGET = 8 << 20
 _INTERPRET_MAX_GRID = 512   # interpret mode unrolls the grid at trace time
 
 
-def _pick_tile(D: int) -> int:
-    """Largest power-of-two multiple of TILE_D that divides D, keeps the
-    grid reasonably short, and fits the VMEM working-set budget."""
+def _pick_tile(D: int, K: int, itemsize: int, n_streams: int = 1) -> int:
+    """Tile length along D for a kernel streaming ``n_streams`` ``(K, D)``
+    inputs of ``itemsize`` bytes per element.
+
+    The largest power-of-two multiple of TILE_D that divides D, keeps the
+    grid longer than 256 steps, and keeps the double-buffered input
+    blocks — K padded to the dtype's sublane tile (8 rows of 4 bytes) —
+    within ``_VMEM_BLOCK_BUDGET``.  At K ≤ 16 every dtype and stream
+    count gets the 32768-lane cap; larger K shrinks the tile, down to one
+    lane-width below TILE_D when K is in the hundreds.
+    """
+    rows_per_tile = 32 // itemsize
+    rows = -(-K // rows_per_tile) * rows_per_tile
+
+    def fits(t):
+        return n_streams * 2 * rows * t * itemsize <= _VMEM_BLOCK_BUDGET
+
     t = TILE_D
-    while t < _MAX_TILE_D and D % (2 * t) == 0 and D // t > 256:
+    while (t < _MAX_TILE_D and D % (2 * t) == 0 and D // t > 256
+           and fits(2 * t)):
         t *= 2
+    while t > _MIN_TILE_D and not fits(t):
+        t //= 2
     return t
 
 
@@ -111,7 +133,7 @@ def folb_scores(grads: jnp.ndarray, g1: jnp.ndarray,
     semantics (different reduction order only).
     """
     K, D = grads.shape
-    tile = _pick_tile(D)
+    tile = _pick_tile(D, K, grads.dtype.itemsize)
     assert D % tile == 0, (D, tile)
     if interpret and D // tile > _INTERPRET_MAX_GRID:
         return jnp.einsum("kd,d->k", grads.astype(jnp.float32),
@@ -138,7 +160,7 @@ def folb_apply(w: jnp.ndarray, deltas: jnp.ndarray, weights: jnp.ndarray,
     ``w.dtype`` with the add performed in fp32.
     """
     K, D = deltas.shape
-    tile = _pick_tile(D)
+    tile = _pick_tile(D, K, deltas.dtype.itemsize)
     assert D % tile == 0, (D, tile)
     if interpret and D // tile > _INTERPRET_MAX_GRID:
         upd = jnp.tensordot(weights.astype(jnp.float32),
@@ -168,7 +190,8 @@ def guard_stats(deltas: jnp.ndarray, grads: jnp.ndarray,
     flag is 1.0 iff every delta AND grad lane of the row is finite.
     """
     K, D = deltas.shape
-    tile = _pick_tile(D)
+    tile = _pick_tile(D, K, max(deltas.dtype.itemsize, grads.dtype.itemsize),
+                      n_streams=2)
     assert D % tile == 0, (D, tile)
     if interpret and D // tile > _INTERPRET_MAX_GRID:
         d = deltas.astype(jnp.float32)
@@ -356,10 +379,11 @@ def folb_aggregate_sharded(w: jnp.ndarray, deltas: jnp.ndarray,
         new_w_l = folb_apply(w_l, d_l, scores / denom, interpret=interpret)
         return new_w_l, scores
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(None, axis), P(None, axis), P(None)),
-                   out_specs=(P(axis), P(None)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(None, axis), P(None, axis),
+                                 P(None)),
+                       out_specs=(P(axis), P(None)),
+                       check_vma=False)
     return fn(w, deltas, grads, psi_gamma)
 
 
@@ -391,11 +415,11 @@ def folb_aggregate_stale_sharded(w: jnp.ndarray, deltas: jnp.ndarray,
         new_w_l = folb_apply(w_l, d_l, scores / denom, interpret=interpret)
         return new_w_l, scores
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(None, axis), P(None, axis),
-                             P(None), P(), P(None), P(None)),
-                   out_specs=(P(axis), P(None)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(None, axis), P(None, axis),
+                                 P(None), P(), P(None), P(None)),
+                       out_specs=(P(axis), P(None)),
+                       check_vma=False)
     return fn(w, deltas, grads, tau, alpha, psi_gamma, mask)
 
 
@@ -444,11 +468,11 @@ def folb_aggregate_stale_guarded_sharded(w: jnp.ndarray, deltas: jnp.ndarray,
         new_w_l = jnp.where(jnp.sum(m0) > 0.0, new_w_l, w_l)
         return new_w_l, scores, m0, jnp.stack([nf, nc, ng])
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(None, axis), P(None, axis),
-                             P(None), P(), P(None), P(None)),
-                   out_specs=(P(axis), P(None), P(None), P(None)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(None, axis), P(None, axis),
+                                 P(None), P(), P(None), P(None)),
+                       out_specs=(P(axis), P(None), P(None), P(None)),
+                       check_vma=False)
     new_w, scores, m0, counters = fn(w, deltas, grads, tau, alpha,
                                      psi_gamma, mask)
     ginfo = {"mask": m0, "n_nonfinite": counters[0],

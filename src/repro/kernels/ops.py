@@ -1,8 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels run in interpret mode (``interpret=True``
-executes the kernel body in Python for correctness); on TPU the same
-pallas_call compiles to Mosaic.  ``INTERPRET`` flips the default.
+Every wrapper picks the kernel's mode from the platform the program is
+lowered for (``_by_platform``): on CPU the Pallas kernels run in interpret
+mode (the kernel body executed as XLA ops, for correctness); on TPU the
+same ``pallas_call`` compiles to a Mosaic ``tpu_custom_call``.  The choice
+is made by ``lax.platform_dependent`` at lowering time, so a program
+compiled for a described TPU from a CPU host gets the Mosaic kernel, and
+importing this module initialises no backend.
 
 The FOLB entry points come in two layers:
 
@@ -28,7 +32,17 @@ from repro.kernels import folb_aggregate as _folb
 from repro.kernels import slstm_scan as _slstm
 from repro.kernels import ssm_scan as _ssd
 
-INTERPRET = jax.default_backend() == "cpu"
+
+def _by_platform(kernel, *args, **static):
+    """``kernel(*args, interpret=..., **static)`` with ``interpret=True``
+    when the enclosing program is lowered for CPU and the compiled Mosaic
+    kernel on every other platform.  ``args`` are arrays; ``static`` holds
+    the non-array arguments (mesh, guard, block sizes)."""
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=lambda *a: kernel(*a, interpret=True, **static),
+        default=lambda *a: kernel(*a, interpret=False, **static))
+
 
 # default storage dtype for the (K, D) grad/delta buffers: bf16 halves the
 # streaming traffic that dominates FOLB's server cost; parameters stay fp32
@@ -40,22 +54,20 @@ DEFAULT_BUF_DTYPE = jnp.bfloat16
 def flash_attention(q, k, v, causal: bool = True, sliding_window: int = 0,
                     block_q: int = _fa.DEFAULT_BLOCK_Q,
                     block_k: int = _fa.DEFAULT_BLOCK_K):
-    return _fa.flash_attention(q, k, v, causal=causal,
-                               sliding_window=sliding_window,
-                               block_q=block_q, block_k=block_k,
-                               interpret=INTERPRET)
+    return _by_platform(_fa.flash_attention, q, k, v, causal=causal,
+                        sliding_window=sliding_window,
+                        block_q=block_q, block_k=block_k)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, loga, w, Bm, Cm, chunk: int = 128):
-    return _ssd.ssd_scan(x, loga, w, Bm, Cm, chunk=chunk,
-                         interpret=INTERPRET)
+    return _by_platform(_ssd.ssd_scan, x, loga, w, Bm, Cm, chunk=chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("n_heads", "chunk"))
 def slstm_scan(xg, r, n_heads: int, chunk: int = 256):
-    return _slstm.slstm_scan(xg, r, n_heads, chunk=chunk,
-                             interpret=INTERPRET)
+    return _by_platform(_slstm.slstm_scan, xg, r, n_heads=n_heads,
+                        chunk=chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "guard"))
@@ -82,12 +94,12 @@ def folb_aggregate_buffers(w, deltas, grads, psi_gamma=None, mesh=None,
             w, deltas, grads, jnp.zeros((K,), jnp.float32),
             jnp.zeros((), jnp.float32), psi_gamma=pg, mesh=mesh, guard=guard)
     if mesh is not None:
-        return _folb.folb_aggregate_sharded(w, deltas, grads, pg, mesh,
-                                            interpret=INTERPRET)
+        return _by_platform(_folb.folb_aggregate_sharded, w, deltas, grads,
+                            pg, mesh=mesh)
     g1 = jnp.mean(grads.astype(jnp.float32), axis=0)
     g1_sq = jnp.sum(g1 * g1)
-    return _folb.folb_aggregate(w, deltas, grads, g1, pg, g1_sq,
-                                interpret=INTERPRET)
+    return _by_platform(_folb.folb_aggregate, w, deltas, grads, g1, pg,
+                        g1_sq)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "guard"))
@@ -108,23 +120,37 @@ def folb_staleness_buffers(w, deltas, grads, tau, alpha, psi_gamma=None,
     alpha = jnp.asarray(alpha, jnp.float32)
     if guard is not None:
         if mesh is not None:
-            return _folb.folb_aggregate_stale_guarded_sharded(
-                w, deltas, grads, tau, alpha, pg, m, guard, mesh,
-                interpret=INTERPRET)
-        return _folb.folb_aggregate_stale_guarded(
-            w, deltas, grads, tau, alpha, pg, m, guard,
-            interpret=INTERPRET)
+            return _by_platform(_folb.folb_aggregate_stale_guarded_sharded,
+                                w, deltas, grads, tau, alpha, pg, m,
+                                guard=guard, mesh=mesh)
+        return _by_platform(_folb.folb_aggregate_stale_guarded, w, deltas,
+                            grads, tau, alpha, pg, m, guard=guard)
     if mesh is not None:
-        return _folb.folb_aggregate_stale_sharded(
-            w, deltas, grads, tau, alpha, pg, m, mesh, interpret=INTERPRET)
-    return _folb.folb_aggregate_stale(w, deltas, grads, tau, alpha, pg, m,
-                                      interpret=INTERPRET)
+        return _by_platform(_folb.folb_aggregate_stale_sharded, w, deltas,
+                            grads, tau, alpha, pg, m, mesh=mesh)
+    return _by_platform(_folb.folb_aggregate_stale, w, deltas, grads, tau,
+                        alpha, pg, m)
+
+
+def _whole(tree, mesh):
+    """Pin ``tree`` whole on every device of ``mesh`` (identity without a
+    mesh).  The pytree front-ends shard only the aggregation: the raveled
+    buffers and the result are pinned whole, so the partitioner does not
+    carry the D sharding on into the local solves around it, which would
+    then run split over the mesh with all-to-alls (13x the compile time
+    at 1e8 parameters on four v5e chips)."""
+    if mesh is None:
+        return tree
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.lax.with_sharding_constraint(tree, NamedSharding(mesh, P()))
 
 
 def _ravel_problem(params, deltas_stacked, grads_stacked, buf_dtype, mesh):
     """Shared flattening for the pytree front-ends: (spec, flat fp32 w,
     buf_dtype (K, D) delta/grad buffers).  With a mesh, D pads to the
-    shard-aligned boundary so every shard's local sweep is tile-aligned."""
+    shard-aligned boundary so every shard's local sweep is tile-aligned,
+    and the buffers are pinned whole (``_whole``): each shard slices its
+    columns locally."""
     from repro.core import flat as flat_lib
     pad_to = (_folb.shard_alignment(mesh) if mesh is not None
               else _folb.TILE_D)
@@ -133,7 +159,7 @@ def _ravel_problem(params, deltas_stacked, grads_stacked, buf_dtype, mesh):
     w = flat_lib.ravel(spec, params)
     deltas = flat_lib.ravel_stacked(bspec, deltas_stacked)
     grads = flat_lib.ravel_stacked(bspec, grads_stacked)
-    return spec, w, deltas, grads
+    return (spec, *_whole((w, deltas, grads), mesh))
 
 
 def folb_aggregate_tree(params, deltas_stacked, grads_stacked,
@@ -151,11 +177,11 @@ def folb_aggregate_tree(params, deltas_stacked, grads_stacked,
     if guard is not None:
         new_flat, scores, ginfo = folb_aggregate_buffers(
             w, deltas, grads, psi_gamma=psi_gammas, mesh=mesh, guard=guard)
-        return flat_lib.unravel(spec, new_flat), scores, ginfo
+        return flat_lib.unravel(spec, _whole(new_flat, mesh)), scores, ginfo
     new_flat, scores = folb_aggregate_buffers(w, deltas, grads,
                                               psi_gamma=psi_gammas,
                                               mesh=mesh)
-    return flat_lib.unravel(spec, new_flat), scores
+    return flat_lib.unravel(spec, _whole(new_flat, mesh)), scores
 
 
 def folb_staleness_tree(params, deltas_stacked, grads_stacked, tau,
@@ -173,12 +199,12 @@ def folb_staleness_tree(params, deltas_stacked, grads_stacked, tau,
             w, deltas, grads, tau.astype(jnp.float32),
             jnp.asarray(alpha, jnp.float32), psi_gamma=psi_gammas,
             mask=mask, mesh=mesh, guard=guard)
-        return flat_lib.unravel(spec, new_flat), scores, ginfo
+        return flat_lib.unravel(spec, _whole(new_flat, mesh)), scores, ginfo
     new_flat, scores = folb_staleness_buffers(
         w, deltas, grads, tau.astype(jnp.float32),
         jnp.asarray(alpha, jnp.float32), psi_gamma=psi_gammas, mask=mask,
         mesh=mesh)
-    return flat_lib.unravel(spec, new_flat), scores
+    return flat_lib.unravel(spec, _whole(new_flat, mesh)), scores
 
 
 def folb_staleness_slots_tree(params, deltas_slots, grads_slots, slot_mask,
@@ -213,11 +239,11 @@ def folb_staleness_slots_tree(params, deltas_slots, grads_slots, slot_mask,
             w, deltas, grads, slot_tau.astype(jnp.float32),
             jnp.asarray(alpha, jnp.float32), psi_gamma=psi_gammas,
             mask=slot_mask, mesh=mesh, guard=guard)
-        return flat_lib.unravel(spec, new_flat), scores, ginfo
+        return flat_lib.unravel(spec, _whole(new_flat, mesh)), scores, ginfo
     new_flat, scores = folb_staleness_buffers(
         w, deltas, grads, slot_tau.astype(jnp.float32),
         jnp.asarray(alpha, jnp.float32), psi_gamma=psi_gammas,
         mask=slot_mask, mesh=mesh)
     alive = jnp.sum(slot_mask) > 0.0
-    new_flat = jnp.where(alive, new_flat, w)
+    new_flat = jnp.where(alive, _whole(new_flat, mesh), w)
     return flat_lib.unravel(spec, new_flat), scores

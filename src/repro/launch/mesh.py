@@ -7,6 +7,7 @@ jax device state.  The production target is TPU v5e: 256 chips per pod in a
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,7 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"production mesh needs {need} devices, found {len(devs)}; "
             "the dry-run entrypoint sets "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -28,7 +30,8 @@ def make_host_mesh(model_parallel: int = 1):
     n = jax.device_count()
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 # Hardware constants for the roofline analysis (TPU v5e).

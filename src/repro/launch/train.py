@@ -20,6 +20,7 @@ from repro.checkpoint import io as ckpt_io
 from repro.configs import get_config
 from repro.data.synthetic import token_stream_lm
 from repro.fed.distributed import RoundConfig, folb_round
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as model_lib
 from repro.sharding import specs as specs_lib
@@ -39,6 +40,28 @@ def make_round_batches(cfg, n_clients: int, seqs: int, seq_len: int,
             "labels": jnp.asarray(np.stack([d["labels"] for d in devs])),
         })
     return batches
+
+
+def init_placed(cfg, mesh, seed: int = 0):
+    """Seeded parameters placed on ``mesh`` under the production param
+    specs -> (params, their NamedSharding pytree)."""
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(seed))
+    ps = jax.eval_shape(lambda: params)
+    p_shard = jax.tree.map(
+        lambda s: jax.sharding.NamedSharding(mesh, s),
+        specs_lib.param_specs(cfg, ps, mesh),
+        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+    return jax.device_put(params, p_shard), p_shard
+
+
+def make_step(cfg, rc: RoundConfig, mesh, p_shard):
+    """The jitted federated round ``(params, batch) -> (params, metrics)``
+    exactly as the launcher runs it."""
+    @jax.jit
+    def step(p, b):
+        with use_sharding(mesh):
+            return folb_round(cfg, rc, p, b, param_shardings=p_shard)
+    return step
 
 
 def main() -> None:
@@ -61,6 +84,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -71,19 +95,8 @@ def main() -> None:
     print(f"[train] {cfg.name} | algo={args.algo} K={args.clients} "
           f"E={args.local_steps} | mesh {dict(mesh.shape)}")
 
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed))
-    ps = jax.eval_shape(lambda: params)
-    p_shard = jax.tree.map(
-        lambda s: jax.sharding.NamedSharding(mesh, s),
-        specs_lib.param_specs(cfg, ps, mesh),
-        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
-    params = jax.device_put(params, p_shard)
-
-    @jax.jit
-    def step(p, b):
-        with use_sharding(mesh):
-            return folb_round(cfg, rc, p, b, param_shardings=p_shard)
-
+    params, p_shard = init_placed(cfg, mesh, args.seed)
+    step = make_step(cfg, rc, mesh, p_shard)
     batches = make_round_batches(cfg, args.clients, args.seqs_per_client,
                                  args.seq_len, args.rounds, args.seed)
     for r, batch in enumerate(batches):

@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -274,4 +274,5 @@ def folb_mesh(n_shards: int = 0) -> Mesh:
     devs = jax.devices()
     n = n_shards or len(devs)
     assert n <= len(devs), (n, len(devs))
-    return jax.make_mesh((n,), (FLAT_AXIS,), devices=devs[:n])
+    return jax.make_mesh((n,), (FLAT_AXIS,), devices=devs[:n],
+                         axis_types=(AxisType.Auto,))

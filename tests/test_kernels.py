@@ -132,6 +132,31 @@ class TestFolbAggregate:
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(exp)):
             assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
+    @pytest.mark.parametrize("D", [TILE_D, 3 * TILE_D, 257 * TILE_D,
+                                   4096 * TILE_D, 98_360 * TILE_D])
+    def test_tile_unchanged_at_small_k(self, D):
+        """K ≤ 16 keeps the D-only tile rule the kernels always used, so
+        their results (reduction order included) are unchanged there."""
+        from repro.kernels.folb_aggregate import _MAX_TILE_D, _pick_tile
+        old = TILE_D
+        while old < _MAX_TILE_D and D % (2 * old) == 0 and D // old > 256:
+            old *= 2
+        for K in range(1, 17):
+            for itemsize in (2, 4):
+                for n_streams in (1, 2):
+                    assert _pick_tile(D, K, itemsize, n_streams) == old
+
+    @pytest.mark.parametrize("K", [17, 64, 100, 128, 512, 2048])
+    @pytest.mark.parametrize("itemsize,n_streams", [(2, 1), (4, 1), (2, 2),
+                                                    (4, 2)])
+    def test_tile_budget_at_large_k(self, K, itemsize, n_streams):
+        from repro.kernels import folb_aggregate as F
+        D = 1 << 22
+        t = F._pick_tile(D, K, itemsize, n_streams)
+        assert D % t == 0 and t >= F._MIN_TILE_D
+        rows = -(-K // (32 // itemsize)) * (32 // itemsize)
+        assert n_streams * 2 * rows * t * itemsize <= F._VMEM_BLOCK_BUDGET
+
 
 class TestSSDScan:
     @pytest.mark.parametrize("S,P,N,chunk", [

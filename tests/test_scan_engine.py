@@ -215,3 +215,18 @@ class TestInputs:
         h1 = run_federated_compiled(MCLR, fed_data, fl, rounds=3)
         h2 = run_federated_compiled(MCLR, fed_data, fl, rounds=3)
         assert h1["train_loss"] == h2["train_loss"]
+
+    @pytest.mark.parametrize("rounds,eval_every", [
+        (1, 1), (5, 1), (5, 2), (7, 3), (6, 3), (4, 10)])
+    @pytest.mark.parametrize("member_axis", [False, True])
+    def test_eval_rows_are_the_eval_points(self, rounds, eval_every,
+                                           member_axis):
+        """The replay's sliced row selection takes exactly the
+        ``_eval_points`` rows, for solo (R, D) and sweep (R, S, D)
+        trajectories."""
+        from repro.fed.scan_engine import _eval_points, _eval_rows
+        shape = (rounds, 3, 5) if member_axis else (rounds, 5)
+        traj = np.arange(np.prod(shape), dtype=np.float32).reshape(shape)
+        got = np.asarray(_eval_rows(traj, rounds, eval_every))
+        want = traj[np.asarray(_eval_points(rounds, eval_every))]
+        assert got.shape == want.shape and (got == want).all()
