@@ -41,6 +41,7 @@ from repro.fed import async_engine as _async
 from repro.fed import scan_engine as _scan
 from repro.fed import simulator as _sim
 from repro.fed import sweep_engine as _sweep
+from repro.telemetry import profiler as _profiler
 
 _ENGINES = ("auto", "loop", "scan")
 
@@ -130,7 +131,22 @@ def run(model_cfg, fed, cfg: RunConfig, rounds: int, *,
 
     Returns ``FedRunResult`` for solo configs, ``SweepResult`` for
     sweeps, ``ScenarioGridResult`` for scenario grids.
+
+    A call given a ``profiler`` records spans and counters
+    (``repro.telemetry.profiler``) for its length, as does every call
+    while recording is switched on; the spans of one call share a call
+    id.
     """
+    with _profiler.call(profiler):
+        return _run(model_cfg, fed, cfg, rounds, engine=engine, sweep=sweep,
+                    fleet=fleet, plan=plan, mesh=mesh,
+                    eval_every=eval_every, telemetry=telemetry,
+                    scenario=scenario, key=key, sel_probs=sel_probs,
+                    profiler=profiler)
+
+
+def _run(model_cfg, fed, cfg, rounds, *, engine, sweep, fleet, plan, mesh,
+         eval_every, telemetry, scenario, key, sel_probs, profiler):
     if engine not in _ENGINES:
         raise ValueError(
             f"engine must be one of {_ENGINES}, got {engine!r}")

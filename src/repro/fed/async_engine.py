@@ -59,6 +59,7 @@ from repro.sysmodel import (DeviceFleet, EventQueue, device_latencies,
                             expected_latencies, plan_deadline_run,
                             round_cost_for)
 from repro.sysmodel import scenario as scenario_mod
+from repro.telemetry import profiler as tprof
 
 ASYNC_MODES = ("deadline", "fedbuff")
 # aggregation bases the async engine can run (the sync-parity fast path
@@ -352,7 +353,7 @@ def deadline_selection_probs(afl: AsyncFLConfig, fleet: DeviceFleet, cost,
     policy."""
     if not afl.latency_aware:
         return None
-    exp_lat = jnp.asarray(expected_latencies(
+    exp_lat = tprof.to_device(expected_latencies(
         fleet, cost, mean_steps=simulator.mean_local_steps(afl),
         n_examples=sizes))
     return selection.latency_aware_probs(
@@ -382,109 +383,115 @@ def build_deadline_plan(afl: AsyncFLConfig, fleet: DeviceFleet, cost,
     """
     from repro.fed.scan_engine import _split_chain
     K = afl.n_selected
-    subs = _split_chain(init_key, rounds)
-    if sel_probs is None and afl.sampler == "indexed":
-        # O(K) per round: never build the (N,) uniform vector
-        ids = np.asarray(
-            _draw_ids_chain_indexed(subs, fleet.n_devices, K), np.int32)
-    else:
-        probs = sel_probs if sel_probs is not None \
-            else selection.uniform_probs(fleet.n_devices)
-        ids = np.asarray(_draw_ids_chain(subs, probs, K), np.int32)
-    n_steps = np.stack([np.asarray(simulator.local_step_draws(t, K, afl))
-                        for t in range(rounds)]).astype(np.int32)
-    sc = scenario_mod.as_active(scenario)
-    if sc is None:
-        arrival, arrived, round_end = plan_deadline_run(
-            fleet, ids, n_steps, cost, deadline=afl.deadline,
-            n_examples=sizes)
-        drop = lost = None
-    else:
-        scenario_mod.check_deadline(sc, afl.deadline)
-        g = scenario_mod.realize(sc, (rounds, K))
-        n_steps = scenario_mod.scale_steps(n_steps, g.comp)
-        drop, lost = g.drop, g.lost
-        arrival, arrived, round_end = plan_deadline_run(
-            fleet, ids, n_steps, cost, deadline=afl.deadline,
-            n_examples=sizes, lat_scale=g.lat_scale, lost=lost)
-        # `arrived` excludes lost dispatches already (plan_deadline_run);
-        # exclude failed uploads from aggregation too — they land on time
-        # but carry nothing
-        arrived = arrived & ~drop
-
-    pending: List[Dict] = []   # {"arrival", "t0", "slot"} in insertion order
-    failed_pending: List[float] = []   # arrival clocks of dropped uploads
-    free: List[int] = []
-    pool = 0
-    store_slot = np.full((rounds, K), -1, np.int64)
-    due_lists: List[List] = []
-    fast = np.zeros(rounds, bool)
-    n_arrived = np.zeros(rounds, np.int64)
-    n_failed = np.zeros(rounds, np.int64)
-    stale_sum = np.zeros(rounds)
-    for t in range(rounds):
-        if sc is not None:
-            # failed-upload byte accounting: a dropped dispatch's upload
-            # still lands on the network at its arrival time (possibly in
-            # a LATER round for dropped stragglers) — drain before the
-            # fast-round shortcut so fast rounds are charged too
-            failed_pending.extend(arrival[t, i]
-                                  for i in np.flatnonzero(drop[t]))
-            n_failed[t] = sum(1 for a in failed_pending
-                              if a <= round_end[t])
-            failed_pending = [a for a in failed_pending
-                              if a > round_end[t]]
-        due = [pu for pu in pending if pu["arrival"] <= round_end[t]]
-        if arrived[t].all() and not due:
-            fast[t] = True
-            due_lists.append([])
-            n_arrived[t] = K
-            continue
-        pending = [pu for pu in pending if pu["arrival"] > round_end[t]]
-        # free due slots BEFORE allocating this round's stragglers: the
-        # step function gathers due rows before storing, so same-round
-        # slot reuse is safe
-        for pu in due:
-            heapq.heappush(free, pu["slot"])
-        if sc is None:
-            stragglers = np.flatnonzero(~arrived[t])
+    with tprof.span("plan_build/key_chain"):
+        subs = _split_chain(init_key, rounds)
+        if sel_probs is None and afl.sampler == "indexed":
+            # O(K) per round: never build the (N,) uniform vector
+            ids = tprof.fetch(
+                _draw_ids_chain_indexed(subs, fleet.n_devices, K), np.int32)
         else:
-            # dropped/lost dispatches are DISCARDED, never parked: their
-            # updates go to the dump row like an on-time device's write
-            stragglers = np.flatnonzero(~arrived[t] & ~drop[t] & ~lost[t])
-        for i in stragglers:
-            if free:
-                slot = heapq.heappop(free)
+            probs = sel_probs if sel_probs is not None \
+                else selection.uniform_probs(fleet.n_devices)
+            ids = tprof.fetch(_draw_ids_chain(subs, probs, K), np.int32)
+        keys = tprof.fetch(subs)
+    with tprof.span("plan_build/step_draws"):
+        n_steps = np.stack([tprof.fetch(simulator.local_step_draws(t, K, afl))
+                            for t in range(rounds)]).astype(np.int32)
+    sc = scenario_mod.as_active(scenario)
+    with tprof.span("plan_build/timeline"):
+        if sc is None:
+            arrival, arrived, round_end = plan_deadline_run(
+                fleet, ids, n_steps, cost, deadline=afl.deadline,
+                n_examples=sizes)
+            drop = lost = None
+        else:
+            scenario_mod.check_deadline(sc, afl.deadline)
+            g = scenario_mod.realize(sc, (rounds, K))
+            n_steps = scenario_mod.scale_steps(n_steps, g.comp)
+            drop, lost = g.drop, g.lost
+            arrival, arrived, round_end = plan_deadline_run(
+                fleet, ids, n_steps, cost, deadline=afl.deadline,
+                n_examples=sizes, lat_scale=g.lat_scale, lost=lost)
+            # `arrived` excludes lost dispatches already
+            # (plan_deadline_run); exclude failed uploads from aggregation
+            # too — they land on time but carry nothing
+            arrived = arrived & ~drop
+    with tprof.span("plan_build/pool"):
+        # {"arrival", "t0", "slot"} in insertion order
+        pending: List[Dict] = []
+        failed_pending: List[float] = []   # arrival clocks of dropped uploads
+        free: List[int] = []
+        pool = 0
+        store_slot = np.full((rounds, K), -1, np.int64)
+        due_lists: List[List] = []
+        fast = np.zeros(rounds, bool)
+        n_arrived = np.zeros(rounds, np.int64)
+        n_failed = np.zeros(rounds, np.int64)
+        stale_sum = np.zeros(rounds)
+        for t in range(rounds):
+            if sc is not None:
+                # failed-upload byte accounting: a dropped dispatch's upload
+                # still lands on the network at its arrival time (possibly in
+                # a LATER round for dropped stragglers) — drain before the
+                # fast-round shortcut so fast rounds are charged too
+                failed_pending.extend(arrival[t, i]
+                                      for i in np.flatnonzero(drop[t]))
+                n_failed[t] = sum(1 for a in failed_pending
+                                  if a <= round_end[t])
+                failed_pending = [a for a in failed_pending
+                                  if a > round_end[t]]
+            due = [pu for pu in pending if pu["arrival"] <= round_end[t]]
+            if arrived[t].all() and not due:
+                fast[t] = True
+                due_lists.append([])
+                n_arrived[t] = K
+                continue
+            pending = [pu for pu in pending if pu["arrival"] > round_end[t]]
+            # free due slots BEFORE allocating this round's stragglers: the
+            # step function gathers due rows before storing, so same-round
+            # slot reuse is safe
+            for pu in due:
+                heapq.heappush(free, pu["slot"])
+            if sc is None:
+                stragglers = np.flatnonzero(~arrived[t])
             else:
-                slot = pool
-                pool += 1
-            store_slot[t, i] = slot
-            pending.append({"arrival": arrival[t, i], "t0": t, "slot": slot})
-        due_lists.append([(pu["slot"], t - pu["t0"]) for pu in due])
-        n_arrived[t] = int(arrived[t].sum()) + len(due)
-        stale_sum[t] = float(sum(tau for _, tau in due_lists[-1]))
-    S = max((len(d) for d in due_lists), default=0)
-    due_slot = np.full((rounds, S), pool, np.int64)
-    due_mask = np.zeros((rounds, S), np.float32)
-    due_tau = np.zeros((rounds, S), np.float32)
-    for t, d in enumerate(due_lists):
-        for j, (slot, tau) in enumerate(d):
-            due_slot[t, j] = slot
-            due_mask[t, j] = 1.0
-            due_tau[t, j] = tau
-    store_slot = np.where(store_slot < 0, pool, store_slot)
-    stale_mean = np.where(n_arrived > 0,
-                          stale_sum / np.maximum(n_arrived, 1), 0.0)
-    return DeadlinePlan(
-        keys=np.asarray(subs), ids=ids, n_steps=n_steps, arrival=arrival,
-        arrived=arrived, round_end=round_end, fast=fast,
-        store_slot=store_slot.astype(np.int32),
-        due_slot=due_slot.astype(np.int32), due_mask=due_mask,
-        due_tau=due_tau, n_arrived=n_arrived, stale_mean=stale_mean,
-        n_slots=pool, n_due=S,
-        drop_mask=drop, lost_mask=lost,
-        n_failed_up=None if sc is None else n_failed,
-        corrupt=None if sc is None else g.corrupt)
+                # dropped/lost dispatches are DISCARDED, never parked: their
+                # updates go to the dump row like an on-time device's write
+                stragglers = np.flatnonzero(~arrived[t] & ~drop[t] & ~lost[t])
+            for i in stragglers:
+                if free:
+                    slot = heapq.heappop(free)
+                else:
+                    slot = pool
+                    pool += 1
+                store_slot[t, i] = slot
+                pending.append({"arrival": arrival[t, i], "t0": t,
+                                "slot": slot})
+            due_lists.append([(pu["slot"], t - pu["t0"]) for pu in due])
+            n_arrived[t] = int(arrived[t].sum()) + len(due)
+            stale_sum[t] = float(sum(tau for _, tau in due_lists[-1]))
+        S = max((len(d) for d in due_lists), default=0)
+        due_slot = np.full((rounds, S), pool, np.int64)
+        due_mask = np.zeros((rounds, S), np.float32)
+        due_tau = np.zeros((rounds, S), np.float32)
+        for t, d in enumerate(due_lists):
+            for j, (slot, tau) in enumerate(d):
+                due_slot[t, j] = slot
+                due_mask[t, j] = 1.0
+                due_tau[t, j] = tau
+        store_slot = np.where(store_slot < 0, pool, store_slot)
+        stale_mean = np.where(n_arrived > 0,
+                              stale_sum / np.maximum(n_arrived, 1), 0.0)
+        return DeadlinePlan(
+            keys=keys, ids=ids, n_steps=n_steps, arrival=arrival,
+            arrived=arrived, round_end=round_end, fast=fast,
+            store_slot=store_slot.astype(np.int32),
+            due_slot=due_slot.astype(np.int32), due_mask=due_mask,
+            due_tau=due_tau, n_arrived=n_arrived, stale_mean=stale_mean,
+            n_slots=pool, n_due=S,
+            drop_mask=drop, lost_mask=lost,
+            n_failed_up=None if sc is None else n_failed,
+            corrupt=None if sc is None else g.corrupt)
 
 
 class _FedBuffCapacity(Exception):
@@ -547,18 +554,18 @@ def _build_fedbuff_attempt(afl: AsyncFLConfig, fleet: DeviceFleet, cost,
     sc = scenario_mod.as_active(scenario)
     g = scenario_mod.realize(sc, (total,)) if sc is not None else None
     if afl.latency_aware and math.isfinite(afl.deadline):
-        exp_lat = jnp.asarray(expected_latencies(
+        exp_lat = tprof.to_device(expected_latencies(
             fleet, cost, mean_steps=simulator.mean_local_steps(afl),
             n_examples=sizes))
         probs = selection.latency_aware_probs(
             jnp.ones((fleet.n_devices,)), exp_lat, afl.deadline)
-        cids = np.asarray(_draw_cids_chain(subs, probs), np.int64)
+        cids = tprof.fetch(_draw_cids_chain(subs, probs), np.int64)
     elif afl.sampler == "indexed":
-        cids = np.asarray(
+        cids = tprof.fetch(
             _draw_cids_chain_indexed(subs, fleet.n_devices), np.int64)
     else:
         probs = selection.uniform_probs(fleet.n_devices)
-        cids = np.asarray(_draw_cids_chain(subs, probs), np.int64)
+        cids = tprof.fetch(_draw_cids_chain(subs, probs), np.int64)
     steps = np.empty(total, np.int64)
     for d in range(total):
         step_rng = np.random.default_rng(20_000 + d)
@@ -1192,11 +1199,7 @@ def run_async(model_cfg, fed: FederatedData, afl: AsyncFLConfig,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(afl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = simulator.device_arrays(fed)
         sizes = np.asarray(fed.mask.sum(axis=1))
         cost = round_cost_for(model_cfg, params,
                               uploads_gradient="folb" in afl.algo)
@@ -1213,9 +1216,9 @@ def run_async(model_cfg, fed: FederatedData, afl: AsyncFLConfig,
             _, te_acc = simulator.eval_global(model_cfg, cur_params, test, p)
             hist["round"].append(t)
             hist["wall_clock"].append(float(clock_now))
-            hist["train_loss"].append(float(tr_loss))
-            hist["train_acc"].append(float(tr_acc))
-            hist["test_acc"].append(float(te_acc))
+            hist["train_loss"].append(tprof.fetch_float(tr_loss))
+            hist["train_acc"].append(tprof.fetch_float(tr_acc))
+            hist["test_acc"].append(tprof.fetch_float(te_acc))
             hist["n_arrived"].append(float(n_arrived))
             hist["stale_mean"].append(float(stale_mean))
 
@@ -1282,8 +1285,9 @@ def _run_deadline(model_cfg, afl, fleet, cost, sizes, train, p, key, params,
 
 def _deadline_round(model_cfg, afl_t, sync_fl, params, pend, train, p, plan,
                     t, sel_probs, hypers, mlist, mesh):
-    n_steps = jnp.asarray(plan.n_steps[t])
-    corrupt = None if plan.corrupt is None else jnp.asarray(plan.corrupt[t])
+    n_steps = tprof.to_device(plan.n_steps[t])
+    corrupt = None if plan.corrupt is None \
+        else tprof.to_device(plan.corrupt[t])
     if plan.fast[t]:
         # sync-parity fast path: every dispatched device made the
         # deadline and no stale upload joins, so every τ is 0 and the
@@ -1296,19 +1300,19 @@ def _deadline_round(model_cfg, afl_t, sync_fl, params, pend, train, p, plan,
         # same key.
         params, diag = simulator.fl_round(
             model_cfg, sync_fl, params, train, p,
-            jnp.asarray(plan.keys[t]), n_steps, sel_probs, hypers,
+            tprof.to_device(plan.keys[t]), n_steps, sel_probs, hypers,
             None, corrupt, mesh=mesh)
         if sync_fl.telemetry:
             mlist.append(diag["metrics"])
         return params, pend
     out = deadline_slow_step(
         model_cfg, afl_t, params, pend, train,
-        jnp.asarray(plan.ids[t]), n_steps,
-        jnp.asarray(plan.arrived[t], jnp.float32),
-        jnp.asarray(plan.store_slot[t]),
-        jnp.asarray(plan.due_slot[t]),
-        jnp.asarray(plan.due_mask[t]),
-        jnp.asarray(plan.due_tau[t]), hypers, corrupt, mesh=mesh)
+        tprof.to_device(plan.ids[t]), n_steps,
+        tprof.to_device(plan.arrived[t], jnp.float32),
+        tprof.to_device(plan.store_slot[t]),
+        tprof.to_device(plan.due_slot[t]),
+        tprof.to_device(plan.due_mask[t]),
+        tprof.to_device(plan.due_tau[t]), hypers, corrupt, mesh=mesh)
     if afl_t.telemetry:
         params, pend, m = out
         mlist.append(m)
@@ -1334,23 +1338,23 @@ def _run_fedbuff(model_cfg, afl, fleet, cost, sizes, train, key, params,
         pend = pool_init(model_cfg, afl_t.sync_config(), params, train,
                          plan.n_slots)
         pend = fedbuff_seed_pool(model_cfg, afl_t, params, pend, train,
-                                 jnp.asarray(plan.seed_ids),
-                                 jnp.asarray(plan.seed_steps),
-                                 jnp.asarray(plan.seed_slots), hypers,
+                                 tprof.to_device(plan.seed_ids),
+                                 tprof.to_device(plan.seed_steps),
+                                 tprof.to_device(plan.seed_slots), hypers,
                                  corrupt=None if plan.seed_corrupt is None
-                                 else jnp.asarray(plan.seed_corrupt))
+                                 else tprof.to_device(plan.seed_corrupt))
     for t in range(rounds):
         with prof.phase("rounds"):
             out = fedbuff_round_step(
                 model_cfg, afl_t, params, pend, train,
-                jnp.asarray(plan.ids[t]), jnp.asarray(plan.n_steps[t]),
-                jnp.asarray(plan.store_slot[t]),
-                jnp.asarray(plan.flush_slot[t]),
-                jnp.asarray(plan.tau[t]), hypers,
+                tprof.to_device(plan.ids[t]), tprof.to_device(plan.n_steps[t]),
+                tprof.to_device(plan.store_slot[t]),
+                tprof.to_device(plan.flush_slot[t]),
+                tprof.to_device(plan.tau[t]), hypers,
                 flush_mask=None if plan.flush_mask is None
-                else jnp.asarray(plan.flush_mask[t]),
+                else tprof.to_device(plan.flush_mask[t]),
                 corrupt=None if plan.corrupt is None
-                else jnp.asarray(plan.corrupt[t]), mesh=mesh)
+                else tprof.to_device(plan.corrupt[t]), mesh=mesh)
             if afl_t.telemetry:
                 params, pend, m = out
                 mlist.append(m)

@@ -57,6 +57,7 @@ from repro.fed import server_opt as sopt
 from repro.fed import simulator
 from repro.models import small
 from repro.sysmodel import round_cost_for
+from repro.telemetry import profiler as tprof
 
 
 def _check_lazy_config(cfg, kind: str) -> None:
@@ -88,22 +89,26 @@ def _eval_arrays(data: LazyFederatedData):
     ``materialize()`` computes ``fed.p`` — so at ``eval_cohort=None``
     and small N the arrays (and every eval result) are bit-for-bit the
     resident engines' inputs."""
-    d = data.gather(data.eval_ids())
-    train = {"x": jnp.asarray(d["x"]), "y": jnp.asarray(d["y"]),
-             "mask": jnp.asarray(d["mask"])}
-    test = {"x": jnp.asarray(d["test_x"]), "y": jnp.asarray(d["test_y"]),
-            "mask": jnp.asarray(d["test_mask"])}
-    sizes = d["mask"].sum(axis=1)
-    p = jnp.asarray((sizes / sizes.sum()).astype(np.float32))
-    return train, test, p
+    with tprof.span("eval/cohort"):
+        d = data.gather(data.eval_ids())
+        sizes = d["mask"].sum(axis=1)
+        p = (sizes / sizes.sum()).astype(np.float32)
+        return _to_device(d), _to_device(d, "test_"), tprof.to_device(p)
+
+
+def _to_device(d, prefix: str = ""):
+    """The gathered ``<prefix>x`` / ``y`` / ``mask`` arrays of ``d`` as
+    ``{"x", "y", "mask"}`` device arrays."""
+    return {k: tprof.to_device(d[prefix + k]) for k in ("x", "y", "mask")}
 
 
 def _round_batches(data: LazyFederatedData, ids: np.ndarray):
     """The scan's per-round cohort inputs: train arrays only, stacked
     (R, K, M, ...) jnp arrays."""
-    d = data.gather(ids)
-    return {"x": jnp.asarray(d["x"]), "y": jnp.asarray(d["y"]),
-            "mask": jnp.asarray(d["mask"])}
+    with tprof.span("gather/synthesize"):
+        d = data.gather(ids)
+    with tprof.span("gather/to_device"):
+        return _to_device(d)
 
 
 # ------------------------------------------------------------- sync engine
@@ -162,8 +167,9 @@ def run_federated_lazy(model_cfg, data: LazyFederatedData,
         w0 = flat_lib.ravel(spec, params)
     with prof.phase("plan_build"):
         subs, steps = scan_engine.draw_round_inputs(fl, rounds, key)
-        ids = np.asarray(async_lib._draw_ids_chain_indexed(
-            subs, data.n_devices, fl.n_selected))
+        with tprof.span("plan_build/key_chain"):
+            ids = tprof.fetch(async_lib._draw_ids_chain_indexed(
+                subs, data.n_devices, fl.n_selected))
         use_so = fl.server_opt != "sgd" or fl.server_lr != 1.0
         so_state0 = sopt.init_server_state(
             sopt.ServerOptConfig(kind=fl.server_opt, lr=1.0), params) \
@@ -182,7 +188,7 @@ def run_federated_lazy(model_cfg, data: LazyFederatedData,
                 (fleet.n_devices, data.n_devices)
             clocks = scan_engine.sync_clock_replay(
                 model_cfg, params, data, fl.algo, fleet, ids, None,
-                np.asarray(steps), rounds)
+                tprof.fetch(steps), rounds)
         hist = scan_engine.eval_history_replay(
             model_cfg, spec, train, test, p, ws, rounds, eval_every, clocks)
     return simulator.FedRunResult(
@@ -299,17 +305,19 @@ def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
                     afl, fleet, cost, data.sizes, rounds, key)
         with prof.phase("gather"):
             batches = _round_batches(data, plan.ids)
-            pend0 = async_lib.pool_init_batch(
-                model_cfg, sync_fl, params,
-                {k: v[0] for k, v in batches.items()}, plan.n_slots + 1)
+            with tprof.span("gather/pool_init"):
+                pend0 = async_lib.pool_init_batch(
+                    model_cfg, sync_fl, params,
+                    {k: v[0] for k, v in batches.items()}, plan.n_slots + 1)
         with prof.phase("scan"):
             w_final, ws = scan_deadline_cohort(
                 model_cfg, afl_t, spec, w0, pend0, batches,
-                jnp.asarray(plan.n_steps),
-                jnp.asarray(plan.arrived, jnp.float32),
-                jnp.asarray(plan.store_slot), jnp.asarray(plan.due_slot),
-                jnp.asarray(plan.due_mask), jnp.asarray(plan.due_tau),
-                jnp.asarray(plan.fast), hypers, mesh=mesh)
+                tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.arrived, jnp.float32),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.due_slot),
+                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
+                tprof.to_device(plan.fast), hypers, mesh=mesh)
         clocks, n_arr = plan.round_end, plan.n_arrived
     else:
         with prof.phase("plan_build"):
@@ -319,17 +327,19 @@ def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
         with prof.phase("gather"):
             seed_batch = _round_batches(data, plan.seed_ids)
             batches = _round_batches(data, plan.ids)
-            pend0 = async_lib.pool_init_batch(
-                model_cfg, sync_fl, params, seed_batch, plan.n_slots)
-            pend0 = async_lib.fedbuff_seed_pool_cohort(
-                model_cfg, afl_t, params, pend0, seed_batch,
-                jnp.asarray(plan.seed_steps), jnp.asarray(plan.seed_slots),
-                hypers)
+            with tprof.span("gather/pool_init"):
+                pend0 = async_lib.pool_init_batch(
+                    model_cfg, sync_fl, params, seed_batch, plan.n_slots)
+                pend0 = async_lib.fedbuff_seed_pool_cohort(
+                    model_cfg, afl_t, params, pend0, seed_batch,
+                    tprof.to_device(plan.seed_steps),
+                    tprof.to_device(plan.seed_slots), hypers)
         with prof.phase("scan"):
             w_final, ws = scan_fedbuff_cohort(
                 model_cfg, afl_t, spec, w0, pend0, batches,
-                jnp.asarray(plan.n_steps), jnp.asarray(plan.store_slot),
-                jnp.asarray(plan.flush_slot), jnp.asarray(plan.tau),
+                tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.flush_slot), tprof.to_device(plan.tau),
                 hypers, mesh=mesh)
         clocks = plan.flush_clock
         n_arr = np.full(rounds, afl.buffer_size)

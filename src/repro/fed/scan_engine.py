@@ -56,6 +56,7 @@ from repro.fed import server_opt as sopt
 from repro.models import small
 from repro.sysmodel import round_cost_for
 from repro.sysmodel import scenario as scenario_mod
+from repro.telemetry import profiler as tprof
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
@@ -79,9 +80,12 @@ def draw_round_inputs(fl: simulator.FLConfig, rounds: int, init_key):
     step draws of ``simulator.local_step_draws`` — so a scan over these
     inputs sees the same randomness as ``run_federated``.
     """
-    steps = [simulator.local_step_draws(t, fl.n_selected, fl)
-             for t in range(rounds)]
-    return _split_chain(init_key, rounds), jnp.stack(steps)
+    with tprof.span("plan_build/key_chain"):
+        subs = _split_chain(init_key, rounds)
+    with tprof.span("plan_build/step_draws"):
+        steps = jnp.stack([simulator.local_step_draws(t, fl.n_selected, fl)
+                           for t in range(rounds)])
+    return subs, steps
 
 
 def make_sync_round_step(model_cfg, fl: simulator.FLConfig,
@@ -184,7 +188,7 @@ def latency_selection_probs(model_cfg, fed: FederatedData, fl, fleet,
     cost = round_cost_for(model_cfg, params,
                           uploads_gradient="folb" in fl.algo)
     sizes = np.asarray(fed.mask.sum(axis=1))
-    exp_lat = jnp.asarray(expected_latencies(
+    exp_lat = tprof.to_device(expected_latencies(
         fleet, cost, mean_steps=simulator.mean_local_steps(fl),
         n_examples=sizes))
     return selection.latency_aware_probs(
@@ -279,18 +283,20 @@ def eval_history_replay(model_cfg, spec: flat_lib.FlatSpec, train, test, p,
     timeline series to record alongside (the async engines pass all three
     from their plan)."""
     ts = _eval_points(rounds, eval_every)
-    traj = _eval_rows(params_traj, rounds, eval_every)
-    tr_loss, tr_acc = eval_traj(model_cfg, spec, traj, train, p)
-    _, te_acc = eval_traj(model_cfg, spec, traj, test, p)
-    hist = {"round": list(ts),
-            "train_loss": [float(v) for v in tr_loss],
-            "test_acc": [float(v) for v in te_acc],
-            "train_acc": [float(v) for v in tr_acc]}
-    extras = {"wall_clock": clocks, "n_arrived": n_arrived,
-              "stale_mean": stale_mean}
-    for k, series in extras.items():
-        if series is not None:
-            hist[k] = [float(series[t]) for t in ts]
+    with tprof.span("eval/device"):
+        traj = _eval_rows(params_traj, rounds, eval_every)
+        tr_loss, tr_acc = eval_traj(model_cfg, spec, traj, train, p)
+        _, te_acc = eval_traj(model_cfg, spec, traj, test, p)
+    with tprof.span("eval/fetch"):
+        hist = {"round": list(ts),
+                "train_loss": [tprof.fetch_float(v) for v in tr_loss],
+                "test_acc": [tprof.fetch_float(v) for v in te_acc],
+                "train_acc": [tprof.fetch_float(v) for v in tr_acc]}
+        extras = {"wall_clock": clocks, "n_arrived": n_arrived,
+                  "stale_mean": stale_mean}
+        for k, series in extras.items():
+            if series is not None:
+                hist[k] = [float(series[t]) for t in ts]
     return hist
 
 
@@ -313,9 +319,9 @@ def eval_history_replay_sweep(model_cfg, spec: flat_lib.FlatSpec, train,
     flat = traj.reshape((E * S,) + traj.shape[2:])
     tr_loss, tr_acc = eval_traj(model_cfg, spec, flat, train, p)
     _, te_acc = eval_traj(model_cfg, spec, flat, test, p)
-    tr_loss = np.asarray(tr_loss).reshape(E, S)
-    tr_acc = np.asarray(tr_acc).reshape(E, S)
-    te_acc = np.asarray(te_acc).reshape(E, S)
+    tr_loss = tprof.fetch(tr_loss).reshape(E, S)
+    tr_acc = tprof.fetch(tr_acc).reshape(E, S)
+    te_acc = tprof.fetch(te_acc).reshape(E, S)
     extras = {"wall_clock": clocks, "n_arrived": n_arrived,
               "stale_mean": stale_mean}
     hists = []
@@ -369,11 +375,8 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(fl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        with tprof.span("setup/to_device"):
+            train, test, p = simulator.device_arrays(fed)
         spec = flat_lib.spec_of(params)
         w0 = flat_lib.ravel(spec, params)
     with prof.phase("plan_build"):
@@ -387,9 +390,10 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
             sc_steps, sc_mask, sc_lat, sc_corr = \
                 simulator.scenario_round_inputs(fl, rounds, sc)
             keys = _split_chain(key, rounds)
-            steps = jnp.asarray(sc_steps)
-            up_mask = jnp.asarray(sc_mask)
-            corrupt = None if sc_corr is None else jnp.asarray(sc_corr)
+            steps = tprof.to_device(sc_steps)
+            up_mask = tprof.to_device(sc_mask)
+            corrupt = None if sc_corr is None \
+                else tprof.to_device(sc_corr)
         so_cfg = sopt.ServerOptConfig(kind=fl.server_opt, lr=1.0)
         use_so = fl.server_opt != "sgd" or fl.server_lr != 1.0
         so_state0 = sopt.init_server_state(so_cfg, params) if use_so \
@@ -411,16 +415,16 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
                 (fleet.n_devices, fed.n_devices)
             clocks = sync_clock_replay(
                 model_cfg, params, fed, fl.algo, fleet,
-                np.asarray(ys["ids"]),
-                np.asarray(ys["ids2"]) if "ids2" in ys else None,
-                np.asarray(steps), rounds, lat_scale=sc_lat)
+                tprof.fetch(ys["ids"]),
+                tprof.fetch(ys["ids2"]) if "ids2" in ys else None,
+                tprof.fetch(steps), rounds, lat_scale=sc_lat)
         hist = eval_history_replay(model_cfg, spec, train, test, p,
                                    ys["params"], rounds, eval_every, clocks)
     with prof.phase("collect"):
-        ids_np = np.asarray(ys["ids"])
+        ids_np = tprof.fetch(ys["ids"])
         metrics = None
         if fl.telemetry:
-            metrics = {k: np.asarray(v) for k, v in ys["metrics"].items()}
+            metrics = {k: tprof.fetch(v) for k, v in ys["metrics"].items()}
             D = int(sum(x.size for x in jax.tree.leaves(params)))
             metrics.update(tmetrics.sync_network_series(
                 D, fl, rounds, fed.n_devices))
@@ -608,11 +612,8 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(afl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        with tprof.span("setup/to_device"):
+            train, test, p = simulator.device_arrays(fed)
         sizes = np.asarray(fed.mask.sum(axis=1))
         cost = round_cost_for(model_cfg, params,
                               uploads_gradient="folb" in afl.algo)
@@ -636,14 +637,15 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
         with prof.phase("scan"):
             w_final, ws = scan_async_deadline(
                 model_cfg, afl_t, spec, w0, pend0, train, p,
-                jnp.asarray(plan.keys), jnp.asarray(plan.ids),
-                jnp.asarray(plan.n_steps),
-                jnp.asarray(plan.arrived, jnp.float32),
-                jnp.asarray(plan.store_slot), jnp.asarray(plan.due_slot),
-                jnp.asarray(plan.due_mask), jnp.asarray(plan.due_tau),
-                jnp.asarray(plan.fast), hypers, sel_probs,
+                tprof.to_device(plan.keys), tprof.to_device(plan.ids),
+                tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.arrived, jnp.float32),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.due_slot),
+                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
+                tprof.to_device(plan.fast), hypers, sel_probs,
                 None if plan.corrupt is None
-                else jnp.asarray(plan.corrupt), mesh=mesh)
+                else tprof.to_device(plan.corrupt), mesh=mesh)
             if afl.telemetry:
                 jax.block_until_ready(ws)
         clocks, n_arr = plan.round_end, plan.n_arrived
@@ -657,20 +659,22 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
                                         plan.n_slots)
             pend0 = async_lib.fedbuff_seed_pool(
                 model_cfg, afl_t, params, pend0, train,
-                jnp.asarray(plan.seed_ids), jnp.asarray(plan.seed_steps),
-                jnp.asarray(plan.seed_slots), hypers,
+                tprof.to_device(plan.seed_ids),
+                tprof.to_device(plan.seed_steps),
+                tprof.to_device(plan.seed_slots), hypers,
                 None if plan.seed_corrupt is None
-                else jnp.asarray(plan.seed_corrupt))
+                else tprof.to_device(plan.seed_corrupt))
         with prof.phase("scan"):
             w_final, ws = scan_async_fedbuff(
                 model_cfg, afl_t, spec, w0, pend0, train,
-                jnp.asarray(plan.ids), jnp.asarray(plan.n_steps),
-                jnp.asarray(plan.store_slot), jnp.asarray(plan.flush_slot),
-                jnp.asarray(plan.tau), hypers,
+                tprof.to_device(plan.ids), tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.flush_slot),
+                tprof.to_device(plan.tau), hypers,
                 None if plan.flush_mask is None
-                else jnp.asarray(plan.flush_mask),
+                else tprof.to_device(plan.flush_mask),
                 None if plan.corrupt is None
-                else jnp.asarray(plan.corrupt), mesh=mesh)
+                else tprof.to_device(plan.corrupt), mesh=mesh)
             if afl.telemetry:
                 jax.block_until_ready(ws)
         clocks = plan.flush_clock
@@ -687,7 +691,7 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
     with prof.phase("collect"):
         metrics = None
         if afl.telemetry:
-            metrics = {k: np.asarray(v) for k, v in ws["metrics"].items()}
+            metrics = {k: tprof.fetch(v) for k, v in ws["metrics"].items()}
             D = int(sum(x.size for x in jax.tree.leaves(params)))
             if afl.mode == "deadline":
                 metrics.update(tmetrics.deadline_network_series(D, afl,

@@ -32,6 +32,7 @@ from repro.data.federated import FederatedData
 from repro.kernels import ops
 from repro.models import small
 from repro.optim import solvers
+from repro.telemetry import profiler as tprof
 
 ALGOS = ("fedavg", "fedprox", "fednu_direct", "fednu_signed", "fednu_norm",
          "folb", "folb2", "folb_het")
@@ -149,7 +150,7 @@ def local_step_draws(t: int, k: int, cfg) -> jnp.ndarray:
     (FLConfig or AsyncFLConfig)."""
     step_rng = np.random.default_rng(10_000 + t)
     if cfg.het_steps:
-        return jnp.asarray(step_rng.integers(
+        return tprof.to_device(step_rng.integers(
             1, cfg.max_local_steps + 1, k), jnp.int32)
     return jnp.full((k,), cfg.max_local_steps, jnp.int32)
 
@@ -165,7 +166,7 @@ def scenario_round_inputs(fl, rounds: int, scenario):
     lat_scale or None, corrupt (R, K) f32 or None).
     """
     from repro.sysmodel import scenario as scenario_mod
-    base = np.stack([np.asarray(local_step_draws(t, fl.n_selected, fl))
+    base = np.stack([tprof.fetch(local_step_draws(t, fl.n_selected, fl))
                      for t in range(rounds)])
     g = scenario_mod.realize(scenario, (rounds, fl.n_selected))
     steps = scenario_mod.scale_steps(base, g.comp)
@@ -182,13 +183,23 @@ def scenario_grid_round_inputs(fl, rounds: int, grid):
     (steps (S, R, K) int32, up_mask (S, R, K) f32, lat_scale (S, R, K)
     or None, corrupt (S, R, K) f32 or None)."""
     from repro.sysmodel import scenario as scenario_mod
-    base = np.stack([np.asarray(local_step_draws(t, fl.n_selected, fl))
+    base = np.stack([tprof.fetch(local_step_draws(t, fl.n_selected, fl))
                      for t in range(rounds)])
     g = scenario_mod.realize_grid(grid, (rounds, fl.n_selected))
     steps = scenario_mod.scale_steps(np.broadcast_to(
         base, g.comp.shape), g.comp)
     up_mask = (~g.drop).astype(np.float32)
     return steps, up_mask, g.lat_scale, g.corrupt
+
+
+def device_arrays(fed: FederatedData):
+    """(train, test, p): the train and test stacks of ``fed`` as
+    ``{"x", "y", "mask"}`` device arrays, and its size weights."""
+    put = tprof.to_device
+    train = {"x": put(fed.x), "y": put(fed.y), "mask": put(fed.mask)}
+    test = {"x": put(fed.test_x), "y": put(fed.test_y),
+            "mask": put(fed.test_mask)}
+    return train, test, put(fed.p)
 
 
 def _client_batch(data, ids):
@@ -227,7 +238,10 @@ def _local_updates_batch(model_cfg, params, batch, n_steps, fl: FLConfig,
             params, {"x": x, "y": y, "mask": m},
             lr=lr, mu=mu, n_steps=steps, max_steps=fl.max_local_steps)
 
-    return jax.vmap(one)(batch["x"], batch["y"], batch["mask"], n_steps)
+    # the device trace names the client solves by this scope
+    with jax.named_scope("local_solve"):
+        return jax.vmap(one)(batch["x"], batch["y"], batch["mask"],
+                             n_steps)
 
 
 def _local_updates(model_cfg, params, data, ids, n_steps, fl: FLConfig,
@@ -236,8 +250,10 @@ def _local_updates(model_cfg, params, data, ids, n_steps, fl: FLConfig,
     (deltas, grads, gammas).  ``hypers`` carries the traced lr/mu (the
     engines always pass it; ``None`` falls back to the config's floats for
     direct callers and shape-only ``eval_shape`` probes)."""
-    return _local_updates_batch(model_cfg, params, _client_batch(data, ids),
-                                n_steps, fl, hypers)
+    with jax.named_scope("local_solve"):
+        batch = _client_batch(data, ids)
+    return _local_updates_batch(model_cfg, params, batch, n_steps, fl,
+                                hypers)
 
 
 def apply_corruption(deltas, grads, corrupt):
@@ -572,7 +588,7 @@ def sync_round_clock(fleet, cost, probe_cost, sizes, algo: str,
         phase_cost = RoundCost(
             flops_per_step_example=cost.flops_per_step_example,
             down_bytes=0.0, up_bytes=probe_cost.down_bytes)
-    plan = plan_sync_round(fleet, ids, np.asarray(n_steps), phase_cost,
+    plan = plan_sync_round(fleet, ids, tprof.fetch(n_steps), phase_cost,
                            start=start, n_examples=sizes[ids],
                            lat_scale=lat_scale)
     clock_now = plan.round_end
@@ -625,11 +641,7 @@ def run_federated(model_cfg, fed: FederatedData, fl: FLConfig, rounds: int,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(fl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = device_arrays(fed)
 
         hist: Dict[str, List[float]] = {"round": [], "train_loss": [],
                                         "test_acc": [], "train_acc": []}
@@ -659,10 +671,10 @@ def run_federated(model_cfg, fed: FederatedData, fl: FLConfig, rounds: int,
                 n_steps = local_step_draws(t, fl.n_selected, fl)
                 up_mask = corrupt = None
             else:
-                n_steps = jnp.asarray(sc_steps[t])
-                up_mask = jnp.asarray(sc_mask[t])
+                n_steps = tprof.to_device(sc_steps[t])
+                up_mask = tprof.to_device(sc_mask[t])
                 corrupt = None if sc_corr is None \
-                    else jnp.asarray(sc_corr[t])
+                    else tprof.to_device(sc_corr[t])
             key, sub = jax.random.split(key)
             new_params, diag = fl_round(model_cfg, fl_t, params, train, p,
                                         sub, n_steps, sel_probs, hypers,
@@ -673,8 +685,8 @@ def run_federated(model_cfg, fed: FederatedData, fl: FLConfig, rounds: int,
             if fleet is not None:
                 clock_now = sync_round_clock(
                     fleet, cost, probe_cost, sizes, fl.algo,
-                    np.asarray(diag["ids"]),
-                    np.asarray(diag["ids2"]) if "ids2" in diag else None,
+                    tprof.fetch(diag["ids"]),
+                    tprof.fetch(diag["ids2"]) if "ids2" in diag else None,
                     n_steps, clock_now,
                     lat_scale=None if sc_lat is None else sc_lat[t])
             if use_server_opt:
@@ -690,13 +702,13 @@ def run_federated(model_cfg, fed: FederatedData, fl: FLConfig, rounds: int,
                 tr_loss, tr_acc = eval_global(model_cfg, params, train, p)
                 _, te_acc = eval_global(model_cfg, params, test, p)
                 hist["round"].append(t)
-                hist["train_loss"].append(float(tr_loss))
-                hist["train_acc"].append(float(tr_acc))
-                hist["test_acc"].append(float(te_acc))
+                hist["train_loss"].append(tprof.fetch_float(tr_loss))
+                hist["train_acc"].append(tprof.fetch_float(tr_acc))
+                hist["test_acc"].append(tprof.fetch_float(te_acc))
                 if fleet is not None:
                     hist["wall_clock"].append(clock_now)
     with prof.phase("collect"):
-        ids_np = np.stack([np.asarray(i) for i in ids_all]) \
+        ids_np = np.stack([tprof.fetch(i) for i in ids_all]) \
             if ids_all else None
         metrics = None
         if fl.telemetry:

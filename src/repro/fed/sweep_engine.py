@@ -52,6 +52,7 @@ from repro.fed import simulator
 from repro.fed import server_opt as sopt
 from repro.models import small
 from repro.sysmodel import round_cost_for
+from repro.telemetry import profiler as tprof
 
 AnyConfig = Union[simulator.FLConfig, async_lib.AsyncFLConfig]
 
@@ -142,7 +143,7 @@ class SweepSpec:
         (base value where a member doesn't override) — the axis the sweep
         programs vmap over."""
         return {
-            name: jnp.asarray(
+            name: tprof.to_device(
                 [float(o.get(name, getattr(self.base, name)))
                  for o in self.overrides], jnp.float32)
             for name in sweepable_fields(self.base)}
@@ -259,11 +260,7 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(base.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = simulator.device_arrays(fed)
         fspec = flat_lib.spec_of(params)
         w0 = flat_lib.ravel(fspec, params)
         w0_S = jnp.broadcast_to(w0, (S,) + w0.shape)
@@ -275,9 +272,10 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
             sc_steps, sc_mask, sc_lat, sc_corr = \
                 simulator.scenario_round_inputs(base, rounds, sc)
             keys = scan_engine._split_chain(key, rounds)
-            steps = jnp.asarray(sc_steps)
-            up_mask = jnp.asarray(sc_mask)
-            corrupt = None if sc_corr is None else jnp.asarray(sc_corr)
+            steps = tprof.to_device(sc_steps)
+            up_mask = tprof.to_device(sc_mask)
+            corrupt = None if sc_corr is None \
+                else tprof.to_device(sc_corr)
         # uniform across members (SweepSpec validates), so member 0
         # decides — the same predicate each member's solo run applies
         use_so = _uses_server_opt(spec.member(0))
@@ -304,14 +302,14 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
                 (fleet.n_devices, fed.n_devices)
             clocks = scan_engine.sync_clock_replay(
                 model_cfg, params, fed, base.algo, fleet,
-                np.asarray(ys["ids"]),
-                np.asarray(ys["ids2"]) if "ids2" in ys else None,
-                np.asarray(steps), rounds, lat_scale=sc_lat)
+                tprof.fetch(ys["ids"]),
+                tprof.fetch(ys["ids2"]) if "ids2" in ys else None,
+                tprof.fetch(steps), rounds, lat_scale=sc_lat)
         hists = scan_engine.eval_history_replay_sweep(
             model_cfg, fspec, train, test, p, ys["params"], rounds,
             eval_every, clocks)
     with prof.phase("collect"):
-        ids_np = np.asarray(ys["ids"])
+        ids_np = tprof.fetch(ys["ids"])
         shared = None
         if base.telemetry:
             # the network series and selection entropy are timeline-only —
@@ -325,7 +323,7 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
         for i in range(S):
             metrics = None
             if base.telemetry:
-                metrics = {k: np.asarray(v[:, i])
+                metrics = {k: tprof.fetch(v[:, i])
                            for k, v in ys["metrics"].items()}
                 metrics.update(shared)
             results.append(simulator.FedRunResult(
@@ -453,11 +451,7 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(base.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = simulator.device_arrays(fed)
         sizes = np.asarray(fed.mask.sum(axis=1))
         cost = round_cost_for(model_cfg, params,
                               uploads_gradient="folb" in base.algo)
@@ -484,14 +478,15 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
         with prof.phase("scan"):
             w_final_S, ws = sweep_scan_deadline(
                 model_cfg, afl_t, fspec, w0_S, pend0_S, train, p,
-                jnp.asarray(plan.keys), jnp.asarray(plan.ids),
-                jnp.asarray(plan.n_steps),
-                jnp.asarray(plan.arrived, jnp.float32),
-                jnp.asarray(plan.store_slot), jnp.asarray(plan.due_slot),
-                jnp.asarray(plan.due_mask), jnp.asarray(plan.due_tau),
-                jnp.asarray(plan.fast), hypers_S, sel_probs,
+                tprof.to_device(plan.keys), tprof.to_device(plan.ids),
+                tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.arrived, jnp.float32),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.due_slot),
+                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
+                tprof.to_device(plan.fast), hypers_S, sel_probs,
                 None if plan.corrupt is None
-                else jnp.asarray(plan.corrupt), mesh=mesh,
+                else tprof.to_device(plan.corrupt), mesh=mesh,
                 always_slow=not bool(np.asarray(plan.fast).any()))
             if base.telemetry or profiler is not None:
                 jax.block_until_ready(ws)
@@ -507,23 +502,25 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
             # the seed dispatches all start from the SAME initial params
             # but member-specific lr/mu: vmap the shared jitted seeding step
             seed_corr = (None if plan.seed_corrupt is None
-                         else jnp.asarray(plan.seed_corrupt))
+                         else tprof.to_device(plan.seed_corrupt))
             pend0_S = jax.vmap(
                 lambda pend, h: async_lib.fedbuff_seed_pool(
                     model_cfg, afl_t, params, pend, train,
-                    jnp.asarray(plan.seed_ids), jnp.asarray(plan.seed_steps),
-                    jnp.asarray(plan.seed_slots), h,
+                    tprof.to_device(plan.seed_ids),
+                    tprof.to_device(plan.seed_steps),
+                    tprof.to_device(plan.seed_slots), h,
                     seed_corr))(bcast(pend0), hypers_S)
         with prof.phase("scan"):
             w_final_S, ws = sweep_scan_fedbuff(
                 model_cfg, afl_t, fspec, w0_S, pend0_S, train,
-                jnp.asarray(plan.ids), jnp.asarray(plan.n_steps),
-                jnp.asarray(plan.store_slot), jnp.asarray(plan.flush_slot),
-                jnp.asarray(plan.tau), hypers_S,
+                tprof.to_device(plan.ids), tprof.to_device(plan.n_steps),
+                tprof.to_device(plan.store_slot),
+                tprof.to_device(plan.flush_slot),
+                tprof.to_device(plan.tau), hypers_S,
                 None if plan.flush_mask is None
-                else jnp.asarray(plan.flush_mask),
+                else tprof.to_device(plan.flush_mask),
                 None if plan.corrupt is None
-                else jnp.asarray(plan.corrupt), mesh=mesh)
+                else tprof.to_device(plan.corrupt), mesh=mesh)
             if base.telemetry or profiler is not None:
                 jax.block_until_ready(ws)
         clocks = plan.flush_clock
@@ -554,7 +551,7 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
         for i in range(S):
             metrics = None
             if base.telemetry:
-                metrics = {k: np.asarray(v[:, i])
+                metrics = {k: tprof.fetch(v[:, i])
                            for k, v in ws["metrics"].items()}
                 metrics.update(shared)
             results.append(simulator.FedRunResult(
@@ -731,8 +728,7 @@ def grid_scan_fedbuff(model_cfg, afl, spec: flat_lib.FlatSpec, w0_S,
 
 def _stack_to_rows(a, dtype=None):
     """(S, R, ...) plan array -> (R, S, ...) scan xs."""
-    out = np.moveaxis(np.asarray(a), 0, 1)
-    return jnp.asarray(out) if dtype is None else jnp.asarray(out, dtype)
+    return tprof.to_device(np.moveaxis(np.asarray(a), 0, 1), dtype)
 
 
 def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
@@ -764,11 +760,7 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(fl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = simulator.device_arrays(fed)
         fspec = flat_lib.spec_of(params)
         w0 = flat_lib.ravel(fspec, params)
         w0_S = jnp.broadcast_to(w0, (S,) + w0.shape)
@@ -798,8 +790,8 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
         if fleet is not None:
             assert fleet.n_devices == fed.n_devices, \
                 (fleet.n_devices, fed.n_devices)
-            ids_all = np.asarray(ys["ids"])
-            ids2_all = np.asarray(ys["ids2"]) if "ids2" in ys else None
+            ids_all = tprof.fetch(ys["ids"])
+            ids2_all = tprof.fetch(ys["ids2"]) if "ids2" in ys else None
             # per-cell clock replay: each cell's completeness-scaled steps
             # and jitter realization time its own wall clock (jitter-free
             # cells take the exact lat_scale=None host path a solo run
@@ -815,7 +807,7 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
             model_cfg, fspec, train, test, p, ys["params"], rounds,
             eval_every, clocks_S)
     with prof.phase("collect"):
-        ids_np = np.asarray(ys["ids"])
+        ids_np = tprof.fetch(ys["ids"])
         shared = None
         if fl.telemetry:
             # bytes are spent whether or not an upload decodes, so the
@@ -830,7 +822,7 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
         for i in range(S):
             metrics = None
             if fl.telemetry:
-                metrics = {k: np.asarray(v[:, i])
+                metrics = {k: tprof.fetch(v[:, i])
                            for k, v in ys["metrics"].items()}
                 metrics.update(shared)
             results.append(simulator.FedRunResult(
@@ -867,11 +859,7 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
         key = init_key if init_key is not None \
             else jax.random.PRNGKey(afl.seed)
         params = small.init_small(model_cfg, key)
-        train = {"x": jnp.asarray(fed.x), "y": jnp.asarray(fed.y),
-                 "mask": jnp.asarray(fed.mask)}
-        test = {"x": jnp.asarray(fed.test_x), "y": jnp.asarray(fed.test_y),
-                "mask": jnp.asarray(fed.test_mask)}
-        p = jnp.asarray(fed.p)
+        train, test, p = simulator.device_arrays(fed)
         sizes = np.asarray(fed.mask.sum(axis=1))
         cost = round_cost_for(model_cfg, params,
                               uploads_gradient="folb" in afl.algo)
@@ -895,7 +883,7 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
         with prof.phase("scan"):
             w_final_S, ws = grid_scan_deadline(
                 model_cfg, afl_t, fspec, w0_S, pend0_S, train, p,
-                jnp.asarray(gplan.keys), _stack_to_rows(gplan.ids),
+                tprof.to_device(gplan.keys), _stack_to_rows(gplan.ids),
                 _stack_to_rows(gplan.n_steps),
                 _stack_to_rows(gplan.arrived, jnp.float32),
                 _stack_to_rows(gplan.store_slot),
@@ -916,7 +904,7 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
             pend0 = async_lib.pool_init(model_cfg, sync_fl, params, train,
                                         gplan.n_slots)
             seed_corr = (None if gplan.seed_corrupt is None
-                         else jnp.asarray(gplan.seed_corrupt))
+                         else tprof.to_device(gplan.seed_corrupt))
             # every cell seeds from the same initial params but its own
             # dispatch stream: vmap the shared jitted seeding step over
             # the per-cell seed rows
@@ -927,9 +915,9 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
                     sslots, hypers, scorr),
                 in_axes=(0, 0, 0, 0,
                          0 if seed_corr is not None else None))(
-                bcast(pend0), jnp.asarray(gplan.seed_ids),
-                jnp.asarray(gplan.seed_steps),
-                jnp.asarray(gplan.seed_slots), seed_corr)
+                bcast(pend0), tprof.to_device(gplan.seed_ids),
+                tprof.to_device(gplan.seed_steps),
+                tprof.to_device(gplan.seed_slots), seed_corr)
         with prof.phase("scan"):
             w_final_S, ws = grid_scan_fedbuff(
                 model_cfg, afl_t, fspec, w0_S, pend0_S, train,
@@ -959,7 +947,7 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
             if afl.telemetry:
                 # network/pool series are plan-derived and per-cell: each
                 # cell's solo plan yields exactly its solo series
-                metrics = {k: np.asarray(v[:, i])
+                metrics = {k: tprof.fetch(v[:, i])
                            for k, v in ws["metrics"].items()}
                 if afl.mode == "deadline":
                     metrics.update(tmetrics.deadline_network_series(

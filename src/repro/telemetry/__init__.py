@@ -15,9 +15,16 @@ of adding dispatches:
     Chrome trace-event JSON (per-device download/compute/upload spans,
     round barriers, deadline cuts, flush instants) loadable in
     ``ui.perfetto.dev``.
-  * ``telemetry.profiler`` — context-manager host-phase timers
-    (setup / plan-build / scan / eval) attached to run results and
-    written into the ``profile`` section of ``BENCH_fed.json``.
+  * ``telemetry.profiler`` — the program's span-and-counter recorder:
+    context-manager host-phase timers (setup / plan-build / scan / eval)
+    attached to run results and written into the ``profile`` section of
+    ``BENCH_fed.json``, and, while recording is on (``recording()``,
+    ``enable()``, or the length of a ``fed.run`` call given
+    ``profiler=``), the engines' ``<phase>/<step>`` spans and the
+    ``d2h_fetches`` / ``h2d_bytes`` counters of its ``fetch`` /
+    ``to_device`` transfer helpers, each span a ``phase:<name>``
+    annotation on the JAX profiler trace; ``snapshot()`` sums them, and
+    a run's ``profile`` carries its own ``spans`` and ``counters``.
 """
 from repro.telemetry.metrics import (METRIC_KEYS, STALE_BINS,  # noqa: F401
                                      round_metrics, selection_entropy,

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import fed
 from repro.configs.paper_models import MCLR
-from repro.data.federated import stack_devices
+from repro.data.federated import LazyFederatedData, stack_devices
 from repro.data.synthetic import synthetic_alpha_beta
 from repro.fed.async_engine import (AsyncFLConfig, build_deadline_plan,
                                     build_fedbuff_plan,
@@ -32,12 +33,13 @@ from repro.fed.simulator import FLConfig, run_federated
 from repro.fed.sweep_engine import (SweepSpec, run_async_sweep_compiled,
                                     run_sweep_compiled)
 from repro.models import small
-from repro.sysmodel import (expected_latencies, heterogeneous_fleet,
-                            round_cost_for)
+from repro.sysmodel import (PopulationSpec, expected_latencies,
+                            heterogeneous_fleet, round_cost_for)
 from repro.telemetry import (METRIC_KEYS, STALE_BINS, NULL_PROFILER,
                              PhaseProfiler, profiler_for, round_metrics,
                              selection_entropy, validate_trace, write_trace)
 from repro.telemetry import metrics as tmetrics
+from repro.telemetry import profiler as tprof
 from repro.telemetry.trace import (REQUIRED_KEYS, deadline_trace_events,
                                    fedbuff_trace_events, queue_trace_events)
 
@@ -82,6 +84,17 @@ def _metrics_eq(a, b):
     assert set(a) == set(b)
     for k in a:
         assert np.array_equal(np.asarray(a[k]), np.asarray(b[k])), k
+
+
+_LAZY_DATA = LazyFederatedData(n_devices=200, seed=3, eval_cohort=12)
+_LAZY_FLEET = PopulationSpec(n_devices=200, seed=7, straggler_frac=0.4,
+                             straggler_slowdown=20.0)
+
+
+def _lazy_cfg():
+    return AsyncFLConfig(mode="deadline", algo="folb", n_selected=4,
+                         max_local_steps=3, staleness_alpha=0.5, seed=7,
+                         deadline=0.05, sampler="indexed")
 
 
 def _run(engine, cfg):
@@ -139,6 +152,23 @@ class TestTelemetryOffInvisible:
                 assert _tree_eq(ro.params, rn.params)
                 assert ro.history == rn.history
                 assert ro.metrics is None and rn.metrics is not None
+
+    @pytest.mark.parametrize("engine", ["scan", "lazy_deadline"])
+    def test_recording_is_invisible(self, engine):
+        def go():
+            if engine == "scan":
+                return fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS,
+                               fleet=_fleet, eval_every=2)
+            return fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS,
+                           fleet=_LAZY_FLEET, eval_every=2)
+        off = go()
+        with tprof.recording():
+            on = go()
+        assert tprof.snapshot()["spans"]
+        tprof.reset()
+        assert _tree_eq(off.params, on.params)
+        assert off.history == on.history
+        assert off.profile is None and on.profile is None
 
 
 # --------------------------------------------------------------------------
@@ -405,6 +435,188 @@ class TestProfiler:
             pass
         s = p.finish()
         assert "a" in s["phases"]
+
+
+def _count_reads(monkeypatch, prefix):
+    """Device arrays read to the host while the innermost recorded span's
+    name starts with ``prefix``, counted where jax hands the data over
+    (``np.asarray`` takes the buffer protocol, ``float()`` ``_value``),
+    so independently of the recorder's own counter."""
+    from jax._src.array import ArrayImpl
+    reads = [0]
+
+    def inside():
+        stack = tprof._THREAD.stack
+        return bool(stack) and stack[-1].name.startswith(prefix)
+
+    buffer, value = ArrayImpl.__buffer__, ArrayImpl._value
+
+    def counted_buffer(self, flags):
+        reads[0] += inside()
+        return buffer(self, flags)
+
+    def counted_value(self):
+        reads[0] += inside()
+        return value.fget(self)
+
+    monkeypatch.setattr(ArrayImpl, "__buffer__", counted_buffer)
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted_value))
+    return reads
+
+
+class TestRecorder:
+    def test_off_builds_no_annotation(self, monkeypatch):
+        def refuse(*a, **k):
+            raise AssertionError("TraceAnnotation built while off")
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+        tprof.reset()
+        assert tprof.span("eval/fetch") is NULL_PROFILER.phase("eval")
+        tprof.count("d2h_fetches")
+        tprof.to_device(np.zeros(3))
+        tprof.fetch(jnp.zeros(3))
+        fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS)
+        fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS, fleet=_LAZY_FLEET)
+        assert tprof.snapshot() == {"spans": {}, "counters": {},
+                                    "calls": 0}
+
+    def test_nesting_self_time_and_counters(self):
+        tprof.reset()
+        with tprof.recording():
+            with tprof.span("outer"):
+                tprof.count("n", 2)
+                with tprof.span("outer/inner") as inner:
+                    tprof.count("n")
+                    tprof.to_device(np.zeros(4, np.float32))
+                    tprof.fetch(jnp.zeros(2))
+                    tprof.fetch(np.zeros(2))      # on the host: no read
+                    sum(range(10000))
+            tprof.count("n", 5)
+        assert not tprof.is_recording()
+        outer = tprof.RECORDER.spans[-1]
+        assert inner.parent is outer and outer.parent is None
+        snap = tprof.snapshot()
+        o, i = snap["spans"]["outer"], snap["spans"]["outer/inner"]
+        assert o["count"] == i["count"] == 1
+        assert o["self_seconds"] == pytest.approx(
+            o["seconds"] - i["seconds"])
+        assert i["self_seconds"] == i["seconds"] > 0
+        assert snap["counters"] == {
+            "outer": {"n": 2},
+            "outer/inner": {"n": 1, "h2d_bytes": 16, "d2h_fetches": 1},
+            "": {"n": 5}}
+        tprof.reset()
+        assert tprof.snapshot()["spans"] == {}
+        assert not tprof.RECORDER.spans
+
+    def test_one_call_id_per_run(self):
+        tprof.reset()
+        with tprof.recording():
+            fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS)
+            fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS)
+        spans = list(tprof.RECORDER.spans)
+        assert tprof.snapshot()["calls"] == 2
+        tprof.reset()
+        calls = [s.call for s in spans]
+        assert set(calls) == {1, 2}
+        # each call's spans are contiguous, and none of them outlives it
+        assert calls == sorted(calls)
+        assert tprof._THREAD.call is None
+
+    def test_eval_fetches_are_three_per_eval_point(self, monkeypatch):
+        reads = _count_reads(monkeypatch, "eval/")
+        tprof.reset()
+        res = fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS, eval_every=1,
+                      profiler=PhaseProfiler())
+        prof = res.profile
+        assert prof["counters"]["eval/fetch"]["d2h_fetches"] == 3 * ROUNDS
+        assert reads[0] == 3 * ROUNDS
+        assert set(prof["spans"]) >= {"setup", "plan_build", "eval",
+                                      "plan_build/step_draws", "eval/fetch"}
+        # a call given a profiler records for its length only
+        assert not tprof.is_recording()
+        # the phase is the parent of its steps
+        spans = {s.name: s for s in tprof.RECORDER.spans}
+        assert spans["eval/fetch"].parent is spans["eval"]
+        tprof.reset()
+
+    def test_every_read_is_counted(self, monkeypatch):
+        """Every device read inside a recorded span goes through the
+        counted ``fetch``: the counter equals the reads jax saw."""
+        reads = _count_reads(monkeypatch, "")
+        tprof.reset()
+        with tprof.recording():
+            fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS, fleet=_fleet,
+                    profiler=PhaseProfiler())
+            fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS,
+                    fleet=_LAZY_FLEET, profiler=PhaseProfiler())
+        snap = tprof.snapshot()
+        tprof.reset()
+        fetches = sum(c.get("d2h_fetches", 0)
+                      for c in snap["counters"].values())
+        assert fetches == reads[0] > 0
+
+    def test_profiler_keeps_its_own_spans(self):
+        tprof.reset()
+        prof = PhaseProfiler()
+        fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS, profiler=prof)
+        before = prof.summary()
+        tprof.reset()
+        fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS)
+        assert prof.summary()["spans"] == before["spans"]
+        assert before["spans"]["eval/fetch"]["count"] == 1
+        assert tprof.snapshot()["spans"] == {}
+
+    def test_span_log_is_bounded(self, monkeypatch):
+        monkeypatch.setattr(tprof, "SPAN_LOG", 3)
+        tprof.reset()
+        with tprof.recording():
+            for _ in range(5):
+                with tprof.span("a"):
+                    pass
+        assert len(tprof.RECORDER.spans) == 3
+        assert tprof.snapshot()["spans"]["a"]["count"] == 5
+        monkeypatch.undo()
+        tprof.reset()
+
+    def test_threads_keep_their_own_parents(self):
+        import threading
+        tprof.reset()
+        inner = {}
+        started = threading.Barrier(2)
+
+        def work(name):
+            with tprof.span(name):
+                started.wait()
+                with tprof.span(name + "/step") as s:
+                    inner[name] = s
+                started.wait()
+
+        with tprof.recording():
+            threads = [threading.Thread(target=work, args=(n,))
+                       for n in ("a", "b")]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        for name, s in inner.items():
+            assert s.parent.name == name and s.parent.parent is None
+        tprof.reset()
+
+    def test_h2d_bytes_of_gathered_cohorts(self):
+        tprof.reset()
+        with tprof.recording():
+            res = fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS,
+                          fleet=_LAZY_FLEET)
+        snap = tprof.snapshot()
+        tprof.reset()
+        d = _LAZY_DATA.gather(res.ids)
+        want = sum(d[k].nbytes for k in ("x", "y", "mask"))
+        assert snap["counters"]["gather/to_device"]["h2d_bytes"] == want
+        assert {"plan_build/key_chain", "plan_build/step_draws",
+                "plan_build/timeline", "plan_build/pool",
+                "gather/synthesize", "gather/to_device", "gather/pool_init",
+                "eval/cohort", "eval/device", "eval/fetch"} == set(
+                    snap["spans"])
 
 
 # --------------------------------------------------------------------------
