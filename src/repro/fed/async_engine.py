@@ -395,8 +395,7 @@ def build_deadline_plan(afl: AsyncFLConfig, fleet: DeviceFleet, cost,
             ids = tprof.fetch(_draw_ids_chain(subs, probs, K), np.int32)
         keys = tprof.fetch(subs)
     with tprof.span("plan_build/step_draws"):
-        n_steps = np.stack([tprof.fetch(simulator.local_step_draws(t, K, afl))
-                            for t in range(rounds)]).astype(np.int32)
+        n_steps = simulator.local_step_table(rounds, K, afl)
     sc = scenario_mod.as_active(scenario)
     with tprof.span("plan_build/timeline"):
         if sc is None:

@@ -77,14 +77,15 @@ def draw_round_inputs(fl: simulator.FLConfig, rounds: int, init_key):
 
     Replicates the python-loop engine's host side exactly: the
     ``key, sub = jax.random.split(key)`` chain and the round-indexed numpy
-    step draws of ``simulator.local_step_draws`` — so a scan over these
-    inputs sees the same randomness as ``run_federated``.
+    step draws of ``simulator.local_step_table``, moved to the device in
+    one transfer — so a scan over these inputs sees the same randomness
+    as ``run_federated``.
     """
     with tprof.span("plan_build/key_chain"):
         subs = _split_chain(init_key, rounds)
     with tprof.span("plan_build/step_draws"):
-        steps = jnp.stack([simulator.local_step_draws(t, fl.n_selected, fl)
-                           for t in range(rounds)])
+        steps = tprof.to_device(
+            simulator.local_step_table(rounds, fl.n_selected, fl))
     return subs, steps
 
 

@@ -141,18 +141,33 @@ def hypers_of(cfg: "FLConfig") -> Dict[str, jnp.ndarray]:
     return tuning.hypers_of(cfg, SWEEPABLE_FIELDS)
 
 
+def _step_row(t: int, k: int, cfg) -> np.ndarray:
+    """Round ``t``'s ``k`` local-step budgets, int32, on the host."""
+    if not cfg.het_steps:
+        return np.full((k,), cfg.max_local_steps, np.int32)
+    return np.random.default_rng(10_000 + t).integers(
+        1, cfg.max_local_steps + 1, k).astype(np.int32)
+
+
+def local_step_table(rounds: int, k: int, cfg) -> np.ndarray:
+    """Device-capability protocol (paper Sec. VI-A): the per-round
+    local-step budgets of ``rounds`` rounds as one host ``(rounds, k)``
+    int32 table.  Row ``t`` is drawn from the round-indexed numpy seed
+    ``10_000 + t`` (``max_local_steps`` everywhere without
+    ``het_steps``), so every compared algorithm — and every engine, sync
+    and async — sees identical device capabilities.  `cfg` is any config
+    with het_steps/max_local_steps (FLConfig or AsyncFLConfig)."""
+    table = np.empty((rounds, k), np.int32)
+    for t in range(rounds):
+        table[t] = _step_row(t, k, cfg)
+    return table
+
+
 def local_step_draws(t: int, k: int, cfg) -> jnp.ndarray:
-    """Device-capability protocol (paper Sec. VI-A): per-round local-step
-    budgets drawn from a round-indexed numpy seed so every compared
-    algorithm — and both the sync and async engines; the bit-for-bit
-    parity depends on sharing this exact draw — sees identical device
-    capabilities.  `cfg` is any config with het_steps/max_local_steps
-    (FLConfig or AsyncFLConfig)."""
-    step_rng = np.random.default_rng(10_000 + t)
-    if cfg.het_steps:
-        return tprof.to_device(step_rng.integers(
-            1, cfg.max_local_steps + 1, k), jnp.int32)
-    return jnp.full((k,), cfg.max_local_steps, jnp.int32)
+    """Row ``t`` of ``local_step_table`` moved to the device: the python
+    loop's per-round budgets, the same integers as the compiled engines'
+    table by construction."""
+    return tprof.to_device(_step_row(t, k, cfg))
 
 
 def scenario_round_inputs(fl, rounds: int, scenario):
@@ -166,8 +181,7 @@ def scenario_round_inputs(fl, rounds: int, scenario):
     lat_scale or None, corrupt (R, K) f32 or None).
     """
     from repro.sysmodel import scenario as scenario_mod
-    base = np.stack([tprof.fetch(local_step_draws(t, fl.n_selected, fl))
-                     for t in range(rounds)])
+    base = local_step_table(rounds, fl.n_selected, fl)
     g = scenario_mod.realize(scenario, (rounds, fl.n_selected))
     steps = scenario_mod.scale_steps(base, g.comp)
     up_mask = (~g.drop).astype(np.float32)
@@ -183,8 +197,7 @@ def scenario_grid_round_inputs(fl, rounds: int, grid):
     (steps (S, R, K) int32, up_mask (S, R, K) f32, lat_scale (S, R, K)
     or None, corrupt (S, R, K) f32 or None)."""
     from repro.sysmodel import scenario as scenario_mod
-    base = np.stack([tprof.fetch(local_step_draws(t, fl.n_selected, fl))
-                     for t in range(rounds)])
+    base = local_step_table(rounds, fl.n_selected, fl)
     g = scenario_mod.realize_grid(grid, (rounds, fl.n_selected))
     steps = scenario_mod.scale_steps(np.broadcast_to(
         base, g.comp.shape), g.comp)

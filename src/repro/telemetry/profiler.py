@@ -33,11 +33,11 @@ Spans are named ``<phase>/<step>`` inside the engines' phases:
   ``eval/fetch``                the history lists read to the host
   ============================  ==========================================
 
-and two counters, counted by the helpers the engines move data through:
+and three counters, counted by the helpers the engines move data through:
 ``d2h_fetches`` (``fetch`` / ``fetch_float``: one per device array read
-to the host) and
-``h2d_bytes`` (``to_device``: the ``nbytes`` of each device array made
-from a host array).
+to the host), ``h2d_transfers`` (``to_device``: one per device array made
+from a host array) and ``h2d_bytes`` (``to_device``: the ``nbytes`` of
+each such device array).
 
 `PhaseProfiler` breaks one run's host time into named contiguous phases
 (`setup` / `plan_build` / `scan` / `eval` for the compiled engines;
@@ -236,10 +236,12 @@ def fetch_float(x) -> float:
 
 
 def to_device(x, dtype=None) -> jax.Array:
-    """``jnp.asarray(x, dtype)``: a host array moved to the device adds
-    the device array's ``nbytes`` to ``h2d_bytes``."""
+    """``jnp.asarray(x, dtype)``: a host array moved to the device counts
+    one ``h2d_transfers`` and adds the device array's ``nbytes`` to
+    ``h2d_bytes``."""
     out = jnp.asarray(x, dtype)
     if _recording and not isinstance(x, jax.Array):
+        RECORDER.add("h2d_transfers", 1)
         RECORDER.add("h2d_bytes", out.nbytes)
     return out
 

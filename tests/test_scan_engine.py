@@ -210,6 +210,54 @@ class TestInputs:
             assert (np.asarray(steps[t])
                     == np.asarray(local_step_draws(t, 6, fl))).all()
 
+    @pytest.mark.parametrize("het", [True, False])
+    def test_step_table_is_int32_rounds_by_k(self, het):
+        from repro.fed.simulator import local_step_table
+        fl = FLConfig(n_selected=6, max_local_steps=7, het_steps=het)
+        table = local_step_table(5, 6, fl)
+        assert isinstance(table, np.ndarray)
+        assert table.dtype == np.int32 and table.shape == (5, 6)
+        assert ((1 <= table) & (table <= 7)).all()
+        assert local_step_table(0, 6, fl).shape == (0, 6)
+
+    @pytest.mark.parametrize("het", [True, False])
+    def test_step_table_rows_are_the_loop_draws(self, het):
+        """The python loop's per-round draw is the table's row, and the
+        row is the paper protocol's round-indexed seed."""
+        from repro.fed.simulator import local_step_draws, local_step_table
+        fl = FLConfig(n_selected=6, max_local_steps=7, het_steps=het)
+        table = local_step_table(8, 6, fl)
+        for t in range(8):
+            row = local_step_draws(t, 6, fl)
+            assert row.dtype == np.int32
+            assert (np.asarray(row) == table[t]).all()
+            want = (np.random.default_rng(10_000 + t).integers(1, 8, 6)
+                    if het else np.full(6, 7))
+            assert (table[t] == want).all()
+
+    @pytest.mark.parametrize("het,grid,want", [
+        (True, False, [[1, 2, 3, 3], [3, 4, 1, 4], [4, 3, 1, 2]]),
+        (False, False, [[4, 3, 5, 5], [5, 5, 3, 5], [4, 4, 5, 4]]),
+        (True, True, [[[1, 2, 3, 3], [3, 4, 1, 4], [4, 3, 1, 2]],
+                      [[1, 2, 3, 3], [3, 2, 1, 4], [2, 2, 1, 2]]]),
+    ])
+    def test_scenario_steps_unchanged(self, het, grid, want):
+        """A fixed scenario's step budgets: the integers the engines
+        replayed when each round's draw went through the device."""
+        from repro.fed.simulator import (scenario_grid_round_inputs,
+                                         scenario_round_inputs)
+        from repro.sysmodel.scenario import ScenarioConfig, ScenarioGrid
+        fl = FLConfig(n_selected=4, max_local_steps=5, het_steps=het)
+        sc = ScenarioConfig(partial_prob=0.5, drop_prob=0.2, seed=3)
+        if grid:
+            steps = scenario_grid_round_inputs(fl, 3, ScenarioGrid((
+                sc, ScenarioConfig(partial_prob=0.9, completeness_min=0.2,
+                                   seed=5))))[0]
+        else:
+            steps = scenario_round_inputs(fl, 3, sc)[0]
+        assert steps.dtype == np.int32
+        assert steps.tolist() == want
+
     def test_deterministic_across_calls(self, fed_data):
         fl = FLConfig(algo="folb", n_selected=4, seed=7)
         h1 = run_federated_compiled(MCLR, fed_data, fl, rounds=3)

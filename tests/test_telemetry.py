@@ -502,11 +502,53 @@ class TestRecorder:
         assert i["self_seconds"] == i["seconds"] > 0
         assert snap["counters"] == {
             "outer": {"n": 2},
-            "outer/inner": {"n": 1, "h2d_bytes": 16, "d2h_fetches": 1},
+            "outer/inner": {"n": 1, "h2d_transfers": 1, "h2d_bytes": 16,
+                            "d2h_fetches": 1},
             "": {"n": 5}}
         tprof.reset()
         assert tprof.snapshot()["spans"] == {}
         assert not tprof.RECORDER.spans
+
+    def test_to_device_counts_host_arrays_only(self):
+        tprof.reset()
+        on_device = jnp.zeros(3)
+        with tprof.recording():
+            with tprof.span("a"):
+                tprof.to_device(np.zeros(4, np.float32))
+                tprof.to_device(np.arange(6), jnp.int32)
+                tprof.to_device(on_device)
+                tprof.to_device(on_device, jnp.int32)
+        snap = tprof.snapshot()
+        tprof.reset()
+        assert snap["counters"] == {"a": {"h2d_transfers": 2,
+                                          "h2d_bytes": 40}}
+
+    def test_step_draws_are_one_transfer_per_scan_call(self):
+        """The resident scan engine's step budgets reach the device as
+        one table: one transfer, no read, whatever the round count."""
+        tprof.reset()
+        with tprof.recording():
+            fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS)
+            fed.run(MCLR, _fed, _sync_cfg(False), 2 * ROUNDS)
+        snap = tprof.snapshot()
+        tprof.reset()
+        assert snap["spans"]["plan_build/step_draws"]["count"] == 2
+        assert snap["counters"]["plan_build/step_draws"] == {
+            "h2d_transfers": 2,
+            "h2d_bytes": 4 * 3 * ROUNDS * _sync_cfg(False).n_selected}
+
+    def test_deadline_step_draws_stay_on_the_host(self):
+        afl = _async_cfg(False, "deadline")
+        sp = deadline_selection_probs(afl, _fleet, _cost, _sizes)
+        tprof.reset()
+        with tprof.recording():
+            plan = build_deadline_plan(afl, _fleet, _cost, _sizes, ROUNDS,
+                                       jax.random.PRNGKey(7), sp)
+        snap = tprof.snapshot()
+        tprof.reset()
+        assert snap["spans"]["plan_build/step_draws"]["count"] == 1
+        assert "plan_build/step_draws" not in snap["counters"]
+        assert plan.n_steps.dtype == np.int32
 
     def test_one_call_id_per_run(self):
         tprof.reset()
