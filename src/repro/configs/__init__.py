@@ -21,6 +21,7 @@ _MODULES: Dict[str, str] = {
     "starcoder2-7b": "starcoder2_7b",
     "granite-20b": "granite_20b",
     "gemma-7b": "gemma_7b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2p5b",
     # paper's own experiment models (federated validation)
     "paper-mclr": "paper_models",
     "paper-mlp": "paper_models",

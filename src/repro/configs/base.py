@@ -24,7 +24,7 @@ ENCODER = "encoder"      # bidirectional attention + dense MLP (no causal mask)
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int              # the router's width: every expert of a layer
     top_k: int
     expert_d_ff: int
     n_shared_experts: int = 0
@@ -34,6 +34,41 @@ class MoEConfig:
     # 'tensor': expert d_ff sharded over model axis (works for any n_experts)
     # 'expert': experts sharded over model axis (requires divisibility)
     sharding: str = "tensor"
+    # This chip's share of an expert-parallel layer: it holds experts
+    # [held_offset, held_offset + n_held) and computes only their part of
+    # the layer's output (0 = all n_experts, the capacity-dispatch layer).
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> bool:
+        return self.n_held > 0
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeConfig:
+    """Rotary embedding of one attention kind.  ``yarn_factor`` > 0 applies
+    YaRN (arXiv:2309.00071) as Hugging Face's ``_compute_yarn_parameters``
+    does: frequencies ramped between extrapolation and interpolation by
+    ``factor`` over the dimensions between ``beta_fast`` and ``beta_slow``
+    rotations in ``original_max_position`` positions, and cos/sin scaled by
+    ``attention_factor``."""
+    theta: float = 10000.0
+    yarn_factor: float = 0.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One attention kind of a mixed stack: a causal window of ``window``
+    keys (the query's own and ``window - 1`` before it; 0 = full causal)
+    and its rotary embedding."""
+    name: str
+    window: int
+    rope: RopeConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +114,12 @@ class ArchConfig:
     # modality stub: number of frontend embedding positions (audio frames /
     # vision patches) prepended to the token sequence.  0 = pure text.
     frontend_positions: int = 0
+    # mixed attention (family "moe"): the period of attention kinds, one per
+    # layer, repeated n_layers / len(attn_period) times; each kind sets its
+    # own window and RoPE in place of sliding_window and rope_theta.  The
+    # period is runs of one kind each (e.g. three window layers, then one
+    # full layer), scanned as a super-group.
+    attn_period: Tuple[AttnKind, ...] = ()
     # provenance
     source: str = ""
     # numerics
@@ -102,12 +143,25 @@ class ArchConfig:
             return True
         return self.sliding_window > 0
 
+    def attn_runs(self) -> Tuple[Tuple[AttnKind, int], ...]:
+        """The attention period as runs of one kind: ((kind, length), ...)."""
+        runs = []
+        for kind in self.attn_period:
+            if runs and runs[-1][0] == kind:
+                runs[-1][1] += 1
+            else:
+                runs.append([kind, 1])
+        return tuple((k, n) for k, n in runs)
+
     def block_pattern(self) -> Tuple[Tuple[str, int], ...]:
         """Return ((block_kind, repeat), ...) describing the stack as groups
         of homogeneous scannable blocks.  Heterogeneous stacks (zamba, xlstm)
         are expressed as repeated super-groups."""
         if self.family in ("encoder", "audio"):
             return ((ENCODER, self.n_layers),)
+        if self.family == "moe" and self.attn_period:
+            assert self.n_layers % len(self.attn_period) == 0
+            return tuple((MOE, n) for _, n in self.attn_runs())
         if self.family == "moe":
             return ((MOE, self.n_layers),)
         if self.family == "hybrid":
@@ -127,6 +181,8 @@ class ArchConfig:
             return self.n_layers // self.shared_attn_every
         if self.xlstm is not None:
             return self.n_layers // self.xlstm.slstm_every
+        if self.attn_period:
+            return self.n_layers // len(self.attn_period)
         return 1
 
     def reduced(self, n_layers: int = 2, d_model: int = 256,
@@ -193,7 +249,7 @@ def n_params(cfg: ArchConfig) -> int:
         total += cfg.n_layers * (per_attn + per_mlp)
     elif cfg.family == "moe":
         m = cfg.moe
-        per_moe = m.n_experts * glu * d * m.expert_d_ff \
+        per_moe = (m.n_held or m.n_experts) * glu * d * m.expert_d_ff \
             + m.n_shared_experts * glu * d * m.shared_d_ff + d * m.n_experts
         total += cfg.n_layers * (per_attn + per_moe)
     elif cfg.family == "hybrid":
@@ -227,6 +283,8 @@ def n_active_params(cfg: ArchConfig) -> int:
     m = cfg.moe
     d = cfg.d_model
     glu = 3 if cfg.act in ("silu", "geglu") else 2
-    all_expert = cfg.n_layers * m.n_experts * glu * d * m.expert_d_ff
-    active_expert = cfg.n_layers * m.top_k * glu * d * m.expert_d_ff
+    all_expert = cfg.n_layers * (m.n_held or m.n_experts) * glu * d \
+        * m.expert_d_ff
+    active_expert = cfg.n_layers * min(m.top_k, m.n_held or m.n_experts) \
+        * glu * d * m.expert_d_ff
     return int(n_params(cfg) - all_expert + active_expert)

@@ -126,6 +126,11 @@ def folb_round(cfg, rc: RoundConfig, params: Params, batch: Dict,
             return constrain(t)
         return jax.lax.with_sharding_constraint(t, acc_shardings)
 
+    compute = lambda w: tree.tree_cast(w, jnp.dtype(cfg.param_dtype))
+    vgs = jax.value_and_grad(
+        lambda p, b: model_lib.loss_and_stats(cfg, p, b, rc.remat,
+                                              rc.remat_group), has_aux=True)
+
     def local_solve(g0, cb):
         """E prox-SGD steps on h_k(w, w^t), entirely in the parameter
         layout and dtype.  Updates in the device dtype (bf16 at scale) are
@@ -134,23 +139,29 @@ def folb_round(cfg, rc: RoundConfig, params: Params, batch: Dict,
         differ by far less than 2×), so no fp32 parameter-layout state is
         ever needed (§Perf B1/B2: fp32 temporaries and in-loop
         fsdp↔param resharding previously cost 10.6–17.7 TB/chip/round of
-        all-gathers on mixtral train_4k).  g0 = ∇F_k(w^t) is reused as the
-        first step's gradient (the prox term vanishes at w = w^t)."""
-        grad_fn = jax.grad(loss_fn)
+        all-gathers on mixtral train_4k).  Params held in a wider dtype
+        than ``cfg.param_dtype`` (float32 master weights) are the iterate's
+        dtype instead: the model and its gradients run in
+        ``cfg.param_dtype``, the steps in the master dtype.  g0 =
+        ∇F_k(w^t) is reused as the first step's gradient (the prox term
+        vanishes at w = w^t).  Returns (w, the held-expert stats of the
+        E − 1 further gradient evaluations, summed)."""
         sgd = lambda w, g: constrain(jax.tree.map(
             lambda wl, gl: wl - jnp.asarray(rc.lr, wl.dtype)
             * gl.astype(wl.dtype), w, g))
         w = sgd(params, g0)
-        if rc.local_steps > 1:
-            def body(w, _):
-                g = jax.tree.map(
-                    lambda gl, wl, rl: gl + jnp.asarray(mu, gl.dtype)
-                    * (wl - rl).astype(gl.dtype),
-                    grad_fn(w, cb), w, params)
-                return sgd(w, g), None
+        if rc.local_steps == 1:
+            return w, None
 
-            w, _ = jax.lax.scan(body, w, None, length=rc.local_steps - 1)
-        return w
+        def body(w, _):
+            (_, st), g = vgs(compute(w), cb)
+            g = jax.tree.map(
+                lambda gl, wl, rl: gl.astype(wl.dtype)
+                + jnp.asarray(mu, wl.dtype) * (wl - rl), g, w, params)
+            return sgd(w, g), st
+
+        w, sts = jax.lax.scan(body, w, None, length=rc.local_steps - 1)
+        return w, jax.tree.map(lambda a: jnp.sum(a, axis=0), sts)
 
     if rc.agg_backend == "flat" and rc.algo in ("folb", "folb_het"):
         # shared-path reroute: ONE client sweep emits flat bf16 deltas and
@@ -162,23 +173,26 @@ def folb_round(cfg, rc: RoundConfig, params: Params, batch: Dict,
         from repro.core import flat as flat_lib
         from repro.kernels import folb_aggregate as _folb
         from repro.kernels import ops as kernel_ops
+        D = sum(x.size for x in jax.tree.leaves(params))
         pad_to = (_folb.shard_alignment(mesh) if mesh is not None
-                  else _folb.TILE_D)
+                  else _folb.pad_unit(D))
         spec = flat_lib.spec_of(params, pad_to=pad_to)
         bspec = flat_lib.with_buf_dtype(spec, rc.agg_dtype)
 
         def client(lsum, cb):
-            l, g_k = vg(params, cb)
+            (l, st), g_k = vgs(compute(params), cb)
             g_k = constrain(g_k)
-            w_new = local_solve(g_k, cb)
+            w_new, st_more = local_solve(g_k, cb)
+            if st_more is not None:
+                st = jax.tree.map(jnp.add, st, st_more)
             delta = jax.tree.map(jnp.subtract, w_new, params)
             gamma = (_gamma(loss_fn, w_new, params, cb, g_k, mu)
                      if rc.algo == "folb_het"
                      else jnp.zeros((), jnp.float32))
             return lsum + l, (flat_lib.ravel(bspec, delta),
-                              flat_lib.ravel(bspec, g_k), gamma)
+                              flat_lib.ravel(bspec, g_k), gamma, l, st)
 
-        loss_sum, (deltas, grads, gammas) = jax.lax.scan(
+        loss_sum, (deltas, grads, gammas, losses, stats) = jax.lax.scan(
             client, jnp.zeros((), jnp.float32), batch)
         w_flat = flat_lib.ravel(spec, params)
         pg = rc.psi * gammas if rc.algo == "folb_het" else None
@@ -188,9 +202,13 @@ def folb_round(cfg, rc: RoundConfig, params: Params, batch: Dict,
         g1_sq = jnp.sum(jnp.mean(grads.astype(jnp.float32), axis=0) ** 2)
         metrics = {
             "client_loss": loss_sum / K,
+            "client_losses": losses,
             "g1_norm": jnp.sqrt(g1_sq),
             "weight_denom": jnp.sum(jnp.abs(scores)),
             "scores": scores,
+            # held-expert layers: summed over the round's K x E gradient
+            # evaluations, per layer
+            **jax.tree.map(lambda a: jnp.sum(a, axis=0), stats),
         }
         return flat_lib.unravel(spec, new_flat), metrics
 
@@ -220,7 +238,7 @@ def folb_round(cfg, rc: RoundConfig, params: Params, batch: Dict,
     def p2(carry, cb):
         acc, denom = carry
         g_k = constrain(jax.grad(loss_fn)(params, cb))  # see p1 note
-        w_new = local_solve(g_k, cb)
+        w_new, _ = local_solve(g_k, cb)
         # delta: exact bf16 subtract in the param layout, reshard to the
         # accumulator layout (param->fsdp is a free local slice), THEN
         # upcast — the only fp32 copy lives in the small fsdp layout.

@@ -54,6 +54,17 @@ _VMEM_BLOCK_BUDGET = 8 << 20
 _INTERPRET_MAX_GRID = 512   # interpret mode unrolls the grid at trace time
 
 
+def pad_unit(D: int) -> int:
+    """Padding unit of a D-parameter flat buffer: the longest tile (a
+    power-of-two multiple of TILE_D, at most _MAX_TILE_D) whose padding
+    costs at most 1/1024 of D, so ``_pick_tile`` can reach long tiles at
+    large D; TILE_D for D up to 2**20."""
+    t = TILE_D
+    while t < _MAX_TILE_D and 2 * t * 1024 <= D:
+        t *= 2
+    return t
+
+
 def _pick_tile(D: int, K: int, itemsize: int, n_streams: int = 1) -> int:
     """Tile length along D for a kernel streaming ``n_streams`` ``(K, D)``
     inputs of ``itemsize`` bytes per element.

@@ -29,7 +29,9 @@ import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import folb_aggregate as _folb
+from repro.kernels import moe_gmm as _gmm
 from repro.kernels import slstm_scan as _slstm
+from repro.kernels import splash_attn as _splash
 from repro.kernels import ssm_scan as _ssd
 
 
@@ -57,6 +59,29 @@ def flash_attention(q, k, v, causal: bool = True, sliding_window: int = 0,
     return _by_platform(_fa.flash_attention, q, k, v, causal=causal,
                         sliding_window=sliding_window,
                         block_q=block_q, block_k=block_k)
+
+
+@functools.partial(jax.jit, static_argnames=("window",))
+def attention_window(q, k, v, window: int):
+    """Causal attention over the last ``window`` keys (splash kernel; the
+    blocks outside the window are skipped).  q: (B, S, H, hd), k/v:
+    (B, S, KV, hd) -> (B, S, H * hd)."""
+    return _by_platform(_splash.splash_attention, q, k, v, window=window)
+
+
+@jax.jit
+def attention_full(q, k, v):
+    """Causal attention over every earlier key (splash kernel; the blocks
+    above the diagonal are skipped).  Shapes as ``attention_window``."""
+    return _by_platform(_splash.splash_attention, q, k, v, window=0)
+
+
+@jax.jit
+def moe_grouped_ffn(xs, w_gate, w_up, w_down, sizes):
+    """SiLU-gated FFN of each held expert over its rows of ``xs`` (sorted
+    by expert, ``sizes`` rows each; rows past them come back 0) with the
+    megablox grouped matrix products."""
+    return _by_platform(_gmm.glu_ffn, xs, w_gate, w_up, w_down, sizes)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -2,6 +2,8 @@
 for every model input (weak-type-correct, shardable, no device allocation).
 
   train_4k     seq_len=4,096    global_batch=256   (training: one FL round)
+  train_8k     seq_len=8,192    global_batch=8     (one chip's FL round:
+                                                    K clients x 8/K seqs)
   prefill_32k  seq_len=32,768   global_batch=32    (inference prefill)
   decode_32k   seq_len=32,768   global_batch=128   (decode: 1 token + cache)
   long_500k    seq_len=524,288  global_batch=1     (long-context decode)
@@ -34,6 +36,7 @@ class InputShape:
 
 SHAPES: Dict[str, InputShape] = {
     "train_4k": InputShape("train_4k", 4_096, 256, "train"),
+    "train_8k": InputShape("train_8k", 8_192, 8, "train"),
     "prefill_32k": InputShape("prefill_32k", 32_768, 32, "prefill"),
     "decode_32k": InputShape("decode_32k", 32_768, 128, "decode"),
     "long_500k": InputShape("long_500k", 524_288, 1, "decode"),
@@ -44,6 +47,9 @@ def combo_supported(cfg: ArchConfig, shape: InputShape) -> Tuple[bool, str]:
     """(supported, reason-if-not)."""
     if shape.kind == "decode" and not cfg.supports_decode:
         return False, "encoder-only architecture has no decode step"
+    if shape.kind != "train" and cfg.attn_period:
+        return False, ("mixed-attention stack (attn_period): training "
+                       "path only")
     if shape.name == "long_500k" and not cfg.sub_quadratic:
         return False, ("pure full-attention arch; long_500k requires "
                        "sub-quadratic attention (DESIGN.md §6)")

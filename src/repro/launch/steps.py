@@ -57,18 +57,18 @@ def build_train_step(cfg: ArchConfig, mesh: Mesh, rc: RoundConfig,
     if rc.fsdp_params or (_n_params(cfg) * 2 / mesh.shape["model"]) > 3 * 2**30:
         p_shard = _named(mesh, specs_lib.fsdp_param_specs(cfg, ps, mesh))
 
-    def step(params, batch):
+    def train_round(params, batch):
         with use_sharding(mesh):
             new_params, metrics = folb_round(cfg, rc, params, batch,
                                              param_shardings=p_shard,
                                              acc_shardings=acc_shard)
         return new_params, metrics
 
-    metrics_shard = {"client_loss": repl, "g1_norm": repl,
-                     "weight_denom": repl, "scores": repl}
-    fn = jax.jit(step,
+    # the params may be held in a wider dtype than cfg.param_dtype (float32
+    # master weights): the shardings fit any dtype, the round keeps it
+    fn = jax.jit(train_round,
                  in_shardings=(p_shard, b_shard),
-                 out_shardings=(p_shard, metrics_shard),
+                 out_shardings=(p_shard, repl),
                  donate_argnums=(0,))
     return fn, (ps, batch)
 

@@ -47,15 +47,16 @@ def init_attention(cfg, key) -> Params:
     }
 
 
-def _qkv(cfg, p: Params, x: jnp.ndarray, positions: jnp.ndarray):
+def _qkv(cfg, p: Params, x: jnp.ndarray, positions: jnp.ndarray,
+         rope=None):
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     q = layers.apply_linear(p["wq"], x).reshape(B, S, cfg.n_heads, hd)
     k = layers.apply_linear(p["wk"], x).reshape(B, S, cfg.n_kv_heads, hd)
     v = layers.apply_linear(p["wv"], x).reshape(B, S, cfg.n_kv_heads, hd)
-    if cfg.rope_theta > 0:
-        q = layers.apply_rope(cfg, q, positions)
-        k = layers.apply_rope(cfg, k, positions)
+    if rope is not None or cfg.rope_theta > 0:
+        q = layers.apply_rope(cfg, q, positions, rope)
+        k = layers.apply_rope(cfg, k, positions, rope)
     return q, k, v
 
 
@@ -195,6 +196,25 @@ def attention_forward(cfg, p: Params, x: jnp.ndarray,
     q, k, v = _qkv(cfg, p, x, positions)
     out = _attend_auto(cfg, q, k, v)
     return layers.apply_linear(p["wo"], out)
+
+
+def attention_kind_forward(cfg, kind, p: Params, x: jnp.ndarray
+                           ) -> jnp.ndarray:
+    """Causal self attention of one kind of a mixed stack (``kind`` an
+    ``AttnKind``: its window and RoPE), training path: the splash kernel
+    of ``kernels.ops.attention_window`` or ``attention_full``, which
+    computes only the blocks its mask reaches.  Named scope
+    ``attn_<kind.name>``."""
+    from repro.kernels import ops as kernel_ops
+    B, S, _ = x.shape
+    with jax.named_scope(f"attn_{kind.name}"):
+        positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+        q, k, v = _qkv(cfg, p, x, positions, kind.rope)
+        if kind.window:
+            out = kernel_ops.attention_window(q, k, v, kind.window)
+        else:
+            out = kernel_ops.attention_full(q, k, v)
+        return layers.apply_linear(p["wo"], out)
 
 
 # ------------------------------------------------------------- KV cache

@@ -1,10 +1,12 @@
 """Shared neural-net building blocks (pure jnp, functional, pytree params)."""
 from __future__ import annotations
 
+import math
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.sharding import context as shard_ctx
 
@@ -83,13 +85,51 @@ def rope_freqs(cfg, head_dim: int) -> jnp.ndarray:
     return cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
 
 
-def apply_rope(cfg, x: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_freqs(rope, head_dim: int) -> np.ndarray:
+    """Inverse frequencies (head_dim/2,) of ``rope`` (a ``RopeConfig``):
+    plain RoPE, or YaRN as Hugging Face's ``_compute_yarn_parameters``
+    (truncated correction range): dimensions that turn more than
+    ``beta_fast`` times in ``original_max_position`` positions keep their
+    frequency, those under ``beta_slow`` are divided by the factor, and a
+    linear ramp blends the ones between."""
+    half = head_dim // 2
+    pos_freqs = rope.theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                               / head_dim)
+    extra = 1.0 / pos_freqs
+    if not rope.yarn_factor:
+        return extra.astype(np.float32)
+    inter = 1.0 / (rope.yarn_factor * pos_freqs)
+
+    def corr(rot):
+        return (head_dim * math.log(rope.original_max_position
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(corr(rope.beta_fast)), 0)
+    high = min(math.ceil(corr(rope.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low) / (high - low),
+                   0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(cfg, x: jnp.ndarray, positions: jnp.ndarray,
+               rope=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  ``rope`` (a
+    ``RopeConfig``) overrides ``cfg.rope_theta``; its ``attention_factor``
+    scales cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(cfg, hd)                          # (hd/2,)
+    scale = 1.0
+    if rope is None:
+        freqs = rope_freqs(cfg, hd)                      # (hd/2,)
+    else:
+        freqs = jnp.asarray(yarn_freqs(rope, hd))
+        scale = rope.attention_factor
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
-    cos = jnp.cos(ang)[..., :, None, :]                  # (..., S, 1, hd/2)
-    sin = jnp.sin(ang)[..., :, None, :]
+    cos = (jnp.cos(ang) * scale)[..., :, None, :]        # (..., S, 1, hd/2)
+    sin = (jnp.sin(ang) * scale)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

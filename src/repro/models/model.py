@@ -11,6 +11,11 @@ stack topologies:
                    parameter attention block applied per super-group.
   * xlstm        — outer scan over super-groups of (slstm_every-1) mLSTM
                    blocks + 1 sLSTM block.
+  * period       — moe with mixed attention (``cfg.attn_period``): outer
+                   scan over super-groups, one per period, each an inner
+                   scan over every run of one attention kind (e.g. three
+                   window layers, then one full layer) of held-expert MoE
+                   blocks.  Training path only: prefill and decode raise.
 
 All functions are pure; `cfg` is static.  Dtype: params in
 ``cfg.param_dtype``, softmax/normalizers/recurrences in fp32.
@@ -63,6 +68,8 @@ def _stack_init(cfg, kind, key, n: int) -> Params:
 
 
 def topology(cfg: ArchConfig) -> str:
+    if cfg.attn_period:
+        return "period"
     if cfg.family == "hybrid":
         return "hybrid"
     if cfg.xlstm is not None:
@@ -87,6 +94,14 @@ def init_params(cfg: ArchConfig, key) -> Params:
     topo = topology(cfg)
     if topo == "homo":
         params["layers"] = _stack_init(cfg, homo_kind(cfg), k_body, cfg.n_layers)
+    elif topo == "period":
+        # one stacked (G, run length, ...) tree per run of the period
+        G = cfg.n_super_groups()
+        params["period"] = tuple(
+            jax.vmap(lambda k, n=n: _stack_init(cfg, MOE, k, n))(
+                jax.random.split(kr, G))
+            for kr, (_, n) in zip(jax.random.split(k_body, len(cfg.attn_runs())),
+                                  cfg.attn_runs()))
     elif topo == "hybrid":
         G = cfg.n_super_groups()
         g = cfg.shared_attn_every
@@ -174,14 +189,59 @@ def _scan_blocks(cfg, kind: str, stacked: Params, x: jnp.ndarray,
     return x, aux
 
 
+def _apply_kind_block(cfg, kind, p: Params, x: jnp.ndarray):
+    """One block of a mixed-attention stack: attention of ``kind`` (an
+    ``AttnKind``), then the held-expert MoE.  Returns (x, MoE stats)."""
+    x = x + attention.attention_kind_forward(
+        cfg, kind, p["attn"], layers.apply_norm(cfg, p["attn_norm"], x))
+    y, stats = moe.moe_held_forward(
+        cfg, p["moe"], layers.apply_norm(cfg, p["moe_norm"], x))
+    return x + y, stats
+
+
+def _scan_period(cfg, runs: Tuple[Params, ...], x: jnp.ndarray,
+                 remat: bool) -> Tuple[jnp.ndarray, Dict]:
+    """Scan the super-groups of a mixed-attention stack; each layer is
+    checkpointed when ``remat``.  Returns (x, stats stacked per layer in
+    depth order: leading axis n_layers)."""
+    def layer(kind):
+        def body(h, lp):
+            h, st = _apply_kind_block(cfg, kind, lp, h)
+            h = shard_ctx.constrain(h, "batch", None, "model")
+            return shard_ctx.barrier(h), st
+        return jax.checkpoint(body) if remat else body
+
+    def super_body(h, group):
+        sts = []
+        for (kind, _), run in zip(cfg.attn_runs(), group):
+            h, st = jax.lax.scan(layer(kind), h, run)
+            sts.append(st)
+        return h, jax.tree.map(lambda *a: jnp.concatenate(a), *sts)
+
+    x, stats = jax.lax.scan(super_body, x, runs)
+    return x, jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), stats)
+
+
 # =================================================================== forward
 
 def backbone(cfg: ArchConfig, params: Params, h: jnp.ndarray,
              remat: bool = False, remat_group: int = 1
              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Apply the full layer stack. h: (B, S, d) -> (B, S, d), aux loss."""
+    h, aux, _ = _backbone(cfg, params, h, remat, remat_group)
+    return h, aux
+
+
+def _backbone(cfg: ArchConfig, params: Params, h: jnp.ndarray,
+              remat: bool, remat_group: int
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict]:
+    """``backbone`` and the per-layer stats of the held-expert layers
+    (``moe_held_forward``; empty for the other topologies)."""
     topo = topology(cfg)
     aux0 = jnp.zeros((), jnp.float32)
+    if topo == "period":
+        h, stats = _scan_period(cfg, params["period"], h, remat)
+        return h, aux0, stats
     if topo == "homo":
         h, aux = _scan_blocks(cfg, homo_kind(cfg), params["layers"], h, remat,
                               remat_group)
@@ -211,7 +271,7 @@ def backbone(cfg: ArchConfig, params: Params, h: jnp.ndarray,
             super_body = jax.checkpoint(super_body)
         (h, aux), _ = jax.lax.scan(
             super_body, (h, aux0), (params["mlstm"], params["slstm"]))
-    return h, aux
+    return h, aux, {}
 
 
 def embed_inputs(cfg: ArchConfig, params: Params, batch: Dict) -> jnp.ndarray:
@@ -232,27 +292,49 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict,
             remat: bool = False, remat_group: int = 1
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence forward -> (logits (B,S,V), aux_loss)."""
+    logits, aux, _ = _forward(cfg, params, batch, remat, remat_group)
+    return logits, aux
+
+
+def _forward(cfg, params, batch, remat, remat_group):
     h = embed_inputs(cfg, params, batch)
-    h, aux = backbone(cfg, params, h, remat=remat, remat_group=remat_group)
+    h, aux, stats = _backbone(cfg, params, h, remat, remat_group)
     h = layers.apply_norm(cfg, params["final_norm"], h)
-    return layers.logits_from_hidden(cfg, params, h), aux
+    return layers.logits_from_hidden(cfg, params, h), aux, stats
 
 
 def loss_fn(cfg: ArchConfig, params: Params, batch: Dict,
             remat: bool = False, remat_group: int = 1) -> jnp.ndarray:
     """Mean cross-entropy (+ MoE aux).  labels: (B,S) int32, -1 = ignore."""
-    logits, aux = forward(cfg, params, batch, remat=remat,
-                          remat_group=remat_group)
+    return loss_and_stats(cfg, params, batch, remat, remat_group)[0]
+
+
+def loss_and_stats(cfg: ArchConfig, params: Params, batch: Dict,
+                   remat: bool = False, remat_group: int = 1
+                   ) -> Tuple[jnp.ndarray, Dict]:
+    """``loss_fn`` and the per-layer stats of the held-expert layers:
+    ``moe_load`` (n_layers, n_held) rows computed per held expert and
+    ``moe_dropped`` (n_layers,) held assignments left uncomputed (empty
+    for a stack without them)."""
+    logits, aux, stats = _forward(cfg, params, batch, remat, remat_group)
     labels = batch["labels"]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     ll = jnp.take_along_axis(logp, jnp.maximum(labels, 0)[..., None],
                              axis=-1)[..., 0]
     mask = (labels >= 0).astype(jnp.float32)
     ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return ce + aux
+    return ce + aux, stats
 
 
 # =================================================================== serving
+
+def _training_only(cfg: ArchConfig) -> None:
+    if topology(cfg) == "period":
+        raise NotImplementedError(
+            f"{cfg.name}: a mixed-attention stack (attn_period) runs the "
+            f"training path only; prefill, decode and its cache are not "
+            f"implemented")
+
 
 def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
                quantize_kv: bool = False) -> Dict:
@@ -260,6 +342,7 @@ def init_cache(cfg: ArchConfig, batch: int, seq_len: int,
     quantize_kv stores int8 values + f16 scales (halves cache HBM; decode
     is memory-bound on every assigned arch — EXPERIMENTS.md §Perf D)."""
     assert cfg.supports_decode, f"{cfg.name} is encoder-only"
+    _training_only(cfg)
     dt = jnp.dtype(cfg.param_dtype)
     C = attention.cache_len_for(cfg, seq_len)
     topo = topology(cfg)
@@ -321,6 +404,7 @@ def _decode_block(cfg, kind, p, x, block_cache):
 def decode_step(cfg: ArchConfig, params: Params, cache: Dict,
                 tokens: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
     """One decode step. tokens: (B, 1) int32 -> logits (B, V), new cache."""
+    _training_only(cfg)
     pos = cache["pos"]
     h = layers.embed_tokens(params["embed"], tokens)
     if cfg.name.startswith("gemma"):
@@ -385,6 +469,7 @@ def prefill(cfg: ArchConfig, params: Params, batch: Dict,
     positioned at S, ready for decode_step.  cache_len (>= prompt length)
     reserves headroom for generated tokens; 0 = exactly the prompt (the
     dry-run decode shapes supply their own cache)."""
+    _training_only(cfg)
     h = embed_inputs(cfg, params, batch)
     B, S, _ = h.shape
     if not cfg.supports_decode:

@@ -1,4 +1,6 @@
-"""Mixture-of-Experts FFN with grouped capacity-based scatter dispatch.
+"""Mixture-of-Experts FFN: grouped capacity-based scatter dispatch over
+every expert (``moe_forward``), and the held-expert layer of one chip's
+share of an expert-parallel layer (``moe_held_forward``).
 
 Tokens are grouped by batch row (each sequence is a dispatch group), so the
 scatter/gather stays local to the data shard that owns the sequence — no
@@ -28,19 +30,22 @@ def _glu_arity(cfg) -> int:
 
 
 def init_moe(cfg, key) -> Params:
+    """Router over all ``n_experts``; expert weights of the experts this
+    chip holds (``n_held``, or all of them)."""
     m = cfg.moe
     d, ff = cfg.d_model, m.expert_d_ff
+    n = m.n_held or m.n_experts
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 6)
     p: Params = {
         "router": layers.init_linear(cfg, ks[0], d, m.n_experts),
-        "w_up": (jax.random.normal(ks[1], (m.n_experts, d, ff), jnp.float32)
+        "w_up": (jax.random.normal(ks[1], (n, d, ff), jnp.float32)
                  * d ** -0.5).astype(dt),
-        "w_down": (jax.random.normal(ks[2], (m.n_experts, ff, d), jnp.float32)
+        "w_down": (jax.random.normal(ks[2], (n, ff, d), jnp.float32)
                    * ff ** -0.5).astype(dt),
     }
     if _glu_arity(cfg) == 3:
-        p["w_gate"] = (jax.random.normal(ks[3], (m.n_experts, d, ff), jnp.float32)
+        p["w_gate"] = (jax.random.normal(ks[3], (n, d, ff), jnp.float32)
                        * d ** -0.5).astype(dt)
     if m.n_shared_experts:
         p["shared"] = layers.init_mlp(
@@ -125,3 +130,67 @@ def moe_forward(cfg, p: Params, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarra
     frac_probs = jnp.mean(probs, axis=(0, 1))
     aux = m.router_aux_weight * E * jnp.sum(frac_tokens * frac_probs)
     return out, aux
+
+
+def held_rows(cfg, tokens: int) -> int:
+    """Rows of the held layer's sorted buffer for ``tokens`` tokens: every
+    (token, expert) assignment that can land on a held expert (a token
+    reaches at most min(top_k, n_held) of them), rounded up to the grouped
+    kernel's row tile."""
+    from repro.kernels import moe_gmm
+    m = cfg.moe
+    rows = tokens * min(m.top_k, m.n_held or m.n_experts)
+    tile = moe_gmm.MAX_ROW_TILE if rows >= moe_gmm.MAX_ROW_TILE \
+        else moe_gmm.LANES
+    return -(-rows // tile) * tile
+
+
+def moe_held_forward(cfg, p: Params, x: jnp.ndarray
+                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """This chip's part of an expert-parallel MoE layer.  x: (B, S, d).
+
+    The router scores all ``n_experts`` (softmax), each token takes its
+    ``top_k`` and renormalises their probabilities; of those assignments,
+    the ones to the held experts [held_offset, held_offset + n_held) are
+    sorted by expert and run through the grouped FFN kernel
+    (``kernels.ops.moe_grouped_ffn``), and each token gets the gated sum
+    of its held experts' outputs.  The absent experts' part is left out.
+    Dropless: every held assignment is computed.
+
+    Returns (out, stats): ``moe_load`` (n_held,) int32, the rows computed
+    per held expert, and ``moe_dropped`` () int32, held assignments left
+    uncomputed.  Named scopes ``moe_route`` and ``moe_experts``."""
+    from repro.kernels import ops as kernel_ops
+    m = cfg.moe
+    B, S, d = x.shape
+    T, E, K = B * S, m.n_experts, m.top_k
+    n = m.n_held or E
+    xf = x.reshape(T, d)
+    with jax.named_scope("moe_route"):
+        logits = layers.apply_linear(p["router"], xf).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, K)                       # (T, K)
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        local = idx - m.held_offset
+        e = jnp.where((local >= 0) & (local < n), local, n).reshape(-1)
+        routed = jnp.sum(e < n)
+        sizes = jnp.bincount(e, length=n + 1)[:n].astype(jnp.int32)
+        rows = held_rows(cfg, T)
+        order = jnp.argsort(e, stable=True)                       # held first
+        if rows > T * K:       # tile padding: rows past every assignment
+            order = jnp.pad(order, (0, rows - T * K),
+                            constant_values=T * K)
+        sel = order[:rows]
+        xs = jnp.take(xf, sel // K, axis=0, mode="clip")          # (rows, d)
+        # where each assignment sits in the sorted rows (past them: unheld)
+        pos = jnp.zeros((T * K,), jnp.int32).at[sel].set(
+            jnp.arange(rows, dtype=jnp.int32), mode="drop")
+        w = jnp.where(e < n, gate.reshape(-1), 0.0).reshape(T, K)
+    with jax.named_scope("moe_experts"):
+        ys = kernel_ops.moe_grouped_ffn(xs, p["w_gate"], p["w_up"],
+                                        p["w_down"], sizes)       # (rows, d)
+        y = jnp.take(ys, pos.reshape(T, K), axis=0)               # (T, K, d)
+        out = jnp.einsum("tk,tkd->td", w, y.astype(jnp.float32))
+    stats = {"moe_load": sizes,
+             "moe_dropped": (routed - jnp.sum(sizes)).astype(jnp.int32)}
+    return out.astype(x.dtype).reshape(B, S, d), stats

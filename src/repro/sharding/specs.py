@@ -3,6 +3,10 @@
 Rules are path-based (leaf names are stable across architectures) and apply
 to the *trailing* dims of each leaf so stacked-layer leading axes (L,) or
 (G, g,) are automatically replicated (they are scanned, never sharded).
+A mixed-attention stack keeps one (G, run length, ...) tree per run of its
+period under ``period/<run>/``, with the same leaf names; its MoE expert
+leaves hold the chip's ``n_held`` experts (the router keeps all
+``n_experts`` outputs), so expert mode shards the held experts.
 
 Mesh contract (repro.launch.mesh):
   data axes  — batch / client-batch dimension ("data", plus "pod" when

@@ -191,3 +191,45 @@ def test_sharded_rounds_keep_solves_whole(mesh4):
     n_all_reduce = (text.count(" all-reduce(")
                     + text.count(" all-reduce-start("))
     assert n_all_reduce == 1, n_all_reduce
+
+
+# ------------------------------------------- Mellum2 kernels, real widths
+
+@pytest.mark.parametrize("window", [1024, 0])
+def test_splash_attention_compiles_with_grad(one_chip, window):
+    """Window (1024 keys) and full causal attention of Mellum2-12B-A2.5B
+    (32 query and 4 KV heads of 128) at 8192 tokens, forward and
+    backward, through the ``kernels.ops`` entry points."""
+    q = _shape(one_chip, (1, 8192, 32, 128), jnp.bfloat16)
+    kv = _shape(one_chip, (1, 8192, 4, 128), jnp.bfloat16)
+    attend = ((lambda q, k, v: ops.attention_window(q, k, v, window))
+              if window else ops.attention_full)
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv))
+    # the forward kernel (with the residuals the backward pass reads) and
+    # the dq and dkv kernels
+    assert txt.count("tpu_custom_call") >= 3
+
+
+def test_grouped_expert_ffn_compiles_with_grad(one_chip):
+    """The held-expert FFN of Mellum2-12B-A2.5B (8 held experts of width
+    896 over d 2304) over the dropless row buffer of 16384 tokens, forward
+    and backward, through ``ops.moe_grouped_ffn``."""
+    rows, d, ff, n = 16384 * 8, 2304, 896, 8
+    xs = _shape(one_chip, (rows, d), jnp.bfloat16)
+    wi = _shape(one_chip, (n, d, ff), jnp.bfloat16)
+    wo = _shape(one_chip, (n, ff, d), jnp.bfloat16)
+    sizes = _shape(one_chip, (n,), jnp.int32)
+
+    def loss(xs, wg, wu, wd, sizes):
+        return jnp.sum(ops.moe_grouped_ffn(xs, wg, wu, wd, sizes)
+                       .astype(jnp.float32))
+
+    txt = _compile_text(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                        (xs, wi, wi, wo, sizes))
+    # the two input products forward (a summed output leaves the third
+    # unread), three dlhs and three dW products backward
+    assert txt.count("tpu_custom_call") >= 8
