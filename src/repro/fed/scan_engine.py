@@ -271,6 +271,42 @@ def _eval_rows(params_traj, rounds: int, eval_every: int):
     return rows
 
 
+# one dispatch, where an eager ``jnp.stack`` makes one per operand and one
+# to join them
+@jax.jit
+def _stack_curves(*curves):
+    return jnp.stack(curves)
+
+
+def _history_curves(model_cfg, spec: flat_lib.FlatSpec, train, test, p,
+                    params_traj, rounds: int, eval_every: int) -> np.ndarray:
+    """Train loss, test accuracy and train accuracy at every eval point of
+    a (rounds, *batch, D_pad) trajectory, as one (3, E, *batch) host
+    array: two ``eval_traj`` dispatches, stacked on the device and read
+    in ONE fetch (a copy, so each value is the float32 ``eval_global``
+    gives)."""
+    with tprof.span("eval/device"):
+        traj = _eval_rows(params_traj, rounds, eval_every)
+        rows = traj.reshape((-1, traj.shape[-1]))
+        tr_loss, tr_acc = eval_traj(model_cfg, spec, rows, train, p)
+        _, te_acc = eval_traj(model_cfg, spec, rows, test, p)
+        curves = _stack_curves(tr_loss, te_acc, tr_acc)
+    with tprof.span("eval/fetch"):
+        return tprof.fetch(curves).reshape((3,) + traj.shape[:-1])
+
+
+def _history(ts, curves: np.ndarray, series: dict) -> dict:
+    """One history dict from a (3, E) host block of ``_history_curves``
+    and the host timeline ``series`` (per-round, indexed at ``ts``)."""
+    tr_loss, te_acc, tr_acc = curves.tolist()
+    hist = {"round": list(ts), "train_loss": tr_loss, "test_acc": te_acc,
+            "train_acc": tr_acc}
+    for k, row in series.items():
+        if row is not None:
+            hist[k] = [float(row[t]) for t in ts]
+    return hist
+
+
 def eval_history_replay(model_cfg, spec: flat_lib.FlatSpec, train, test, p,
                         params_traj, rounds: int, eval_every: int,
                         clocks=None, n_arrived=None, stale_mean=None):
@@ -279,26 +315,16 @@ def eval_history_replay(model_cfg, spec: flat_lib.FlatSpec, train, test, p,
     shared by the solo compiled runs (sync and async); the sweep engine
     batches further via ``eval_history_replay_sweep``.  The eval-point
     rows are evaluated in one vmapped dispatch (``eval_traj``), row-wise
-    bit-identical to the python loops' per-round ``eval_global`` calls.
-    ``clocks``/``n_arrived``/``stale_mean`` are optional per-round
+    bit-identical to the python loops' per-round ``eval_global`` calls,
+    and the whole history is read to the host in one fetch.
+    ``clocks``/``n_arrived``/``stale_mean`` are optional per-round host
     timeline series to record alongside (the async engines pass all three
     from their plan)."""
-    ts = _eval_points(rounds, eval_every)
-    with tprof.span("eval/device"):
-        traj = _eval_rows(params_traj, rounds, eval_every)
-        tr_loss, tr_acc = eval_traj(model_cfg, spec, traj, train, p)
-        _, te_acc = eval_traj(model_cfg, spec, traj, test, p)
-    with tprof.span("eval/fetch"):
-        hist = {"round": list(ts),
-                "train_loss": [tprof.fetch_float(v) for v in tr_loss],
-                "test_acc": [tprof.fetch_float(v) for v in te_acc],
-                "train_acc": [tprof.fetch_float(v) for v in tr_acc]}
-        extras = {"wall_clock": clocks, "n_arrived": n_arrived,
-                  "stale_mean": stale_mean}
-        for k, series in extras.items():
-            if series is not None:
-                hist[k] = [float(series[t]) for t in ts]
-    return hist
+    curves = _history_curves(model_cfg, spec, train, test, p, params_traj,
+                             rounds, eval_every)
+    return _history(_eval_points(rounds, eval_every), curves,
+                    {"wall_clock": clocks, "n_arrived": n_arrived,
+                     "stale_mean": stale_mean})
 
 
 def eval_history_replay_sweep(model_cfg, spec: flat_lib.FlatSpec, train,
@@ -307,36 +333,22 @@ def eval_history_replay_sweep(model_cfg, spec: flat_lib.FlatSpec, train,
                               stale_mean=None):
     """Sweep-native history evaluation: ONE batched dispatch over every
     (eval round, member) pair of an (R, S, D_pad) trajectory instead of
-    R·S separate ``eval_global`` dispatches.  Returns S history dicts,
-    member i row-wise bit-identical to
+    R·S separate ``eval_global`` dispatches, read in one fetch.  Returns S
+    history dicts, member i equal to
     ``eval_history_replay(..., params_traj_RS[:, i], ...)``.
 
     The timeline series (clocks / n_arrived / stale_mean) accept either a
     shared (R,) vector — hyper sweeps, one plan for all members — or a
     per-member (S, R) stack (scenario grids, one timeline per cell)."""
+    curves = _history_curves(model_cfg, spec, train, test, p,
+                             params_traj_RS, rounds, eval_every)
     ts = _eval_points(rounds, eval_every)
-    traj = _eval_rows(params_traj_RS, rounds, eval_every)
-    E, S = traj.shape[0], traj.shape[1]
-    flat = traj.reshape((E * S,) + traj.shape[2:])
-    tr_loss, tr_acc = eval_traj(model_cfg, spec, flat, train, p)
-    _, te_acc = eval_traj(model_cfg, spec, flat, test, p)
-    tr_loss = tprof.fetch(tr_loss).reshape(E, S)
-    tr_acc = tprof.fetch(tr_acc).reshape(E, S)
-    te_acc = tprof.fetch(te_acc).reshape(E, S)
-    extras = {"wall_clock": clocks, "n_arrived": n_arrived,
+    series = {"wall_clock": clocks, "n_arrived": n_arrived,
               "stale_mean": stale_mean}
-    hists = []
-    for i in range(S):
-        hist = {"round": list(ts),
-                "train_loss": [float(v) for v in tr_loss[:, i]],
-                "test_acc": [float(v) for v in te_acc[:, i]],
-                "train_acc": [float(v) for v in tr_acc[:, i]]}
-        for k, series in extras.items():
-            if series is not None:
-                row = series[i] if np.asarray(series).ndim == 2 else series
-                hist[k] = [float(row[t]) for t in ts]
-        hists.append(hist)
-    return hists
+    return [_history(ts, curves[:, :, i],
+                     {k: row[i] if np.ndim(row) == 2 else row
+                      for k, row in series.items()})
+            for i in range(curves.shape[2])]
 
 
 def run_federated_compiled(model_cfg, fed: FederatedData,

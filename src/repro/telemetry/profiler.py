@@ -29,8 +29,8 @@ Spans are named ``<phase>/<step>`` inside the engines' phases:
   ``gather/pool_init``          the pending-update pool's zero rows
   ``setup/to_device``           resident data moved to the device
   ``eval/cohort``               the lazy evaluation cohort, gathered
-  ``eval/device``               eval rows and ``eval_traj`` dispatch
-  ``eval/fetch``                the history lists read to the host
+  ``eval/device``               eval rows, ``eval_traj`` dispatches, stack
+  ``eval/fetch``                the whole history read to the host, once
   ============================  ==========================================
 
 and three counters, counted by the helpers the engines move data through:

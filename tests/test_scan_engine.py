@@ -278,3 +278,78 @@ class TestInputs:
         got = np.asarray(_eval_rows(traj, rounds, eval_every))
         want = traj[np.asarray(_eval_points(rounds, eval_every))]
         assert got.shape == want.shape and (got == want).all()
+
+
+def _replay_inputs(fed_data, shape):
+    """Device eval arrays, the MCLR flat spec and a seeded trajectory of
+    flat parameter vectors of leading ``shape``."""
+    from repro.core import flat as flat_lib
+    from repro.fed import simulator
+    from repro.models import small
+    spec = flat_lib.spec_of(small.init_small(MCLR, jax.random.PRNGKey(0)))
+    traj = np.random.default_rng(11).normal(
+        0.0, 0.3, shape + (spec.D_pad,)).astype(np.float32)
+    return simulator.device_arrays(fed_data), spec, jax.numpy.asarray(traj)
+
+
+class TestHistoryReplay:
+    @pytest.mark.parametrize("rounds,eval_every,timeline", [
+        (6, 2, False), (7, 3, False), (7, 3, True)],
+        ids=["multiple", "extra_last_row", "timeline"])
+    def test_lists_are_the_per_scalar_reads(self, fed_data, rounds,
+                                            eval_every, timeline):
+        """The history read in one fetch holds exactly the floats that
+        reading each device scalar with ``float()`` gives, in the same
+        order, with the host timeline series at the eval points."""
+        from repro.fed.scan_engine import (_eval_points, _eval_rows,
+                                           eval_history_replay, eval_traj)
+        (train, test, p), spec, traj = _replay_inputs(fed_data, (rounds,))
+        rng = np.random.default_rng(rounds)
+        series = {"wall_clock": np.cumsum(rng.random(rounds)),
+                  "n_arrived": rng.integers(0, 5, rounds),
+                  "stale_mean": rng.random(rounds)} if timeline else {}
+        hist = eval_history_replay(
+            MCLR, spec, train, test, p, traj, rounds, eval_every,
+            clocks=series.get("wall_clock"),
+            n_arrived=series.get("n_arrived"),
+            stale_mean=series.get("stale_mean"))
+        rows = _eval_rows(traj, rounds, eval_every)
+        tr_loss, tr_acc = eval_traj(MCLR, spec, rows, train, p)
+        _, te_acc = eval_traj(MCLR, spec, rows, test, p)
+        ts = _eval_points(rounds, eval_every)
+        want = {"round": ts,
+                "train_loss": [float(v) for v in tr_loss],
+                "test_acc": [float(v) for v in te_acc],
+                "train_acc": [float(v) for v in tr_acc]}
+        want.update({k: [float(v[t]) for t in ts]
+                     for k, v in series.items()})
+        assert hist == want
+        assert all(type(v) is float for k, vs in hist.items()
+                   if k != "round" for v in vs)
+
+    def test_sweep_members_are_solo_replays_in_one_fetch(self, fed_data):
+        """Each member of the sweep replay equals the solo replay of its
+        trajectory, with shared (R,) and per-member (S, R) series, and
+        the whole sweep is read in one fetch.  Nine eval points fill the
+        eval chunk of 8 rows in both, so both run the same row program."""
+        from repro.fed.scan_engine import (eval_history_replay,
+                                           eval_history_replay_sweep)
+        from repro.telemetry import profiler as tprof
+        rounds, eval_every, S = 17, 2, 3
+        (train, test, p), spec, traj = _replay_inputs(fed_data, (rounds, S))
+        rng = np.random.default_rng(5)
+        clocks = np.cumsum(rng.random((S, rounds)), axis=1)
+        stale = rng.random(rounds)
+        tprof.reset()
+        with tprof.recording():
+            hists = eval_history_replay_sweep(
+                MCLR, spec, train, test, p, traj, rounds, eval_every,
+                clocks=clocks, stale_mean=stale)
+        snap = tprof.snapshot()
+        tprof.reset()
+        assert snap["counters"]["eval/fetch"]["d2h_fetches"] == 1
+        assert len(hists) == S
+        for i, hist in enumerate(hists):
+            assert hist == eval_history_replay(
+                MCLR, spec, train, test, p, traj[:, i], rounds, eval_every,
+                clocks=clocks[i], stale_mean=stale)

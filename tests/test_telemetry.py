@@ -564,16 +564,29 @@ class TestRecorder:
         assert calls == sorted(calls)
         assert tprof._THREAD.call is None
 
-    def test_eval_fetches_are_three_per_eval_point(self, monkeypatch):
+    @pytest.mark.parametrize("engine,eval_every", [
+        ("sync", 1), ("sync", 3), ("lazy_deadline", 1)])
+    def test_eval_history_is_one_fetch_per_call(self, monkeypatch, engine,
+                                                eval_every):
+        """The eval history of a call is read to the host in one fetch,
+        however many eval points the call has."""
         reads = _count_reads(monkeypatch, "eval/")
         tprof.reset()
-        res = fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS, eval_every=1,
-                      profiler=PhaseProfiler())
+        if engine == "sync":
+            res = fed.run(MCLR, _fed, _sync_cfg(False), ROUNDS,
+                          eval_every=eval_every, profiler=PhaseProfiler())
+        else:
+            res = fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS,
+                          fleet=_LAZY_FLEET, eval_every=eval_every,
+                          profiler=PhaseProfiler())
+        points = len(range(0, ROUNDS, eval_every)) \
+            + ((ROUNDS - 1) % eval_every > 0)
+        assert len(res["train_loss"]) == points
         prof = res.profile
-        assert prof["counters"]["eval/fetch"]["d2h_fetches"] == 3 * ROUNDS
-        assert reads[0] == 3 * ROUNDS
+        assert prof["counters"]["eval/fetch"]["d2h_fetches"] == 1
+        assert reads[0] == 1
         assert set(prof["spans"]) >= {"setup", "plan_build", "eval",
-                                      "plan_build/step_draws", "eval/fetch"}
+                                      "eval/device", "eval/fetch"}
         # a call given a profiler records for its length only
         assert not tprof.is_recording()
         # the phase is the parent of its steps
