@@ -281,7 +281,7 @@ def phase_paper(s: Smoke, rounds: int = PAPER_ROUNDS) -> None:
     afl = AsyncFLConfig(mode="deadline", algo="folb", n_selected=10, mu=1.0,
                         lr=0.05, deadline=float(np.quantile(lat, 0.9)),
                         staleness_alpha=0.5, seed=0)
-    with s.programs(ph, "deadline round", "scan_async_deadline"):
+    with s.programs(ph, "deadline round", "scan_deadline_cohort"):
         ares, async_s = _timed(lambda: fed_api.run(MCLR, data, afl, rounds,
                                                    fleet=fleet))
     aloss = ares["train_loss"]

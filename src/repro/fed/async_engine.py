@@ -923,48 +923,41 @@ def build_plan_grid(afl: AsyncFLConfig, fleet: DeviceFleet, cost,
 
 # ------------------------------------------------- shared jitted round steps
 
-def pool_init(model_cfg, fl: simulator.FLConfig, params, data, n_rows: int):
+def pool_init(model_cfg, fl: simulator.FLConfig, params, batch, n_rows: int):
     """Zero pending-update pool with the exact per-row leaf shapes/dtypes
-    of one `_local_updates` output (deltas tree, grads tree, gammas)."""
-    ids = jnp.zeros((1,), jnp.int32)
+    of one local update (deltas tree, grads tree, gammas) on ``batch``'s
+    devices: any (B, M, ...) stack, a gathered cohort or the resident
+    data itself.  Probed abstractly on one row, so nothing runs and no
+    (N, M, ...) stack is needed."""
     steps = jnp.ones((1,), jnp.int32)
     d_s, g_s, gam_s = jax.eval_shape(
-        lambda p, dat: simulator._local_updates(model_cfg, p, dat, ids,
-                                                steps, fl), params, data)
-    row = lambda s: jnp.zeros((n_rows,) + s.shape[1:], s.dtype)
-    return (jax.tree.map(row, d_s), jax.tree.map(row, g_s),
-            jnp.zeros((n_rows,), gam_s.dtype))
-
-
-def pool_init_batch(model_cfg, fl: simulator.FLConfig, params, batch,
-                    n_rows: int):
-    """`pool_init` for the lazy cohort path: probes shapes through
-    `_local_updates_batch` on a width-1 slice of a pre-gathered batch, so
-    no resident (N, M, ...) stack is ever needed."""
-    one = {k: batch[k][:1] for k in ("x", "y", "mask")}
-    steps = jnp.ones((1,), jnp.int32)
-    d_s, g_s, gam_s = jax.eval_shape(
-        lambda p, b: simulator._local_updates_batch(model_cfg, p, b,
-                                                    steps, fl), params, one)
+        lambda p, b: simulator._local_updates_batch(
+            model_cfg, p, {k: b[k][:1] for k in ("x", "y", "mask")}, steps,
+            fl), params, batch)
     row = lambda s: jnp.zeros((n_rows,) + s.shape[1:], s.dtype)
     return (jax.tree.map(row, d_s), jax.tree.map(row, g_s),
             jnp.zeros((n_rows,), gam_s.dtype))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",))
-def deadline_slow_step(model_cfg, afl: AsyncFLConfig, params, pend, data,
-                       ids, n_steps, arrived_mask, store_slot, due_slot,
+def deadline_slow_step(model_cfg, afl: AsyncFLConfig, params, pend, batch,
+                       n_steps, arrived_mask, store_slot, due_slot,
                        due_mask, due_tau, hypers=None, corrupt=None, *,
                        mesh=None):
-    """One non-fast deadline round: compute the K dispatched updates,
-    gather this round's due stragglers from the pool, stash this round's
-    misses, and run the fixed-budget masked staleness aggregation.
+    """One non-fast deadline round: compute the K dispatched updates on
+    their gathered ``batch``, gather this round's due stragglers from the
+    pool, stash this round's misses, and run the fixed-budget masked
+    staleness aggregation.  Returns (new params, pool, metrics), the
+    metrics None without telemetry.
 
     Shared verbatim by the python event loop, the compiled scan, and the
     vmapped sweep engine — the bit-for-bit parity between `run_async` and
     `run_async_compiled` rests on both replaying this exact program
     (separate jit graphs of the "same" math are not guaranteed
-    bit-identical).  ``hypers`` carries the traced sweepable scalars.
+    bit-identical).  Callers holding resident data gather the cohort
+    first (`simulator._client_batch`); lazy runs pass the host-gathered
+    cohort, so the shapes depend on K and the pool width, never on N.
+    ``hypers`` carries the traced sweepable scalars.
 
     ``corrupt`` (scenario payload channels, (K,) f32) multiplies the K
     dispatched payloads before they are stored or aggregated — a
@@ -973,25 +966,9 @@ def deadline_slow_step(model_cfg, afl: AsyncFLConfig, params, pend, data,
     the pre-corruption trace exactly.
     """
     h = hypers if hypers is not None else hypers_of(afl)
-    fl = afl.sync_config()
-    deltas, grads, gammas = simulator._local_updates(
-        model_cfg, params, data, ids, n_steps, fl, h)
+    deltas, grads, gammas = simulator._local_updates_batch(
+        model_cfg, params, batch, n_steps, afl.sync_config(), h)
     deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
-    return _deadline_after_updates(
-        afl, params, pend, deltas, grads, gammas, arrived_mask, store_slot,
-        due_slot, due_mask, due_tau, h, corrupt is not None, mesh)
-
-
-def _deadline_after_updates(afl, params, pend, deltas, grads, gammas,
-                            arrived_mask, store_slot, due_slot, due_mask,
-                            due_tau, h, corrupted: bool, mesh):
-    """Everything after the local solves of a non-fast deadline round:
-    due-slot gather, straggler stash, fixed-budget masked staleness
-    aggregation, telemetry.  Factored so `deadline_slow_step` (resident
-    data, gather inside the jit) and `deadline_slow_step_cohort`
-    (host-gathered lazy batch) run the identical traced ops —
-    ``corrupted`` is the (trace-static) None-ness of the corruption
-    channel."""
     pend_d, pend_g, pend_gam = pend
     # gather due rows BEFORE storing: a slot aggregated this round may be
     # reallocated to one of this round's stragglers
@@ -1000,18 +977,14 @@ def _deadline_after_updates(afl, params, pend, deltas, grads, gammas,
     due_gam = pend_gam[due_slot]
     # stash this round's stragglers (arrived rows land in the dump slot,
     # whose contents are only ever read through a masked-out due slot)
-    pend_d = jax.tree.map(lambda b, x: b.at[store_slot].set(x),
-                          pend_d, deltas)
-    pend_g = jax.tree.map(lambda b, x: b.at[store_slot].set(x),
-                          pend_g, grads)
-    pend_gam = pend_gam.at[store_slot].set(gammas)
+    pend = _pool_store(pend, store_slot, deltas, grads, gammas)
     K = gammas.shape[0]
     tau = jnp.concatenate([jnp.zeros((K,), jnp.float32), due_tau])
     mask = jnp.concatenate([arrived_mask.astype(jnp.float32), due_mask])
     deltas_all = _concat0(deltas, due_d)
     grads_all = _concat0(grads, due_g)
     gammas_all = jnp.concatenate([gammas, due_gam])
-    if corrupted:
+    if corrupt is not None:
         # corruption breaks the masked-row contract the aggregation rules
         # rely on (a NaN row enters the reductions as 0·NaN = NaN): a
         # corrupted straggler still in flight — and the dump row read
@@ -1025,44 +998,26 @@ def _deadline_after_updates(afl, params, pend, deltas, grads, gammas,
     new_params, ginfo = _apply_aggregation(
         afl, params, deltas_all, grads_all, gammas_all, tau, mask=mask,
         mesh=mesh, hypers=h)
+    m = None
     if afl.telemetry:
         from repro.telemetry import metrics as tmetrics
         m = tmetrics.metrics_for_algo(
             afl.algo, params, new_params, deltas_all, grads_all,
             psi=h["psi"], gammas=gammas_all, tau=tau,
             alpha=h["staleness_alpha"], mask=mask, guard=ginfo)
-        return new_params, (pend_d, pend_g, pend_gam), m
-    return new_params, (pend_d, pend_g, pend_gam)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",))
-def deadline_slow_step_cohort(model_cfg, afl: AsyncFLConfig, params, pend,
-                              batch, n_steps, arrived_mask, store_slot,
-                              due_slot, due_mask, due_tau, hypers=None,
-                              corrupt=None, *, mesh=None):
-    """`deadline_slow_step` for lazy populations: the cohort batch is
-    pre-gathered on the host (``data.gather(plan.ids[t])``), so the traced
-    program's shapes depend on K and the pool width — never on N.  Runs
-    `_local_updates_batch` + `_deadline_after_updates`, the exact units of
-    the resident step."""
-    h = hypers if hypers is not None else hypers_of(afl)
-    deltas, grads, gammas = simulator._local_updates_batch(
-        model_cfg, params, batch, n_steps, afl.sync_config(), h)
-    deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
-    return _deadline_after_updates(
-        afl, params, pend, deltas, grads, gammas, arrived_mask, store_slot,
-        due_slot, due_mask, due_tau, h, corrupt is not None, mesh)
+    return new_params, pend, m
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def fedbuff_seed_pool(model_cfg, afl: AsyncFLConfig, params, pend, data,
-                      ids, n_steps, store_slot, hypers=None, corrupt=None):
+def fedbuff_seed_pool(model_cfg, afl: AsyncFLConfig, params, pend, batch,
+                      n_steps, store_slot, hypers=None, corrupt=None):
     """Compute the initial `concurrency` dispatches on the initial params
-    and stash them in their pool slots (one batched update call).
-    ``corrupt`` stamps the scenario payload factors on the seed uploads."""
+    from their gathered ``batch`` and stash them in their pool slots (one
+    batched update call).  ``corrupt`` stamps the scenario payload
+    factors on the seed uploads."""
     h = hypers if hypers is not None else hypers_of(afl)
-    deltas, grads, gammas = simulator._local_updates(
-        model_cfg, params, data, ids, n_steps, afl.sync_config(), h)
+    deltas, grads, gammas = simulator._local_updates_batch(
+        model_cfg, params, batch, n_steps, afl.sync_config(), h)
     deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
     return _pool_store(pend, store_slot, deltas, grads, gammas)
 
@@ -1078,32 +1033,23 @@ def _pool_store(pend, store_slot, deltas, grads, gammas):
     return (pend_d, pend_g, pend_gam)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def fedbuff_seed_pool_cohort(model_cfg, afl: AsyncFLConfig, params, pend,
-                             batch, n_steps, store_slot, hypers=None,
-                             corrupt=None):
-    """`fedbuff_seed_pool` over a host-gathered seed-cohort batch (lazy
-    populations): shapes depend on `concurrency`, never on N."""
-    h = hypers if hypers is not None else hypers_of(afl)
-    deltas, grads, gammas = simulator._local_updates_batch(
-        model_cfg, params, batch, n_steps, afl.sync_config(), h)
-    deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
-    return _pool_store(pend, store_slot, deltas, grads, gammas)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",))
-def fedbuff_round_step(model_cfg, afl: AsyncFLConfig, params, pend, data,
-                       ids, n_steps, store_slot, flush_slot, tau,
+def fedbuff_round_step(model_cfg, afl: AsyncFLConfig, params, pend, batch,
+                       n_steps, store_slot, flush_slot, tau,
                        hypers=None, flush_mask=None, corrupt=None, *,
                        mesh=None):
     """One fedbuff flush round: batch-compute the dispatches made during
     this round (all reference the current params — the server version only
-    bumps at the flush), store them, then aggregate the M flushed rows.
+    bumps at the flush) on their gathered ``batch``, store them, then
+    aggregate the M flushed rows.  Returns (new params, pool, metrics),
+    the metrics None without telemetry.
 
     Storing happens BEFORE the flush gather: a device dispatched this
     round can arrive fast enough to be part of this very flush.  Shared
     verbatim by the python event loop, the compiled scan, and the vmapped
-    sweep engine.
+    sweep engine; resident callers gather the cohort first, lazy runs
+    pass the host-gathered one (shapes in the plan's dispatch width W and
+    the pool size, never N).
 
     ``flush_mask`` (scenario drop channel, (M,) f32) excludes flushed
     rows whose upload failed in transit; ``None`` keeps the pre-scenario
@@ -1112,20 +1058,9 @@ def fedbuff_round_step(model_cfg, afl: AsyncFLConfig, params, pend, data,
     dispatches before they are stored; pad rows carry exactly 1.0.
     """
     h = hypers if hypers is not None else hypers_of(afl)
-    deltas, grads, gammas = simulator._local_updates(
-        model_cfg, params, data, ids, n_steps, afl.sync_config(), h)
+    deltas, grads, gammas = simulator._local_updates_batch(
+        model_cfg, params, batch, n_steps, afl.sync_config(), h)
     deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
-    return _fedbuff_after_updates(afl, params, pend, deltas, grads, gammas,
-                                  store_slot, flush_slot, tau, h,
-                                  flush_mask, mesh)
-
-
-def _fedbuff_after_updates(afl, params, pend, deltas, grads, gammas,
-                           store_slot, flush_slot, tau, h, flush_mask, mesh):
-    """Everything after the local solves of a fedbuff flush round: store,
-    flush gather, staleness aggregation, telemetry.  Shared by
-    `fedbuff_round_step` (resident) and `fedbuff_round_step_cohort`
-    (lazy, host-gathered batch) so both run identical traced ops."""
     pend = _pool_store(pend, store_slot, deltas, grads, gammas)
     pend_d, pend_g, pend_gam = pend
     flush_d = jax.tree.map(lambda x: x[flush_slot], pend_d)
@@ -1134,31 +1069,14 @@ def _fedbuff_after_updates(afl, params, pend, deltas, grads, gammas,
     new_params, ginfo = _apply_aggregation(afl, params, flush_d, flush_g,
                                            flush_gam, tau, mask=flush_mask,
                                            mesh=mesh, hypers=h)
+    m = None
     if afl.telemetry:
         from repro.telemetry import metrics as tmetrics
         m = tmetrics.metrics_for_algo(
             afl.algo, params, new_params, flush_d, flush_g, psi=h["psi"],
             gammas=flush_gam, tau=tau, alpha=h["staleness_alpha"],
             mask=flush_mask, guard=ginfo)
-        return new_params, (pend_d, pend_g, pend_gam), m
-    return new_params, (pend_d, pend_g, pend_gam)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",))
-def fedbuff_round_step_cohort(model_cfg, afl: AsyncFLConfig, params, pend,
-                              batch, n_steps, store_slot, flush_slot, tau,
-                              hypers=None, flush_mask=None, corrupt=None, *,
-                              mesh=None):
-    """`fedbuff_round_step` for lazy populations: this round's dispatch
-    cohort arrives pre-gathered, so shapes depend on the plan's dispatch
-    width W and pool size — never on N."""
-    h = hypers if hypers is not None else hypers_of(afl)
-    deltas, grads, gammas = simulator._local_updates_batch(
-        model_cfg, params, batch, n_steps, afl.sync_config(), h)
-    deltas, grads = simulator.apply_corruption(deltas, grads, corrupt)
-    return _fedbuff_after_updates(afl, params, pend, deltas, grads, gammas,
-                                  store_slot, flush_slot, tau, h,
-                                  flush_mask, mesh)
+    return new_params, pend, m
 
 
 # ----------------------------------------------------------- python driver
@@ -1304,19 +1222,16 @@ def _deadline_round(model_cfg, afl_t, sync_fl, params, pend, train, p, plan,
         if sync_fl.telemetry:
             mlist.append(diag["metrics"])
         return params, pend
-    out = deadline_slow_step(
-        model_cfg, afl_t, params, pend, train,
-        tprof.to_device(plan.ids[t]), n_steps,
-        tprof.to_device(plan.arrived[t], jnp.float32),
+    params, pend, m = deadline_slow_step(
+        model_cfg, afl_t, params, pend,
+        simulator._client_batch(train, tprof.to_device(plan.ids[t])),
+        n_steps, tprof.to_device(plan.arrived[t], jnp.float32),
         tprof.to_device(plan.store_slot[t]),
         tprof.to_device(plan.due_slot[t]),
         tprof.to_device(plan.due_mask[t]),
         tprof.to_device(plan.due_tau[t]), hypers, corrupt, mesh=mesh)
     if afl_t.telemetry:
-        params, pend, m = out
         mlist.append(m)
-    else:
-        params, pend = out
     return params, pend
 
 
@@ -1336,17 +1251,19 @@ def _run_fedbuff(model_cfg, afl, fleet, cost, sizes, train, key, params,
                                       scenario=scenario)
         pend = pool_init(model_cfg, afl_t.sync_config(), params, train,
                          plan.n_slots)
-        pend = fedbuff_seed_pool(model_cfg, afl_t, params, pend, train,
-                                 tprof.to_device(plan.seed_ids),
+        pend = fedbuff_seed_pool(model_cfg, afl_t, params, pend,
+                                 simulator._client_batch(
+                                     train, tprof.to_device(plan.seed_ids)),
                                  tprof.to_device(plan.seed_steps),
                                  tprof.to_device(plan.seed_slots), hypers,
                                  corrupt=None if plan.seed_corrupt is None
                                  else tprof.to_device(plan.seed_corrupt))
     for t in range(rounds):
         with prof.phase("rounds"):
-            out = fedbuff_round_step(
-                model_cfg, afl_t, params, pend, train,
-                tprof.to_device(plan.ids[t]), tprof.to_device(plan.n_steps[t]),
+            params, pend, m = fedbuff_round_step(
+                model_cfg, afl_t, params, pend,
+                simulator._client_batch(train, tprof.to_device(plan.ids[t])),
+                tprof.to_device(plan.n_steps[t]),
                 tprof.to_device(plan.store_slot[t]),
                 tprof.to_device(plan.flush_slot[t]),
                 tprof.to_device(plan.tau[t]), hypers,
@@ -1355,10 +1272,7 @@ def _run_fedbuff(model_cfg, afl, fleet, cost, sizes, train, key, params,
                 corrupt=None if plan.corrupt is None
                 else tprof.to_device(plan.corrupt[t]), mesh=mesh)
             if afl_t.telemetry:
-                params, pend, m = out
                 mlist.append(m)
-            else:
-                params, pend = out
         if t % eval_every == 0 or t == rounds - 1:
             n_arrived = (afl.buffer_size if plan.flush_mask is None
                          else int(plan.flush_mask[t].sum()))

@@ -15,11 +15,13 @@ restructure the run so nothing scales with N:
     vector) — the plan's pre-drawn ``(R, K)`` id grid is the only record
     of who participates;
   * the host gathers the ``(R, K, M, ...)`` cohort batches once, up
-    front, and the ``lax.scan`` consumes them as scan inputs — the
-    traced programs (``simulator.fl_round_cohort``,
-    ``async_engine.deadline_slow_step_cohort`` /
-    ``fedbuff_round_step_cohort``) have shapes in K, R and the pool
-    width only;
+    front, and the scan consumes them as its ``"batch"`` rows: the one
+    scan body of ``scan_engine`` runs the same mode steps as a resident
+    run, which read the batch from the row in place of gathering from
+    resident stacks, so the traced programs (``scan_engine.scan_rounds``
+    / ``scan_deadline_cohort`` / ``scan_fedbuff_cohort`` over
+    ``simulator.fl_round_cohort``, ``async_engine.deadline_slow_step``,
+    ``fedbuff_round_step``) have shapes in K, R and the pool width only;
   * plan builders run on the lazy gather protocol
     (``PopulationSpec.gather_caps`` / ``gather_avail`` /
     ``LazyFederatedData.sizes``), so event-plan construction is O(R·K);
@@ -32,8 +34,7 @@ Equivalence contract (tests/test_population.py): on the SAME config with
 identical params, history, wall clocks, and plan digests — the lazy
 gathers are literally rows of the materialized arrays, and the round
 math runs the same shared units (``_local_updates_batch``,
-``_sync_aggregate``, ``_deadline_after_updates``,
-``_fedbuff_after_updates``) as the resident steps.
+``_sync_aggregate``) and the very step functions of the resident runs.
 
 Scope: cohort-shaped algorithms only (``simulator.COHORT_ALGOS`` — the
 all-N-scoring fednu baselines and folb2's second draw are inherently
@@ -42,11 +43,9 @@ the resident-engine alternative spelled out.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import flat as flat_lib
@@ -113,35 +112,6 @@ def _round_batches(data: LazyFederatedData, ids: np.ndarray):
 
 # ------------------------------------------------------------- sync engine
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def scan_rounds_cohort(model_cfg, fl: simulator.FLConfig,
-                       spec: flat_lib.FlatSpec, w0_flat, batches, steps,
-                       hypers, so_state0=None, *, mesh=None):
-    """Whole-run XLA program over pre-gathered cohorts: scan
-    ``fl_round_cohort`` (plus the same jitted server-optimizer update the
-    resident engines apply) over the (R, K, ...) batch stack.  Shapes
-    depend on R and K only."""
-    use_so = so_state0 is not None
-    so_cfg = sopt.ServerOptConfig(kind=fl.server_opt, lr=1.0)
-
-    def body(carry, xs):
-        w_flat, so_state = carry if use_so else (carry, None)
-        batch_t, steps_t = xs
-        params = flat_lib.unravel(spec, w_flat)
-        new_params, _ = simulator.fl_round_cohort(
-            model_cfg, fl, params, batch_t, steps_t, hypers, mesh=mesh)
-        if use_so:
-            new_params, so_state = sopt.server_round_update(
-                so_cfg, params, so_state, new_params, hypers["server_lr"])
-        w_new = flat_lib.ravel(spec, new_params)
-        return ((w_new, so_state) if use_so else w_new), w_new
-
-    carry0 = (w0_flat, so_state0) if use_so else w0_flat
-    carry, ws = jax.lax.scan(body, carry0, (batches, steps))
-    return (carry[0] if use_so else carry), ws
-
-
 def run_federated_lazy(model_cfg, data: LazyFederatedData,
                        fl: simulator.FLConfig, rounds: int,
                        init_key: Optional[jax.Array] = None,
@@ -177,9 +147,10 @@ def run_federated_lazy(model_cfg, data: LazyFederatedData,
     with prof.phase("gather"):
         batches = _round_batches(data, ids)
     with prof.phase("scan"):
-        w_final, ws = scan_rounds_cohort(
-            model_cfg, fl.timeline_config(), spec, w0, batches, steps,
-            simulator.hypers_of(fl), so_state0, mesh=mesh)
+        w_final, ys = scan_engine.scan_rounds(
+            model_cfg, fl.timeline_config(), spec, (w0, so_state0),
+            {"batch": batches, "steps": steps}, simulator.hypers_of(fl),
+            mesh=mesh)
     with prof.phase("eval"):
         train, test, p = _eval_arrays(data)
         clocks = None
@@ -190,74 +161,14 @@ def run_federated_lazy(model_cfg, data: LazyFederatedData,
                 model_cfg, params, data, fl.algo, fleet, ids, None,
                 tprof.fetch(steps), rounds)
         hist = scan_engine.eval_history_replay(
-            model_cfg, spec, train, test, p, ws, rounds, eval_every, clocks)
+            model_cfg, spec, train, test, p, ys["params"], rounds,
+            eval_every, clocks)
     return simulator.FedRunResult(
         history=hist, params=flat_lib.unravel(spec, w_final), ids=ids,
         metrics=None, profile=prof.finish())
 
 
 # ------------------------------------------------------------ async engine
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def scan_deadline_cohort(model_cfg, afl, spec: flat_lib.FlatSpec, w0_flat,
-                         pend0, batches, steps, arrived, store_slot,
-                         due_slot, due_mask, due_tau, fast, hypers, *,
-                         mesh=None):
-    """Whole-run deadline-mode program over pre-gathered cohorts:
-    sync-parity fast rounds run ``fl_round_cohort`` (the τ = 0 full-mask
-    case), every other round ``deadline_slow_step_cohort`` against the
-    straggler pool — the cohort forms of exactly the two branches the
-    resident scan conds between."""
-    fl = afl.sync_config()
-
-    def body(carry, xs):
-        batch_t, steps_t, arr_t, store_t, due_s, due_m, due_t, fast_t = xs
-        w_flat, pend = carry
-        params = flat_lib.unravel(spec, w_flat)
-
-        def fast_fn(params, pend):
-            new, _ = simulator.fl_round_cohort(
-                model_cfg, fl, params, batch_t, steps_t, hypers, mesh=mesh)
-            return flat_lib.ravel(spec, new), pend
-
-        def slow_fn(params, pend):
-            new, pend2 = async_lib.deadline_slow_step_cohort(
-                model_cfg, afl, params, pend, batch_t, steps_t, arr_t,
-                store_t, due_s, due_m, due_t, hypers, mesh=mesh)
-            return flat_lib.ravel(spec, new), pend2
-
-        w_new, pend = jax.lax.cond(fast_t, fast_fn, slow_fn, params, pend)
-        return (w_new, pend), w_new
-
-    (w_final, _), ws = jax.lax.scan(
-        body, (w0_flat, pend0),
-        (batches, steps, arrived, store_slot, due_slot, due_mask, due_tau,
-         fast))
-    return w_final, ws
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def scan_fedbuff_cohort(model_cfg, afl, spec: flat_lib.FlatSpec, w0_flat,
-                        pend0, batches, steps, store_slot, flush_slot, tau,
-                        hypers, *, mesh=None):
-    """Whole-run fedbuff program over pre-gathered dispatch cohorts."""
-    def body(carry, xs):
-        batch_t, steps_t, store_t, flush_t, tau_t = xs
-        w_flat, pend = carry
-        params = flat_lib.unravel(spec, w_flat)
-        new, pend = async_lib.fedbuff_round_step_cohort(
-            model_cfg, afl, params, pend, batch_t, steps_t, store_t,
-            flush_t, tau_t, hypers, mesh=mesh)
-        w_new = flat_lib.ravel(spec, new)
-        return (w_new, pend), w_new
-
-    (w_final, _), ws = jax.lax.scan(
-        body, (w0_flat, pend0),
-        (batches, steps, store_slot, flush_slot, tau))
-    return w_final, ws
-
 
 def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
                    rounds: int, init_key: Optional[jax.Array] = None,
@@ -269,8 +180,8 @@ def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
     gather protocol — a materialized ``DeviceFleet`` produces the
     bit-identical plan and run).  The event plan is built through the
     O(R·K) lazy gathers, the R cohort batches are gathered once on the
-    host, and one ``lax.scan`` replays the plan through the cohort step
-    functions.  ``plan`` replays a pre-built event plan instead (it must
+    host, and one ``lax.scan`` replays the plan through the mode's step
+    functions on those cohorts.  ``plan`` replays a pre-built event plan instead (it must
     come from this (afl, fleet, rounds, key) timeline).
     """
     from repro.telemetry import profiler_for
@@ -306,18 +217,13 @@ def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
         with prof.phase("gather"):
             batches = _round_batches(data, plan.ids)
             with tprof.span("gather/pool_init"):
-                pend0 = async_lib.pool_init_batch(
+                pend0 = async_lib.pool_init(
                     model_cfg, sync_fl, params,
                     {k: v[0] for k, v in batches.items()}, plan.n_slots + 1)
         with prof.phase("scan"):
-            w_final, ws = scan_deadline_cohort(
-                model_cfg, afl_t, spec, w0, pend0, batches,
-                tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.arrived, jnp.float32),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.due_slot),
-                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
-                tprof.to_device(plan.fast), hypers, mesh=mesh)
+            w_final, ys = scan_engine.scan_deadline_cohort(
+                model_cfg, afl_t, spec, (w0, pend0),
+                scan_engine.deadline_xs(plan, batches), hypers, mesh=mesh)
         clocks, n_arr = plan.round_end, plan.n_arrived
     else:
         with prof.phase("plan_build"):
@@ -328,27 +234,25 @@ def run_async_lazy(model_cfg, data: LazyFederatedData, afl, fleet,
             seed_batch = _round_batches(data, plan.seed_ids)
             batches = _round_batches(data, plan.ids)
             with tprof.span("gather/pool_init"):
-                pend0 = async_lib.pool_init_batch(
+                pend0 = async_lib.pool_init(
                     model_cfg, sync_fl, params, seed_batch, plan.n_slots)
-                pend0 = async_lib.fedbuff_seed_pool_cohort(
+                pend0 = async_lib.fedbuff_seed_pool(
                     model_cfg, afl_t, params, pend0, seed_batch,
                     tprof.to_device(plan.seed_steps),
                     tprof.to_device(plan.seed_slots), hypers)
         with prof.phase("scan"):
-            w_final, ws = scan_fedbuff_cohort(
-                model_cfg, afl_t, spec, w0, pend0, batches,
-                tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.flush_slot), tprof.to_device(plan.tau),
-                hypers, mesh=mesh)
+            w_final, ys = scan_engine.scan_fedbuff_cohort(
+                model_cfg, afl_t, spec, (w0, pend0),
+                scan_engine.fedbuff_xs(plan, batches), hypers, mesh=mesh)
         clocks = plan.flush_clock
         n_arr = np.full(rounds, afl.buffer_size)
 
     with prof.phase("eval"):
         train, test, p = _eval_arrays(data)
         hist = scan_engine.eval_history_replay(
-            model_cfg, spec, train, test, p, ws, rounds, eval_every,
-            clocks=clocks, n_arrived=n_arr, stale_mean=plan.stale_mean)
+            model_cfg, spec, train, test, p, ys["params"], rounds,
+            eval_every, clocks=clocks, n_arrived=n_arr,
+            stale_mean=plan.stale_mean)
     return simulator.FedRunResult(
         history=hist, params=flat_lib.unravel(spec, w_final),
         ids=np.asarray(plan.ids), metrics=None, profile=prof.finish())

@@ -6,12 +6,29 @@ round-trips for every communication round.  For the paper-scale models a
 round's actual math is microseconds of work, so dispatch dominates — and
 sweeping schedules/hyper-parameters at scale means thousands of runs.
 
-Two compiled drivers:
+One scan body, ``_scan``, runs every compiled mode, data source and batch
+axis.  A mode is a per-round step ``step(carry, x_t, hypers) -> (carry,
+ys_t)`` over the carry ``(w_flat, state)`` (``make_sync_round_step``,
+``make_deadline_step``, ``make_fedbuff_step``); ``x_t`` is one row of a
+dict of per-round inputs, in which an optional operand (a scenario's
+``up_mask``, ``flush_mask`` or ``corrupt``) is a key that is present or
+absent.  The step reads its data source from that row: a ``"batch"`` is a
+cohort the host gathered (lazy populations, ``lazy_engine``); otherwise
+the round's cohort comes from the resident stacks inside the program.
+``member`` adds a leading member axis: ``"sweep"`` batches the carry and
+the hypers over one shared timeline (``sweep_engine`` hyper sweeps),
+``"grid"`` the carry and the per-cell plan rows under one learning config
+(scenario grids).  A solo run calls the step directly.
 
-  * ``run_federated_compiled`` — the synchronous engine: one XLA program
-    scanning ``simulator.fl_round`` over pre-drawn (key, step) inputs,
-    optionally carrying FedOpt-style server-optimizer state (momentum /
-    adam) in the scan carry via the same jitted
+Three jitted entries wrap the body, one per mode: ``scan_rounds``,
+``scan_deadline_cohort`` and ``scan_fedbuff_cohort``.  Their programs are
+named ``jit_<entry>`` in a device trace, which is how the chip benchmark
+finds the round programs; every compiled run of a mode, resident or lazy,
+solo, sweep or grid, goes through its entry.  The front doors here are:
+
+  * ``run_federated_compiled`` — the synchronous engine: ``fl_round`` over
+    pre-drawn (key, step) inputs, optionally carrying FedOpt-style
+    server-optimizer state (momentum / adam) via the same jitted
     ``server_opt.server_round_update`` the python loop applies.
   * ``run_async_compiled`` — the async engine: fleet latencies are a
     deterministic function of the seeded fleet and the pre-drawn key
@@ -19,10 +36,9 @@ Two compiled drivers:
     due/straggler/missed partitions, fedbuff flush boundaries and τ
     counters) is pre-computed on the host into fixed-width stacked arrays
     (``async_engine.build_deadline_plan`` / ``build_fedbuff_plan``) and
-    replayed inside a ``lax.scan`` whose body calls the *same* jitted
-    step functions the python event loop uses (``fl_round`` on sync-parity
-    fast rounds, ``deadline_slow_step`` / ``fedbuff_round_step``
-    otherwise).
+    replayed through the *same* jitted step functions the python event
+    loop uses (``fl_round`` on sync-parity fast rounds,
+    ``deadline_slow_step`` / ``fedbuff_round_step`` otherwise).
 
 Shared parity discipline: parameters ride the scan carry as a flat fp32
 buffer (``repro.core.flat``; exact ravel/unravel round-trip), pre-drawn
@@ -89,84 +105,143 @@ def draw_round_inputs(fl: simulator.FLConfig, rounds: int, init_key):
     return subs, steps
 
 
+def sync_round_xs(fl: simulator.FLConfig, rounds: int, init_key, sc=None):
+    """The sync scan's per-round rows, and the scenario's latency scales
+    for the clock replay (None without jitter).  With an active scenario
+    ``sc`` the key chain is the unmodified program's, the steps and the
+    upload mask carry the realized completeness and drop channels, and
+    ``corrupt`` the payload-corruption factors (absent when off)."""
+    if sc is None:
+        keys, steps = draw_round_inputs(fl, rounds, init_key)
+        return {"key": keys, "steps": steps}, None
+    sc_steps, sc_mask, sc_lat, sc_corr = \
+        simulator.scenario_round_inputs(fl, rounds, sc)
+    xs = {"key": _split_chain(init_key, rounds),
+          "steps": tprof.to_device(sc_steps),
+          "up_mask": tprof.to_device(sc_mask)}
+    if sc_corr is not None:
+        xs["corrupt"] = tprof.to_device(sc_corr)
+    return xs, sc_lat
+
+
+# per-round outputs every member of a sweep or grid shares: selection is
+# drawn before any member's own math, and out_axes None asserts it
+_SHARED_YS = ("ids", "ids2")
+
+
+def _scan(step, carry0, xs, hypers, member=None):
+    """The scan body of every compiled run: ``lax.scan`` of ``step`` over
+    the per-round rows of the dict ``xs``, from ``carry0 = (w_flat,
+    state)``.  Returns (final carry, ys), ys holding the post-round
+    ``"params"`` trajectory and whatever of ``ids``/``ids2``/``metrics``
+    the step emits (a step emits None for an output it does not have).
+
+    ``member`` (static) adds a leading member axis: None calls ``step``
+    directly (a solo run, never through a size-1 vmap, so the solo
+    program is the step's own); ``"sweep"`` vmaps it over the carry and
+    ``hypers``, every xs row shared; ``"grid"`` over the carry and every
+    xs row but the round ``"key"`` (one timeline config, one key chain),
+    ``hypers`` shared."""
+    def emit(carry, x_t, h):
+        carry, extras = step(carry, x_t, h)
+        return carry, {k: v for k, v in extras.items() if v is not None}
+
+    if member is None:
+        round_fn = emit
+    else:
+        def one(carry, x_t, h):
+            carry, ys = emit(carry, x_t, h)
+            shared = {k: ys.pop(k) for k in _SHARED_YS if k in ys}
+            return carry, ys, shared
+
+        if member == "sweep":
+            in_axes = (0, None, 0)
+        else:
+            in_axes = (0, {k: None if k == "key" else 0 for k in xs}, None)
+        vone = jax.vmap(one, in_axes=in_axes, out_axes=(0, 0, None))
+
+        def round_fn(carry, x_t, h):
+            carry, ys, shared = vone(carry, x_t, h)
+            return carry, {**ys, **shared}
+
+    def body(carry, x_t):
+        carry, ys = round_fn(carry, x_t, hypers)
+        return carry, {"params": carry[0], **ys}
+
+    return jax.lax.scan(body, carry0, xs)
+
+
+def _sync_round(model_cfg, fl, params, x, hypers, data, p_weights,
+                sel_probs, mesh):
+    """One synchronous round on row ``x``: ``fl_round_cohort`` on a cohort
+    the host gathered (``x["batch"]``), else ``fl_round`` drawing the
+    selection in the program from ``x["key"]`` over the resident
+    stacks."""
+    if "batch" in x:
+        return simulator.fl_round_cohort(
+            model_cfg, fl, params, x["batch"], x["steps"], hypers,
+            x.get("up_mask"), x.get("corrupt"), mesh=mesh)
+    return simulator.fl_round(
+        model_cfg, fl, params, data, p_weights, x["key"], x["steps"],
+        sel_probs, hypers, x.get("up_mask"), x.get("corrupt"), mesh=mesh)
+
+
+def _cohort(x, data):
+    """Row ``x``'s dispatched cohort: ``x["batch"]`` when the host gathered
+    it, else the rows of ``x["ids"]`` gathered from the resident stacks
+    in the program, under the local solve's trace scope as
+    ``simulator._local_updates`` gathers."""
+    if "batch" in x:
+        return x["batch"]
+    with jax.named_scope("local_solve"):
+        return simulator._client_batch(data, x["ids"])
+
+
 def make_sync_round_step(model_cfg, fl: simulator.FLConfig,
-                         spec: flat_lib.FlatSpec, use_so: bool, data,
-                         p_weights, sel_probs, mesh):
-    """The per-round flat-carry transition, shared VERBATIM by the solo
-    scan (``scan_rounds``) and the sweep engine (which vmaps it over a
-    stacked hypers/carry axis): unravel → ``fl_round`` → optional
-    ``server_round_update`` → ravel.  ``fl`` must be the canonical
-    ``timeline_config()``; every sweepable scalar arrives via ``hypers``.
-    """
+                         spec: flat_lib.FlatSpec, data, p_weights, sel_probs,
+                         mesh):
+    """One synchronous round as a ``_scan`` step over ``(w_flat,
+    so_state)``: unravel → ``fl_round`` / ``fl_round_cohort`` → the
+    server-optimizer update when ``so_state`` is not None → ravel.
+    ``fl`` must be the canonical ``timeline_config()``; every sweepable
+    scalar arrives via ``hypers``."""
     so_cfg = sopt.ServerOptConfig(kind=fl.server_opt, lr=1.0)
 
-    def step(w_flat, so_state, sub, n_steps, hypers, up_mask=None,
-             corrupt=None):
+    def step(carry, x, hypers):
+        w_flat, so_state = carry
         params = flat_lib.unravel(spec, w_flat)
-        new_params, diag = simulator.fl_round(
-            model_cfg, fl, params, data, p_weights, sub, n_steps,
-            sel_probs, hypers, up_mask, corrupt, mesh=mesh)
-        if use_so:
+        new_params, diag = _sync_round(model_cfg, fl, params, x, hypers,
+                                       data, p_weights, sel_probs, mesh)
+        if so_state is not None:
             new_params, so_state = sopt.server_round_update(
                 so_cfg, params, so_state, new_params, hypers["server_lr"])
-        w_new = flat_lib.ravel(spec, new_params)
-        extras = {"ids": diag["ids"]}
-        if "ids2" in diag:
-            extras["ids2"] = diag["ids2"]
-        if fl.telemetry:
-            extras["metrics"] = diag["metrics"]
-        return w_new, so_state, extras
+        return ((flat_lib.ravel(spec, new_params), so_state),
+                {k: diag.get(k) for k in ("ids", "ids2", "metrics")})
 
     return step
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
+                   static_argnames=("mesh", "member"))
 def scan_rounds(model_cfg, fl: simulator.FLConfig, spec: flat_lib.FlatSpec,
-                w0_flat, data, p_weights, keys, steps, hypers,
-                sel_probs=None, so_state0=None, up_mask=None, corrupt=None,
-                *, mesh=None):
-    """The whole-run XLA program: scan ``fl_round`` over pre-drawn inputs.
+                carry0, xs, hypers, data=None, p_weights=None,
+                sel_probs=None, *, mesh=None, member=None):
+    """The whole-run sync program: ``_scan`` of ``make_sync_round_step``.
 
-    Returns (final flat params, ys) where ys carries the per-round
-    post-update flat parameter trajectory and the sampled device ids.
-    ``fl`` is the canonical timeline config; ``hypers`` the traced
-    sweepable scalars (``simulator.hypers_of``).  ``sel_probs``/``mesh``
-    forward to ``fl_round`` (static selection distribution; D-sharded
-    flat aggregation).  With a FedOpt-style server optimizer configured,
-    ``so_state0`` seeds the optimizer state in the scan carry and each
-    round applies the same jitted ``server_round_update`` the python loop
-    uses.  ``up_mask`` (optional, (rounds, K) f32) is the scenario drop
-    channel: each round's row forwards to ``fl_round`` as the arrived-
-    upload mask; ``corrupt`` (optional, (rounds, K) f32) the realized
-    payload-corruption factors.  None for each is the exact pre-scenario
-    program.
-    """
-    # the caller encodes the use-a-server-optimizer decision in so_state0
-    # (one source of truth with run_federated_compiled's predicate)
-    use_so = so_state0 is not None
-    step = make_sync_round_step(model_cfg, fl, spec, use_so, data,
-                                p_weights, sel_probs, mesh)
-
-    def body(carry, xs):
-        w_flat, so_state = carry if use_so else (carry, None)
-        parts = list(xs)
-        corr = parts.pop() if corrupt is not None else None
-        um = parts.pop() if up_mask is not None else None
-        sub, n_steps = parts
-        w_new, so_state, extras = step(w_flat, so_state, sub, n_steps,
-                                       hypers, um, corr)
-        ys = {"params": w_new, **extras}
-        return ((w_new, so_state) if use_so else w_new), ys
-
-    carry0 = (w0_flat, so_state0) if use_so else w0_flat
-    xs = (keys, steps)
-    if up_mask is not None:
-        xs = xs + (up_mask,)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    carry, ys = jax.lax.scan(body, carry0, xs)
-    return (carry[0] if use_so else carry), ys
+    ``carry0`` is ``(w0_flat, so_state0)``, where ``so_state0`` (None for
+    plain SGD at server lr 1) seeds the server-optimizer state.  ``xs``
+    rows: ``"steps"`` and either ``"key"`` (resident ``data``;
+    ``sel_probs`` is the static selection distribution) or ``"batch"``
+    (host-gathered cohorts), plus the optional scenario rows
+    ``"up_mask"`` (the arrived-upload mask) and ``"corrupt"`` (payload
+    factors), each (rounds, K) f32.  ``fl`` is the canonical timeline
+    config, ``hypers`` the traced sweepable scalars
+    (``simulator.hypers_of``), ``mesh`` shards the flat aggregation's D
+    axis.  Returns (final flat params, ys)."""
+    step = make_sync_round_step(model_cfg, fl, spec, data, p_weights,
+                                sel_probs, mesh)
+    (w_final, _), ys = _scan(step, carry0, xs, hypers, member)
+    return w_final, ys
 
 
 def latency_selection_probs(model_cfg, fed: FederatedData, fl, fleet,
@@ -393,29 +468,15 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
         spec = flat_lib.spec_of(params)
         w0 = flat_lib.ravel(spec, params)
     with prof.phase("plan_build"):
-        if sc is None:
-            keys, steps = draw_round_inputs(fl, rounds, key)
-            up_mask = sc_lat = corrupt = None
-        else:
-            # same key chain as the unmodified program; steps/mask carry
-            # the realized completeness + drop channels, corrupt the
-            # payload-corruption factors (None when those channels are off)
-            sc_steps, sc_mask, sc_lat, sc_corr = \
-                simulator.scenario_round_inputs(fl, rounds, sc)
-            keys = _split_chain(key, rounds)
-            steps = tprof.to_device(sc_steps)
-            up_mask = tprof.to_device(sc_mask)
-            corrupt = None if sc_corr is None \
-                else tprof.to_device(sc_corr)
+        xs, sc_lat = sync_round_xs(fl, rounds, key, sc)
         so_cfg = sopt.ServerOptConfig(kind=fl.server_opt, lr=1.0)
         use_so = fl.server_opt != "sgd" or fl.server_lr != 1.0
         so_state0 = sopt.init_server_state(so_cfg, params) if use_so \
             else None
     with prof.phase("scan"):
         w_final, ys = scan_rounds(
-            model_cfg, fl.timeline_config(), spec, w0, train, p, keys,
-            steps, simulator.hypers_of(fl), sel_probs, so_state0, up_mask,
-            corrupt, mesh=mesh)
+            model_cfg, fl.timeline_config(), spec, (w0, so_state0), xs,
+            simulator.hypers_of(fl), train, p, sel_probs, mesh=mesh)
         if fl.telemetry:
             # attribute device time honestly when profiling (jax dispatch
             # is async); the telemetry-off path never adds a barrier
@@ -430,7 +491,7 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
                 model_cfg, params, fed, fl.algo, fleet,
                 tprof.fetch(ys["ids"]),
                 tprof.fetch(ys["ids2"]) if "ids2" in ys else None,
-                tprof.fetch(steps), rounds, lat_scale=sc_lat)
+                tprof.fetch(xs["steps"]), rounds, lat_scale=sc_lat)
         hist = eval_history_replay(model_cfg, spec, train, test, p,
                                    ys["params"], rounds, eval_every, clocks)
     with prof.phase("collect"):
@@ -452,141 +513,133 @@ def run_federated_compiled(model_cfg, fed: FederatedData,
 
 def make_deadline_step(model_cfg, afl, spec: flat_lib.FlatSpec, data,
                        p_weights, sel_probs, mesh, always_slow=False):
-    """One planned deadline round as a flat-carry transition, shared
-    VERBATIM by the solo scan and the vmapped sweep engine: sync-parity
-    fast rounds run the same jitted ``simulator.fl_round`` the python
-    loop calls (under ``lax.cond``), every other round runs the shared
-    ``async_engine.deadline_slow_step`` against the pending-straggler
-    slot pool.  ``afl`` must be the canonical ``timeline_config()``.
+    """One planned deadline round as a ``_scan`` step over ``(w_flat,
+    pool)``: sync-parity fast rounds (``x["fast"]``) run the same jitted
+    sync round the python loop calls (under ``lax.cond``), every other
+    round the shared ``async_engine.deadline_slow_step`` against the
+    pending-straggler slot pool.  ``afl`` must be the canonical
+    ``timeline_config()``.
 
     ``always_slow`` (static): skip the cond and run the slow branch
     unconditionally.  Bit-identical whenever the caller's entire fast
     array is False (cond on a False predicate IS the slow branch) — the
-    vmapped grid/sweep engines use it because their batched cond lowers
-    to a select that executes BOTH branches for every member, and any
-    active drop scenario leaves essentially no fast rounds to select."""
+    vmapped grid/sweep runs use it because their batched cond lowers to a
+    select that executes BOTH branches for every member, and any active
+    drop scenario leaves essentially no fast rounds to select."""
     fl = afl.sync_config()
 
-    def step(w_flat, pend, xs, hypers, corrupt=None):
-        sub, ids_t, steps_t, arr_t, store_t, due_s, due_m, due_t, fast_t = xs
+    def step(carry, x, hypers):
+        w_flat, pend = carry
         params = flat_lib.unravel(spec, w_flat)
 
-        # with telemetry both branches return a third metrics pytree; the
-        # schemas are structurally identical by construction (the sync
+        # both branches return a metrics pytree (None without telemetry);
+        # the schemas are structurally identical by construction (the sync
         # round is the τ = 0 full-mask case), which lax.cond requires
         def fast_fn(params, pend):
-            new, diag = simulator.fl_round(model_cfg, fl, params, data,
-                                           p_weights, sub, steps_t,
-                                           sel_probs, hypers, None, corrupt,
-                                           mesh=mesh)
-            if fl.telemetry:
-                return flat_lib.ravel(spec, new), pend, diag["metrics"]
-            return flat_lib.ravel(spec, new), pend
+            new, diag = _sync_round(model_cfg, fl, params, x, hypers, data,
+                                    p_weights, sel_probs, mesh)
+            return flat_lib.ravel(spec, new), pend, diag.get("metrics")
 
         def slow_fn(params, pend):
-            out = async_lib.deadline_slow_step(
-                model_cfg, afl, params, pend, data, ids_t, steps_t, arr_t,
-                store_t, due_s, due_m, due_t, hypers, corrupt, mesh=mesh)
-            if afl.telemetry:
-                new, pend2, m = out
-                return flat_lib.ravel(spec, new), pend2, m
-            new, pend2 = out
-            return flat_lib.ravel(spec, new), pend2
+            new, pend2, m = async_lib.deadline_slow_step(
+                model_cfg, afl, params, pend, _cohort(x, data), x["steps"],
+                x["arrived"], x["store_slot"], x["due_slot"], x["due_mask"],
+                x["due_tau"], hypers, x.get("corrupt"), mesh=mesh)
+            return flat_lib.ravel(spec, new), pend2, m
 
         if always_slow:
-            return slow_fn(params, pend)
-        return jax.lax.cond(fast_t, fast_fn, slow_fn, params, pend)
+            w_new, pend, m = slow_fn(params, pend)
+        else:
+            w_new, pend, m = jax.lax.cond(x["fast"], fast_fn, slow_fn,
+                                          params, pend)
+        return (w_new, pend), {"metrics": m}
 
     return step
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def scan_async_deadline(model_cfg, afl, spec: flat_lib.FlatSpec, w0_flat,
-                        pend0, data, p_weights, keys, ids, steps, arrived,
-                        store_slot, due_slot, due_mask, due_tau, fast,
-                        hypers, sel_probs=None, corrupt=None, *, mesh=None):
-    """Whole-run deadline-mode XLA program: scan ``make_deadline_step``
-    over the planned timeline, carrying the straggler pool.  ``corrupt``
-    (optional, (R, K) f32 — the realized payload-corruption factors)
-    forwards per round to both cond branches; None is the exact
-    pre-scenario program."""
+                   static_argnames=("mesh", "member", "always_slow"))
+def scan_deadline_cohort(model_cfg, afl, spec: flat_lib.FlatSpec, carry0,
+                         xs, hypers, data=None, p_weights=None,
+                         sel_probs=None, *, mesh=None, member=None,
+                         always_slow=False):
+    """The whole-run deadline program: ``_scan`` of ``make_deadline_step``
+    over the planned timeline (``deadline_xs``), carrying ``(w0_flat,
+    pool0)``.  Lazy cohort runs pass their gathered batches; resident,
+    sweep and grid runs the round keys and ids over ``data``."""
     step = make_deadline_step(model_cfg, afl, spec, data, p_weights,
-                              sel_probs, mesh)
+                              sel_probs, mesh, always_slow=always_slow)
+    (w_final, _), ys = _scan(step, carry0, xs, hypers, member)
+    return w_final, ys
 
-    def body(carry, xs):
-        if corrupt is None:
-            corr = None
-        else:
-            *xs, corr = xs
-            xs = tuple(xs)
-        out = step(carry[0], carry[1], xs, hypers, corr)
-        if afl.telemetry:
-            w_new, pend, m = out
-            return (w_new, pend), {"params": w_new, "metrics": m}
-        w_new, pend = out
-        return (w_new, pend), w_new
 
-    xs = (keys, ids, steps, arrived, store_slot, due_slot, due_mask,
-          due_tau, fast)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_flat, pend0), xs)
-    return w_final, ws
+def deadline_xs(plan, batch=None, put=tprof.to_device):
+    """A deadline plan's per-round scan rows, each moved to the device by
+    ``put`` (a grid stacks (S, R) cell plans into (R, S) rows).  The
+    cohort is ``batch`` (host-gathered cohorts) or, without one, the
+    plan's round keys (shared by every cell) and dispatched ids; the
+    optional ``corrupt`` row is the scenario's payload factors."""
+    xs = {"steps": put(plan.n_steps),
+          "arrived": put(plan.arrived, jnp.float32),
+          "store_slot": put(plan.store_slot),
+          "due_slot": put(plan.due_slot), "due_mask": put(plan.due_mask),
+          "due_tau": put(plan.due_tau), "fast": put(plan.fast)}
+    if batch is not None:
+        xs["batch"] = batch
+    else:
+        xs["key"] = tprof.to_device(plan.keys)
+        xs["ids"] = put(plan.ids)
+    if plan.corrupt is not None:
+        xs["corrupt"] = put(plan.corrupt)
+    return xs
 
 
 def make_fedbuff_step(model_cfg, afl, spec: flat_lib.FlatSpec, data, mesh):
-    """One planned fedbuff flush as a flat-carry transition (shared by the
-    solo scan and the vmapped sweep engine).  ``afl`` must be the
-    canonical ``timeline_config()``."""
-    def step(w_flat, pend, xs, hypers, flush_mask=None, corrupt=None):
-        ids_t, steps_t, store_t, flush_t, tau_t = xs
-        params = flat_lib.unravel(spec, w_flat)
-        out = async_lib.fedbuff_round_step(
-            model_cfg, afl, params, pend, data, ids_t, steps_t, store_t,
-            flush_t, tau_t, hypers, flush_mask=flush_mask, corrupt=corrupt,
+    """One planned fedbuff flush as a ``_scan`` step over ``(w_flat,
+    pool)``: the shared ``async_engine.fedbuff_round_step`` on the round's
+    dispatch cohort.  ``afl`` must be the canonical
+    ``timeline_config()``."""
+    def step(carry, x, hypers):
+        w_flat, pend = carry
+        new, pend, m = async_lib.fedbuff_round_step(
+            model_cfg, afl, flat_lib.unravel(spec, w_flat), pend,
+            _cohort(x, data), x["steps"], x["store_slot"], x["flush_slot"],
+            x["tau"], hypers, x.get("flush_mask"), x.get("corrupt"),
             mesh=mesh)
-        if afl.telemetry:
-            new, pend, m = out
-            return flat_lib.ravel(spec, new), pend, m
-        new, pend = out
-        return flat_lib.ravel(spec, new), pend
+        return (flat_lib.ravel(spec, new), pend), {"metrics": m}
 
     return step
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def scan_async_fedbuff(model_cfg, afl, spec: flat_lib.FlatSpec, w0_flat,
-                       pend0, data, ids, steps, store_slot, flush_slot, tau,
-                       hypers, flush_mask=None, corrupt=None, *, mesh=None):
-    """Whole-run fedbuff XLA program: scan the shared
-    ``async_engine.fedbuff_round_step`` over the planned flush schedule,
-    carrying the in-flight update pool.  ``flush_mask`` (optional,
-    (R, M) f32 — the scenario drop channel) excludes failed uploads from
-    each flush's aggregation; ``corrupt`` (optional, (R, W) f32) scales
-    each planned dispatch's stored payload.  None for each is the exact
-    pre-scenario program."""
+                   static_argnames=("mesh", "member"))
+def scan_fedbuff_cohort(model_cfg, afl, spec: flat_lib.FlatSpec, carry0, xs,
+                        hypers, data=None, *, mesh=None, member=None):
+    """The whole-run fedbuff program: ``_scan`` of ``make_fedbuff_step``
+    over the planned flush schedule (``fedbuff_xs``), carrying the
+    in-flight update pool.  Lazy cohort runs pass their gathered batches;
+    resident, sweep and grid runs the dispatched ids over ``data``."""
     step = make_fedbuff_step(model_cfg, afl, spec, data, mesh)
+    (w_final, _), ys = _scan(step, carry0, xs, hypers, member)
+    return w_final, ys
 
-    def body(carry, xs):
-        parts = list(xs)
-        corr = parts.pop() if corrupt is not None else None
-        fm = parts.pop() if flush_mask is not None else None
-        out = step(carry[0], carry[1], tuple(parts), hypers, fm, corr)
-        if afl.telemetry:
-            w_new, pend, m = out
-            return (w_new, pend), {"params": w_new, "metrics": m}
-        w_new, pend = out
-        return (w_new, pend), w_new
 
-    xs = (ids, steps, store_slot, flush_slot, tau)
-    if flush_mask is not None:
-        xs = xs + (flush_mask,)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_flat, pend0), xs)
-    return w_final, ws
+def fedbuff_xs(plan, batch=None, put=tprof.to_device):
+    """A fedbuff plan's per-round scan rows, each moved to the device by
+    ``put``.  The cohort is ``batch`` or the plan's dispatched ids; the
+    optional scenario rows are ``flush_mask`` ((R, M) f32, the failed
+    uploads a flush excludes) and ``corrupt`` ((R, W) f32 payload factors
+    of each planned dispatch)."""
+    xs = {"steps": put(plan.n_steps), "store_slot": put(plan.store_slot),
+          "flush_slot": put(plan.flush_slot), "tau": put(plan.tau)}
+    if batch is not None:
+        xs["batch"] = batch
+    else:
+        xs["ids"] = put(plan.ids)
+    for k in ("flush_mask", "corrupt"):
+        if getattr(plan, k) is not None:
+            xs[k] = put(getattr(plan, k))
+    return xs
 
 
 def run_async_compiled(model_cfg, fed: FederatedData, afl,
@@ -648,19 +701,11 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
             pend0 = async_lib.pool_init(model_cfg, sync_fl, params, train,
                                         plan.n_slots + 1)
         with prof.phase("scan"):
-            w_final, ws = scan_async_deadline(
-                model_cfg, afl_t, spec, w0, pend0, train, p,
-                tprof.to_device(plan.keys), tprof.to_device(plan.ids),
-                tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.arrived, jnp.float32),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.due_slot),
-                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
-                tprof.to_device(plan.fast), hypers, sel_probs,
-                None if plan.corrupt is None
-                else tprof.to_device(plan.corrupt), mesh=mesh)
+            w_final, ys = scan_deadline_cohort(
+                model_cfg, afl_t, spec, (w0, pend0), deadline_xs(plan),
+                hypers, train, p, sel_probs, mesh=mesh)
             if afl.telemetry:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks, n_arr = plan.round_end, plan.n_arrived
     else:
         with prof.phase("plan_build"):
@@ -671,40 +716,33 @@ def run_async_compiled(model_cfg, fed: FederatedData, afl,
             pend0 = async_lib.pool_init(model_cfg, sync_fl, params, train,
                                         plan.n_slots)
             pend0 = async_lib.fedbuff_seed_pool(
-                model_cfg, afl_t, params, pend0, train,
-                tprof.to_device(plan.seed_ids),
+                model_cfg, afl_t, params, pend0,
+                simulator._client_batch(train,
+                                        tprof.to_device(plan.seed_ids)),
                 tprof.to_device(plan.seed_steps),
                 tprof.to_device(plan.seed_slots), hypers,
                 None if plan.seed_corrupt is None
                 else tprof.to_device(plan.seed_corrupt))
         with prof.phase("scan"):
-            w_final, ws = scan_async_fedbuff(
-                model_cfg, afl_t, spec, w0, pend0, train,
-                tprof.to_device(plan.ids), tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.flush_slot),
-                tprof.to_device(plan.tau), hypers,
-                None if plan.flush_mask is None
-                else tprof.to_device(plan.flush_mask),
-                None if plan.corrupt is None
-                else tprof.to_device(plan.corrupt), mesh=mesh)
+            w_final, ys = scan_fedbuff_cohort(
+                model_cfg, afl_t, spec, (w0, pend0), fedbuff_xs(plan),
+                hypers, train, mesh=mesh)
             if afl.telemetry:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks = plan.flush_clock
         n_arr = (np.full(rounds, afl.buffer_size)
                  if plan.flush_mask is None
                  else plan.flush_mask.sum(axis=1).astype(np.int64))
 
-    params_traj = ws["params"] if afl.telemetry else ws
     with prof.phase("eval"):
         hist = eval_history_replay(model_cfg, spec, train, test, p,
-                                   params_traj, rounds, eval_every,
+                                   ys["params"], rounds, eval_every,
                                    clocks=clocks, n_arrived=n_arr,
                                    stale_mean=plan.stale_mean)
     with prof.phase("collect"):
         metrics = None
         if afl.telemetry:
-            metrics = {k: tprof.fetch(v) for k, v in ws["metrics"].items()}
+            metrics = {k: tprof.fetch(v) for k, v in ys["metrics"].items()}
             D = int(sum(x.size for x in jax.tree.leaves(params)))
             if afl.mode == "deadline":
                 metrics.update(tmetrics.deadline_network_series(D, afl,

@@ -238,10 +238,10 @@ def _local_updates_batch(model_cfg, params, batch, n_steps, fl: FLConfig,
                          hypers=None):
     """vmapped device updates over a pre-gathered (K, M, ...) cohort
     batch -> stacked (deltas, grads, gammas).  The shared local-solve
-    unit of both the resident path (`_local_updates`, which gathers from
-    the (N, M, ...) stack first) and the lazy-population cohort steps
-    (which receive host-gathered batches) — one function, so the two
-    paths run the identical math."""
+    unit of `fl_round` (through `_local_updates`, which gathers from the
+    (N, M, ...) stack first), `fl_round_cohort` and the async steps
+    (which take a gathered batch) — one function, so every path runs
+    the identical math."""
     lr = fl.lr if hypers is None else hypers["lr"]
     mu = fl.mu if hypers is None else hypers["mu"]
 

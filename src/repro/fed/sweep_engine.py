@@ -9,13 +9,12 @@ shares everything that is expensive to build or compile:
   * the event plan (``async_engine.build_deadline_plan`` /
     ``build_fedbuff_plan``) and the pre-drawn key chain are built ONCE and
     replayed by every sweep member;
-  * the learning math for all S configs runs in a SINGLE XLA program: the
-    same per-round step functions the solo engines scan
-    (``scan_engine.make_sync_round_step`` / ``make_deadline_step`` /
-    ``make_fedbuff_step``, which call the shared jitted ``fl_round``,
-    ``deadline_slow_step``, ``fedbuff_round_step`` and
-    ``server_round_update``) are vmapped over a stacked (S, D) flat-param
-    carry — plus the (S,)-stacked hypers and, for the async modes, the
+  * the learning math for all S configs runs in a SINGLE XLA program:
+    the one scan body of ``scan_engine`` with ``member="sweep"``, which
+    vmaps the very per-round steps a solo run calls (the shared jitted
+    ``fl_round``, ``deadline_slow_step``, ``fedbuff_round_step`` and
+    ``server_round_update``) over a stacked (S, D) flat-param carry —
+    plus the (S,)-stacked hypers and, for the async modes, the
     (S, P, ...) pending pools — inside one ``lax.scan`` over rounds.
 
 Per-config host cost drops to ~zero (no per-member plan building, input
@@ -36,7 +35,6 @@ future config fields cannot silently corrupt plan reuse.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Mapping, Optional, Sequence, Tuple, Union
 
 import jax
@@ -173,59 +171,6 @@ class SweepResult:
 
 # ----------------------------------------------------------- sync sweeps
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def sweep_scan_rounds(model_cfg, fl, spec: flat_lib.FlatSpec, w0_S, data,
-                      p_weights, keys, steps, hypers_S, sel_probs=None,
-                      so_state0_S=None, up_mask=None, corrupt=None,
-                      *, mesh=None):
-    """The whole-sweep XLA program: one ``lax.scan`` over rounds whose
-    body vmaps the SAME per-round step the solo scan uses
-    (``scan_engine.make_sync_round_step``) over the stacked (S, D) carry
-    and (S,) hypers.  Selection stays unbatched inside the vmap (keys and
-    probs are shared), so every member samples the same devices — the
-    shared-timeline property, asserted by ``out_axes=None`` on the ids.
-    """
-    use_so = so_state0_S is not None
-    step = scan_engine.make_sync_round_step(
-        model_cfg, fl, spec, use_so, data, p_weights, sel_probs, mesh)
-
-    # ids stay unbatched (out_axes None asserts the shared timeline);
-    # per-round metrics DO vary per member (deltas depend on lr/mu), so
-    # with telemetry they come back stacked along the sweep axis
-    extras_axes = {"ids": None}
-    if fl.algo == "folb2":
-        extras_axes["ids2"] = None
-    if fl.telemetry:
-        extras_axes["metrics"] = 0
-
-    def body(carry, xs):
-        w_S, so_S = carry if use_so else (carry, None)
-        # the scenario mask/corruption rows are timeline-shared: one row
-        # per round, closed over unbatched so every member drops (and
-        # corrupts) the same uploads
-        parts = list(xs)
-        corr = parts.pop() if corrupt is not None else None
-        um = parts.pop() if up_mask is not None else None
-        sub, n_steps = parts
-        vstep = jax.vmap(
-            lambda w, so, h: step(w, so, sub, n_steps, h, um, corr),
-            in_axes=(0, 0 if use_so else None, 0),
-            out_axes=(0, 0 if use_so else None, extras_axes))
-        w_new, so_S, extras = vstep(w_S, so_S, hypers_S)
-        ys = {"params": w_new, **extras}
-        return ((w_new, so_S) if use_so else w_new), ys
-
-    carry0 = (w0_S, so_state0_S) if use_so else w0_S
-    xs = (keys, steps)
-    if up_mask is not None:
-        xs = xs + (up_mask,)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    carry, ys = jax.lax.scan(body, carry0, xs)
-    return (carry[0] if use_so else carry), ys
-
-
 def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
                        rounds: int,
                        init_key: Optional[jax.Array] = None,
@@ -265,17 +210,7 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
         w0 = flat_lib.ravel(fspec, params)
         w0_S = jnp.broadcast_to(w0, (S,) + w0.shape)
     with prof.phase("plan_build"):
-        if sc is None:
-            keys, steps = scan_engine.draw_round_inputs(base, rounds, key)
-            up_mask = sc_lat = corrupt = None
-        else:
-            sc_steps, sc_mask, sc_lat, sc_corr = \
-                simulator.scenario_round_inputs(base, rounds, sc)
-            keys = scan_engine._split_chain(key, rounds)
-            steps = tprof.to_device(sc_steps)
-            up_mask = tprof.to_device(sc_mask)
-            corrupt = None if sc_corr is None \
-                else tprof.to_device(sc_corr)
+        xs, sc_lat = scan_engine.sync_round_xs(base, rounds, key, sc)
         # uniform across members (SweepSpec validates), so member 0
         # decides — the same predicate each member's solo run applies
         use_so = _uses_server_opt(spec.member(0))
@@ -286,10 +221,10 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
             so_state0_S = jax.tree.map(
                 lambda x: jnp.broadcast_to(x, (S,) + x.shape), so0)
     with prof.phase("scan"):
-        w_final_S, ys = sweep_scan_rounds(
-            model_cfg, base.timeline_config(), fspec, w0_S, train, p, keys,
-            steps, spec.stacked_hypers(), sel_probs, so_state0_S, up_mask,
-            corrupt, mesh=mesh)
+        w_final_S, ys = scan_engine.scan_rounds(
+            model_cfg, base.timeline_config(), fspec, (w0_S, so_state0_S),
+            xs, spec.stacked_hypers(), train, p, sel_probs, mesh=mesh,
+            member="sweep")
         if base.telemetry or profiler is not None:
             # an explicit profiler wants honest phase attribution: block
             # here so the async scan's compute doesn't land in `eval`
@@ -304,7 +239,7 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
                 model_cfg, params, fed, base.algo, fleet,
                 tprof.fetch(ys["ids"]),
                 tprof.fetch(ys["ids2"]) if "ids2" in ys else None,
-                tprof.fetch(steps), rounds, lat_scale=sc_lat)
+                tprof.fetch(xs["steps"]), rounds, lat_scale=sc_lat)
         hists = scan_engine.eval_history_replay_sweep(
             model_cfg, fspec, train, test, p, ys["params"], rounds,
             eval_every, clocks)
@@ -335,89 +270,6 @@ def run_sweep_compiled(model_cfg, fed: FederatedData, spec: SweepSpec,
 
 
 # ---------------------------------------------------------- async sweeps
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh", "always_slow"))
-def sweep_scan_deadline(model_cfg, afl, spec: flat_lib.FlatSpec, w0_S,
-                        pend0_S, data, p_weights, keys, ids, steps, arrived,
-                        store_slot, due_slot, due_mask, due_tau, fast,
-                        hypers_S, sel_probs=None, corrupt=None,
-                        *, mesh=None, always_slow=False):
-    """Whole-sweep deadline program: scan over the ONE shared event plan,
-    vmapping ``scan_engine.make_deadline_step`` over the stacked carries
-    (flat params + per-member straggler pools) and hypers.  ``corrupt``
-    ((R, K) f32 payload factors) is timeline-shared: the per-round row is
-    closed over unbatched so every member corrupts the same uploads.
-    ``always_slow`` skips the step's cond (bit-identical when the plan
-    has no fast rounds — see ``grid_scan_deadline``)."""
-    step = scan_engine.make_deadline_step(model_cfg, afl, spec, data,
-                                          p_weights, sel_probs, mesh,
-                                          always_slow=always_slow)
-
-    def body(carry, xs):
-        w_S, pend_S = carry
-        if corrupt is None:
-            corr = None
-        else:
-            *xs, corr = xs
-            xs = tuple(xs)
-        if afl.telemetry:
-            w_new, pend_S, m = jax.vmap(
-                lambda w, pend, h: step(w, pend, xs, h, corr))(w_S, pend_S,
-                                                               hypers_S)
-            return (w_new, pend_S), {"params": w_new, "metrics": m}
-        w_new, pend_S = jax.vmap(
-            lambda w, pend, h: step(w, pend, xs, h, corr))(w_S, pend_S,
-                                                           hypers_S)
-        return (w_new, pend_S), w_new
-
-    xs = (keys, ids, steps, arrived, store_slot, due_slot, due_mask,
-          due_tau, fast)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_S, pend0_S), xs)
-    return w_final, ws
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def sweep_scan_fedbuff(model_cfg, afl, spec: flat_lib.FlatSpec, w0_S,
-                       pend0_S, data, ids, steps, store_slot, flush_slot,
-                       tau, hypers_S, flush_mask=None, corrupt=None,
-                       *, mesh=None):
-    """Whole-sweep fedbuff program: scan the shared flush schedule,
-    vmapping ``scan_engine.make_fedbuff_step`` over the stacked carries
-    (flat params + per-member in-flight pools) and hypers.
-    ``flush_mask`` ((R, M) f32, the scenario drop channel) and ``corrupt``
-    ((R, W) f32 payload factors) are timeline-shared: the per-round rows
-    are closed over unbatched so every member drops/corrupts the same
-    uploads."""
-    step = scan_engine.make_fedbuff_step(model_cfg, afl, spec, data, mesh)
-
-    def body(carry, xs):
-        w_S, pend_S = carry
-        parts = list(xs)
-        corr = parts.pop() if corrupt is not None else None
-        fm = parts.pop() if flush_mask is not None else None
-        xs = tuple(parts)
-        if afl.telemetry:
-            w_new, pend_S, m = jax.vmap(
-                lambda w, pend, h: step(w, pend, xs, h, fm, corr))(
-                    w_S, pend_S, hypers_S)
-            return (w_new, pend_S), {"params": w_new, "metrics": m}
-        w_new, pend_S = jax.vmap(
-            lambda w, pend, h: step(w, pend, xs, h, fm, corr))(w_S, pend_S,
-                                                               hypers_S)
-        return (w_new, pend_S), w_new
-
-    xs = (ids, steps, store_slot, flush_slot, tau)
-    if flush_mask is not None:
-        xs = xs + (flush_mask,)
-    if corrupt is not None:
-        xs = xs + (corrupt,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_S, pend0_S), xs)
-    return w_final, ws
-
 
 def run_async_sweep_compiled(model_cfg, fed: FederatedData,
                              spec: SweepSpec, fleet, rounds: int,
@@ -476,20 +328,13 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
             pend0_S = bcast(async_lib.pool_init(model_cfg, sync_fl, params,
                                                 train, plan.n_slots + 1))
         with prof.phase("scan"):
-            w_final_S, ws = sweep_scan_deadline(
-                model_cfg, afl_t, fspec, w0_S, pend0_S, train, p,
-                tprof.to_device(plan.keys), tprof.to_device(plan.ids),
-                tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.arrived, jnp.float32),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.due_slot),
-                tprof.to_device(plan.due_mask), tprof.to_device(plan.due_tau),
-                tprof.to_device(plan.fast), hypers_S, sel_probs,
-                None if plan.corrupt is None
-                else tprof.to_device(plan.corrupt), mesh=mesh,
+            w_final_S, ys = scan_engine.scan_deadline_cohort(
+                model_cfg, afl_t, fspec, (w0_S, pend0_S),
+                scan_engine.deadline_xs(plan), hypers_S, train, p, sel_probs,
+                mesh=mesh, member="sweep",
                 always_slow=not bool(np.asarray(plan.fast).any()))
             if base.telemetry or profiler is not None:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks, n_arr = plan.round_end, plan.n_arrived
     else:
         with prof.phase("plan_build"):
@@ -505,33 +350,27 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
                          else tprof.to_device(plan.seed_corrupt))
             pend0_S = jax.vmap(
                 lambda pend, h: async_lib.fedbuff_seed_pool(
-                    model_cfg, afl_t, params, pend, train,
-                    tprof.to_device(plan.seed_ids),
+                    model_cfg, afl_t, params, pend,
+                    simulator._client_batch(
+                        train, tprof.to_device(plan.seed_ids)),
                     tprof.to_device(plan.seed_steps),
                     tprof.to_device(plan.seed_slots), h,
                     seed_corr))(bcast(pend0), hypers_S)
         with prof.phase("scan"):
-            w_final_S, ws = sweep_scan_fedbuff(
-                model_cfg, afl_t, fspec, w0_S, pend0_S, train,
-                tprof.to_device(plan.ids), tprof.to_device(plan.n_steps),
-                tprof.to_device(plan.store_slot),
-                tprof.to_device(plan.flush_slot),
-                tprof.to_device(plan.tau), hypers_S,
-                None if plan.flush_mask is None
-                else tprof.to_device(plan.flush_mask),
-                None if plan.corrupt is None
-                else tprof.to_device(plan.corrupt), mesh=mesh)
+            w_final_S, ys = scan_engine.scan_fedbuff_cohort(
+                model_cfg, afl_t, fspec, (w0_S, pend0_S),
+                scan_engine.fedbuff_xs(plan), hypers_S, train, mesh=mesh,
+                member="sweep")
             if base.telemetry or profiler is not None:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks = plan.flush_clock
         n_arr = (np.full(rounds, base.buffer_size)
                  if plan.flush_mask is None
                  else plan.flush_mask.sum(axis=1).astype(np.int64))
 
-    params_traj = ws["params"] if base.telemetry else ws
     with prof.phase("eval"):
         hists = scan_engine.eval_history_replay_sweep(
-            model_cfg, fspec, train, test, p, params_traj, rounds,
+            model_cfg, fspec, train, test, p, ys["params"], rounds,
             eval_every, clocks=clocks, n_arrived=n_arr,
             stale_mean=plan.stale_mean)
     with prof.phase("collect"):
@@ -552,7 +391,7 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
             metrics = None
             if base.telemetry:
                 metrics = {k: tprof.fetch(v[:, i])
-                           for k, v in ws["metrics"].items()}
+                           for k, v in ys["metrics"].items()}
                 metrics.update(shared)
             results.append(simulator.FedRunResult(
                 history=hists[i],
@@ -567,10 +406,10 @@ def run_async_sweep_compiled(model_cfg, fed: FederatedData,
 # The dual of the hyper sweep: a hyper sweep varies the learning math
 # over ONE shared timeline, a scenario grid varies the TIMELINE (failure
 # realizations, hence masks/arrivals/pools) under one learning config.
-# The same shared round steps are vmapped — here over per-cell xs rows
-# and per-cell pending pools, with the hypers closed over unbatched —
-# so grid cell i stays bit-for-bit identical to a solo run under
-# scenario i (tests/test_scenario_grid.py).
+# The same scan body runs with member="grid": the round steps are vmapped
+# over per-cell xs rows and per-cell pending pools, with the round keys
+# and hypers shared, so grid cell i stays bit-for-bit identical to a solo
+# run under scenario i (tests/test_scenario_grid.py).
 
 @dataclasses.dataclass
 class ScenarioGridResult:
@@ -591,139 +430,6 @@ class ScenarioGridResult:
 
     def __iter__(self):
         return iter(self.results)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def grid_scan_rounds(model_cfg, fl, spec: flat_lib.FlatSpec, w0_S, data,
-                     p_weights, keys, steps_S, hypers, up_mask_S,
-                     corrupt_S=None, sel_probs=None, so_state0_S=None,
-                     *, mesh=None):
-    """Whole-grid sync program: one ``lax.scan`` over rounds whose body
-    vmaps ``scan_engine.make_sync_round_step`` over the S_scenario axis
-    of the carry and the per-cell step/mask/corrupt rows.  Selection is
-    scenario-independent (ids are drawn before the failure channels
-    apply), so keys and hypers stay unbatched and ``out_axes=None`` on
-    the ids structurally asserts the shared selection stream."""
-    use_so = so_state0_S is not None
-    step = scan_engine.make_sync_round_step(
-        model_cfg, fl, spec, use_so, data, p_weights, sel_probs, mesh)
-
-    extras_axes = {"ids": None}
-    if fl.algo == "folb2":
-        extras_axes["ids2"] = None
-    if fl.telemetry:
-        extras_axes["metrics"] = 0
-
-    def body(carry, xs):
-        w_S, so_S = carry if use_so else (carry, None)
-        parts = list(xs)
-        corr_S = parts.pop() if corrupt_S is not None else None
-        sub, steps_t, um_t = parts
-        vstep = jax.vmap(
-            lambda w, so, ns, um, corr: step(w, so, sub, ns, hypers, um,
-                                             corr),
-            in_axes=(0, 0 if use_so else None, 0, 0,
-                     0 if corrupt_S is not None else None),
-            out_axes=(0, 0 if use_so else None, extras_axes))
-        w_new, so_S, extras = vstep(w_S, so_S, steps_t, um_t, corr_S)
-        ys = {"params": w_new, **extras}
-        return ((w_new, so_S) if use_so else w_new), ys
-
-    carry0 = (w0_S, so_state0_S) if use_so else w0_S
-    xs = (keys, steps_S, up_mask_S)
-    if corrupt_S is not None:
-        xs = xs + (corrupt_S,)
-    carry, ys = jax.lax.scan(body, carry0, xs)
-    return (carry[0] if use_so else carry), ys
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh", "always_slow"))
-def grid_scan_deadline(model_cfg, afl, spec: flat_lib.FlatSpec, w0_S,
-                       pend0_S, data, p_weights, keys, ids_S, steps_S,
-                       arrived_S, store_slot_S, due_slot_S, due_mask_S,
-                       due_tau_S, fast_S, hypers, sel_probs=None,
-                       corrupt_S=None, *, mesh=None, always_slow=False):
-    """Whole-grid deadline program: scan the stacked plan, vmapping
-    ``scan_engine.make_deadline_step`` over each cell's plan rows and
-    straggler pool.  The round subkeys stay unbatched (one timeline
-    config, one key chain); the per-cell ``fast`` flags lower the step's
-    ``lax.cond`` to a select under vmap, which keeps the taken branch's
-    values bit-identical to the solo scan's — but a select executes BOTH
-    branches for every cell, so the driver passes ``always_slow=True``
-    (skip the cond, bit-identical) whenever no cell has a fast round,
-    which is the norm for active drop scenarios."""
-    step = scan_engine.make_deadline_step(model_cfg, afl, spec, data,
-                                          p_weights, sel_probs, mesh,
-                                          always_slow=always_slow)
-
-    def body(carry, xs):
-        w_S, pend_S = carry
-        sub = xs[0]
-        rest = xs[1:]
-        if corrupt_S is not None:
-            *rest, corr = rest
-            rest = tuple(rest)
-        else:
-            corr = None
-        in_ax = (0, 0, 0, 0 if corrupt_S is not None else None)
-
-        def one(w, pend, row, corr_c):
-            return step(w, pend, (sub,) + row, hypers, corr_c)
-
-        if afl.telemetry:
-            w_new, pend_S, m = jax.vmap(one, in_axes=in_ax)(
-                w_S, pend_S, rest, corr)
-            return (w_new, pend_S), {"params": w_new, "metrics": m}
-        w_new, pend_S = jax.vmap(one, in_axes=in_ax)(w_S, pend_S, rest, corr)
-        return (w_new, pend_S), w_new
-
-    xs = (keys, ids_S, steps_S, arrived_S, store_slot_S, due_slot_S,
-          due_mask_S, due_tau_S, fast_S)
-    if corrupt_S is not None:
-        xs = xs + (corrupt_S,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_S, pend0_S), xs)
-    return w_final, ws
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2),
-                   static_argnames=("mesh",))
-def grid_scan_fedbuff(model_cfg, afl, spec: flat_lib.FlatSpec, w0_S,
-                      pend0_S, data, ids_S, steps_S, store_slot_S,
-                      flush_slot_S, tau_S, hypers, flush_mask_S,
-                      corrupt_S=None, *, mesh=None):
-    """Whole-grid fedbuff program: scan the stacked flush schedule,
-    vmapping ``scan_engine.make_fedbuff_step`` over each cell's dispatch
-    rows and in-flight pool.  Active cells always carry a flush mask
-    (the drop channel's per-flush validity), so it is a required
-    per-cell operand here."""
-    step = scan_engine.make_fedbuff_step(model_cfg, afl, spec, data, mesh)
-
-    def body(carry, xs):
-        w_S, pend_S = carry
-        parts = list(xs)
-        corr = parts.pop() if corrupt_S is not None else None
-        fm = parts.pop()
-        rest = tuple(parts)
-        in_ax = (0, 0, 0, 0, 0 if corrupt_S is not None else None)
-
-        def one(w, pend, row, fm_c, corr_c):
-            return step(w, pend, row, hypers, fm_c, corr_c)
-
-        if afl.telemetry:
-            w_new, pend_S, m = jax.vmap(one, in_axes=in_ax)(
-                w_S, pend_S, rest, fm, corr)
-            return (w_new, pend_S), {"params": w_new, "metrics": m}
-        w_new, pend_S = jax.vmap(one, in_axes=in_ax)(w_S, pend_S, rest, fm,
-                                                     corr)
-        return (w_new, pend_S), w_new
-
-    xs = (ids_S, steps_S, store_slot_S, flush_slot_S, tau_S, flush_mask_S)
-    if corrupt_S is not None:
-        xs = xs + (corrupt_S,)
-    (w_final, _), ws = jax.lax.scan(body, (w0_S, pend0_S), xs)
-    return w_final, ws
 
 
 def _stack_to_rows(a, dtype=None):
@@ -767,10 +473,11 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
     with prof.phase("plan_build"):
         sc_steps, sc_mask, sc_lat, sc_corr = \
             simulator.scenario_grid_round_inputs(fl, rounds, grid)
-        keys = scan_engine._split_chain(key, rounds)
-        steps_S = _stack_to_rows(sc_steps)
-        up_mask_S = _stack_to_rows(sc_mask)
-        corrupt_S = None if sc_corr is None else _stack_to_rows(sc_corr)
+        xs = {"key": scan_engine._split_chain(key, rounds),
+              "steps": _stack_to_rows(sc_steps),
+              "up_mask": _stack_to_rows(sc_mask)}
+        if sc_corr is not None:
+            xs["corrupt"] = _stack_to_rows(sc_corr)
         use_so = _uses_server_opt(fl)
         so_state0_S = None
         if use_so:
@@ -779,10 +486,10 @@ def run_scenario_grid_compiled(model_cfg, fed: FederatedData,
             so_state0_S = jax.tree.map(
                 lambda x: jnp.broadcast_to(x, (S,) + x.shape), so0)
     with prof.phase("scan"):
-        w_final_S, ys = grid_scan_rounds(
-            model_cfg, fl.timeline_config(), fspec, w0_S, train, p, keys,
-            steps_S, simulator.hypers_of(fl), up_mask_S, corrupt_S,
-            sel_probs, so_state0_S, mesh=mesh)
+        w_final_S, ys = scan_engine.scan_rounds(
+            model_cfg, fl.timeline_config(), fspec, (w0_S, so_state0_S), xs,
+            simulator.hypers_of(fl), train, p, sel_probs, mesh=mesh,
+            member="grid")
         if fl.telemetry or profiler is not None:
             jax.block_until_ready(ys)
     with prof.phase("eval"):
@@ -881,21 +588,13 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
             pend0_S = bcast(async_lib.pool_init(
                 model_cfg, sync_fl, params, train, gplan.n_slots + 1))
         with prof.phase("scan"):
-            w_final_S, ws = grid_scan_deadline(
-                model_cfg, afl_t, fspec, w0_S, pend0_S, train, p,
-                tprof.to_device(gplan.keys), _stack_to_rows(gplan.ids),
-                _stack_to_rows(gplan.n_steps),
-                _stack_to_rows(gplan.arrived, jnp.float32),
-                _stack_to_rows(gplan.store_slot),
-                _stack_to_rows(gplan.due_slot),
-                _stack_to_rows(gplan.due_mask),
-                _stack_to_rows(gplan.due_tau),
-                _stack_to_rows(gplan.fast), hypers, sel_probs,
-                None if gplan.corrupt is None
-                else _stack_to_rows(gplan.corrupt), mesh=mesh,
+            w_final_S, ys = scan_engine.scan_deadline_cohort(
+                model_cfg, afl_t, fspec, (w0_S, pend0_S),
+                scan_engine.deadline_xs(gplan, put=_stack_to_rows), hypers,
+                train, p, sel_probs, mesh=mesh, member="grid",
                 always_slow=not bool(np.asarray(gplan.fast).any()))
             if afl.telemetry or profiler is not None:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks_S, n_arr_S = gplan.round_end, gplan.n_arrived
     else:
         with prof.phase("plan_build"):
@@ -911,31 +610,27 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
             pend0_S = jax.vmap(
                 lambda pend, sids, ssteps, sslots, scorr:
                 async_lib.fedbuff_seed_pool(
-                    model_cfg, afl_t, params, pend, train, sids, ssteps,
-                    sslots, hypers, scorr),
+                    model_cfg, afl_t, params, pend,
+                    simulator._client_batch(train, sids), ssteps, sslots,
+                    hypers, scorr),
                 in_axes=(0, 0, 0, 0,
                          0 if seed_corr is not None else None))(
                 bcast(pend0), tprof.to_device(gplan.seed_ids),
                 tprof.to_device(gplan.seed_steps),
                 tprof.to_device(gplan.seed_slots), seed_corr)
         with prof.phase("scan"):
-            w_final_S, ws = grid_scan_fedbuff(
-                model_cfg, afl_t, fspec, w0_S, pend0_S, train,
-                _stack_to_rows(gplan.ids), _stack_to_rows(gplan.n_steps),
-                _stack_to_rows(gplan.store_slot),
-                _stack_to_rows(gplan.flush_slot), _stack_to_rows(gplan.tau),
-                hypers, _stack_to_rows(gplan.flush_mask),
-                None if gplan.corrupt is None
-                else _stack_to_rows(gplan.corrupt), mesh=mesh)
+            w_final_S, ys = scan_engine.scan_fedbuff_cohort(
+                model_cfg, afl_t, fspec, (w0_S, pend0_S),
+                scan_engine.fedbuff_xs(gplan, put=_stack_to_rows), hypers,
+                train, mesh=mesh, member="grid")
             if afl.telemetry or profiler is not None:
-                jax.block_until_ready(ws)
+                jax.block_until_ready(ys)
         clocks_S = gplan.flush_clock
         n_arr_S = gplan.flush_mask.sum(axis=2).astype(np.int64)
 
-    params_traj = ws["params"] if afl.telemetry else ws
     with prof.phase("eval"):
         hists = scan_engine.eval_history_replay_sweep(
-            model_cfg, fspec, train, test, p, params_traj, rounds,
+            model_cfg, fspec, train, test, p, ys["params"], rounds,
             eval_every, clocks=clocks_S, n_arrived=n_arr_S,
             stale_mean=gplan.stale_mean)
     with prof.phase("collect"):
@@ -948,7 +643,7 @@ def run_async_scenario_grid_compiled(model_cfg, fed: FederatedData, afl,
                 # network/pool series are plan-derived and per-cell: each
                 # cell's solo plan yields exactly its solo series
                 metrics = {k: tprof.fetch(v[:, i])
-                           for k, v in ws["metrics"].items()}
+                           for k, v in ys["metrics"].items()}
                 if afl.mode == "deadline":
                     metrics.update(tmetrics.deadline_network_series(
                         D, afl, plan_i))

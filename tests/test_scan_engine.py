@@ -1,13 +1,18 @@
 """Scan-compiled engine: the whole-run lax.scan execution path must
 reproduce the python-loop engine bit-for-bit on a fixed seed — history,
 wall-clock, and final parameters — and reject configs it cannot compile."""
+import re
+
 import jax
 import numpy as np
 import pytest
 
+from repro import fed as fed_api
 from repro.configs.paper_models import MCLR
 from repro.data.federated import stack_devices
 from repro.data.synthetic import synthetic_alpha_beta
+from repro.fed import scan_engine
+from repro.fed.async_engine import AsyncFLConfig
 from repro.fed.scan_engine import draw_round_inputs, run_federated_compiled
 from repro.fed.simulator import FLConfig, run_federated
 from repro.sysmodel import heterogeneous_fleet, uniform_fleet
@@ -353,3 +358,47 @@ class TestHistoryReplay:
             assert hist == eval_history_replay(
                 MCLR, spec, train, test, p, traj[:, i], rounds, eval_every,
                 clocks=clocks[i], stale_mean=stale)
+
+
+# the smallest fed.run that reaches each round-program entry: resident
+# sync, lazy deadline, lazy fedbuff
+def _lazy_run(afl):
+    from repro.data.federated import LazyFederatedData
+    from repro.sysmodel import PopulationSpec
+    return lambda _: fed_api.run(
+        MCLR, LazyFederatedData(n_devices=24, seed=3), afl, 2,
+        fleet=PopulationSpec(n_devices=24, seed=7, straggler_frac=0.4,
+                             straggler_slowdown=20.0))
+
+
+_ENTRY_RUNS = {
+    "scan_rounds": lambda fed_data: fed_api.run(
+        MCLR, fed_data, FLConfig(algo="folb", n_selected=3, seed=0), 2),
+    "scan_deadline_cohort": _lazy_run(AsyncFLConfig(
+        mode="deadline", algo="folb", n_selected=4, deadline=40.0,
+        sampler="indexed")),
+    "scan_fedbuff_cohort": _lazy_run(AsyncFLConfig(
+        mode="fedbuff", algo="folb", buffer_size=3, concurrency=5,
+        sampler="indexed")),
+}
+
+
+class TestRoundProgramNames:
+    @pytest.mark.parametrize("entry", sorted(_ENTRY_RUNS))
+    def test_entry_lowers_to_its_trace_name(self, fed_data, monkeypatch,
+                                            entry):
+        """A device trace names each round program ``jit_<entry>``, the
+        names the chip benchmark reads its round-program and aggregation-
+        kernel times by; a run of each kind lowers exactly one."""
+        real = getattr(scan_engine, entry)
+        lowered = []
+
+        def spy(*args, **kwargs):
+            lowered.append(real.lower(*args, **kwargs))
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(scan_engine, entry, spy)
+        _ENTRY_RUNS[entry](fed_data)
+        assert len(lowered) == 1
+        name = re.search(r"module @(\w+)", lowered[0].as_text()).group(1)
+        assert name == f"jit_{entry}"
