@@ -167,54 +167,71 @@ class LazyFederatedData:
         return SizesView(self)
 
     # --------------------------------------------------------- synthesis
-    def _device_labels(self, rng: np.random.Generator, did: int,
-                       n_train: int):
-        C = self.n_classes
-        if self.partition == "dirichlet":
-            pi = partition.dirichlet_proportions(rng, C, self.alpha)
-            y_tr = rng.choice(C, size=n_train, p=pi)
-            y_te = rng.choice(C, size=self.test_size, p=pi)
-        elif self.partition == "shard":
-            owned = partition.shard_labels(
-                self.seed, np.asarray([did]), self.n_devices,
-                self.shards_per_device, C)[0]
-            y_tr = owned[np.arange(n_train) % len(owned)]
-            y_te = owned[np.arange(self.test_size) % len(owned)]
-        else:  # iid
-            y_tr = rng.integers(0, C, size=n_train)
-            y_te = rng.integers(0, C, size=self.test_size)
-        return y_tr.astype(np.int32), y_te.astype(np.int32)
-
     def gather(self, ids) -> Dict[str, np.ndarray]:
         """Cohort batch for ``ids`` (any shape): dict with
         x (..., M, F) f32 / y (..., M) i32 / mask (..., M) f32 and the
         test_* equivalents, rows bit-for-bit equal to the materialized
         stack's rows.  Cost O(#unique ids · M); duplicate ids (a device
-        selected in many rounds) are synthesized once."""
+        selected in many rounds) are synthesized once.
+
+        A device of train size n draws from its own stream, in order:
+        its class proportions (``dirichlet`` only), its n + T labels
+        (``iid``: bounded integers; ``dirichlet``: uniforms through the
+        CDF of the proportions, ``Generator.choice(C, p=pi)`` written
+        out; ``shard``: no draw, the labels cycle through its owned
+        shards), then (n + T, F) standard normals: n train rows, then T
+        test rows.  Each of these is one call where the train and test
+        parts could be two: a uniform or a normal takes whole 64-bit
+        words of the bit generator, and the half word a bounded integer
+        leaves over stays in the bit generator between calls, so one
+        draw of n + T values is the same bits as a draw of n and then a
+        draw of T.  The rest is vectorized over the cohort."""
         ids = np.asarray(ids, dtype=np.int64)
         flat = ids.reshape(-1)
         uniq, inv = np.unique(flat, return_inverse=True)
-        M, T, F = self.max_size, self.test_size, self.n_features
-        proto = _class_prototypes(self.seed, self.n_classes, F,
-                                  self.proto_scale)
+        M, T, F, C = (self.max_size, self.test_size, self.n_features,
+                      self.n_classes)
+        proto = _class_prototypes(self.seed, C, F, self.proto_scale)
         sizes = self.gather_sizes(uniq)
-        U = len(uniq)
-        x = np.zeros((U, M, F), np.float32)
-        y = np.zeros((U, M), np.int32)
-        mask = np.zeros((U, M), np.float32)
-        tx = np.zeros((U, T, F), np.float32)
-        ty = np.zeros((U, T), np.int32)
-        for i, did in enumerate(uniq):
-            n = int(sizes[i])
+        U, W = len(uniq), M + T
+        # device i's draws fill [i, :n_i + T]: train rows, then test rows
+        lab = np.zeros((U, W), np.int64)
+        u = np.zeros((U, W))
+        pi = np.ones((U, C))
+        z = np.zeros((U, W, F), np.float32)
+        for i, (did, n) in enumerate(zip(uniq.tolist(), sizes.tolist())):
             rng = partition.device_rng(self.seed, did)
-            y_tr, y_te = self._device_labels(rng, int(did), n)
-            x[i, :n] = proto[y_tr] + self.noise * rng.standard_normal(
-                (n, F)).astype(np.float32)
-            y[i, :n] = y_tr
-            mask[i, :n] = 1.0
-            tx[i] = proto[y_te] + self.noise * rng.standard_normal(
-                (T, F)).astype(np.float32)
-            ty[i] = y_te
+            k = n + T
+            if self.partition == "dirichlet":
+                pi[i] = partition.dirichlet_proportions(rng, C, self.alpha)
+                rng.random(out=u[i, :k])
+            elif self.partition == "iid":
+                lab[i, :k] = rng.integers(0, C, size=k)
+            z[i, :k] = rng.standard_normal((k, F))
+        pos = np.arange(W)
+        if self.partition == "dirichlet":
+            cdf = pi.cumsum(axis=1)
+            cdf /= cdf[:, -1:]
+            # searchsorted(cdf, u, side="right"): the CDF entries <= u
+            for c in range(C):
+                lab += cdf[:, c, None] <= u
+        elif self.partition == "shard":
+            owned = partition.shard_labels(self.seed, uniq, self.n_devices,
+                                           self.shards_per_device, C)
+            rank = np.where(pos < sizes[:, None], pos, pos - sizes[:, None])
+            lab = np.take_along_axis(owned, rank % owned.shape[1], axis=1)
+        lab = lab.astype(np.int32)
+        xs = self.noise * z
+        xs += np.take(proto, lab, axis=0)
+        xs = xs.astype(np.float32, copy=False)
+        rows = np.arange(U)[:, None]
+        test_pos = sizes[:, None] + pos[:T]
+        tx, ty = xs[rows, test_pos], lab[rows, test_pos]
+        valid = pos[:M] < sizes[:, None]
+        x, y = xs[:, :M], lab[:, :M]
+        x[~valid] = 0.0
+        y[~valid] = 0
+        mask = valid.astype(np.float32)
         lead = ids.shape
         out = {"x": x[inv], "y": y[inv], "mask": mask[inv],
                "test_x": tx[inv], "test_y": ty[inv],

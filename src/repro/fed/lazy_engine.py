@@ -106,6 +106,8 @@ def _round_batches(data: LazyFederatedData, ids: np.ndarray):
     (R, K, M, ...) jnp arrays."""
     with tprof.span("gather/synthesize"):
         d = data.gather(ids)
+        if tprof.is_recording():
+            tprof.count("synth_devices", len(np.unique(ids)))
     with tprof.span("gather/to_device"):
         return _to_device(d)
 

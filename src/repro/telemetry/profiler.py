@@ -37,7 +37,9 @@ and three counters, counted by the helpers the engines move data through:
 ``d2h_fetches`` (``fetch`` / ``fetch_float``: one per device array read
 to the host), ``h2d_transfers`` (``to_device``: one per device array made
 from a host array) and ``h2d_bytes`` (``to_device``: the ``nbytes`` of
-each such device array).
+each such device array); and one the lazy engines count themselves,
+``synth_devices`` under ``gather/synthesize`` (the unique devices a
+cohort gather synthesized).
 
 `PhaseProfiler` breaks one run's host time into named contiguous phases
 (`setup` / `plan_build` / `scan` / `eval` for the compiled engines;

@@ -4,6 +4,7 @@ partitioners' determinism and non-IID shape, fleet-construction speed at
 ``(PopulationSpec, LazyFederatedData)`` is bit-for-bit the materialized
 run of the same config at small N, for sync / deadline / fedbuff and
 both aggregation dtypes."""
+import hashlib
 import subprocess
 import sys
 import time
@@ -15,7 +16,7 @@ import pytest
 from repro import fed
 from repro.configs.paper_models import MCLR
 from repro.data import partition
-from repro.data.federated import LazyFederatedData
+from repro.data.federated import LazyFederatedData, _class_prototypes
 from repro.fed.async_engine import AsyncFLConfig, build_plan, plan_digest
 from repro.fed.simulator import FLConfig
 from repro.models import small
@@ -221,6 +222,116 @@ class TestPartitioners:
         assert len(np.unique(ids)) == 10
         full = LazyFederatedData(n_devices=50, seed=3)
         assert np.array_equal(full.eval_ids(), np.arange(50))
+
+
+# --------------------------------------------------------------------------
+# gather == the per-device definition of a device's stream
+# --------------------------------------------------------------------------
+
+def _gather_per_device(data, ids):
+    """The lazy cohort by its definition: one device at a time from its
+    own stream, labels by ``rng.choice`` / ``rng.integers`` / its owned
+    shards, train then test, and one normal draw for the train rows and
+    another for the test rows."""
+    ids = np.asarray(ids, dtype=np.int64)
+    M, T, F, C = data.max_size, data.test_size, data.n_features, \
+        data.n_classes
+    proto = _class_prototypes(data.seed, C, F, data.proto_scale)
+    out = {"x": np.zeros(ids.shape + (M, F), np.float32),
+           "y": np.zeros(ids.shape + (M,), np.int32),
+           "mask": np.zeros(ids.shape + (M,), np.float32),
+           "test_x": np.zeros(ids.shape + (T, F), np.float32),
+           "test_y": np.zeros(ids.shape + (T,), np.int32),
+           "test_mask": np.ones(ids.shape + (T,), np.float32)}
+    for at in np.ndindex(ids.shape):
+        did = int(ids[at])
+        n = int(data.gather_sizes(np.asarray([did]))[0])
+        rng = partition.device_rng(data.seed, did)
+        if data.partition == "dirichlet":
+            pi = rng.dirichlet(np.full(C, float(data.alpha)))
+            y_tr = rng.choice(C, size=n, p=pi)
+            y_te = rng.choice(C, size=T, p=pi)
+        elif data.partition == "shard":
+            owned = partition.shard_labels(
+                data.seed, np.asarray([did]), data.n_devices,
+                data.shards_per_device, C)[0]
+            y_tr = owned[np.arange(n) % len(owned)]
+            y_te = owned[np.arange(T) % len(owned)]
+        else:
+            y_tr = rng.integers(0, C, size=n)
+            y_te = rng.integers(0, C, size=T)
+        out["x"][at][:n] = proto[y_tr] + data.noise * rng.standard_normal(
+            (n, F)).astype(np.float32)
+        out["y"][at][:n] = y_tr
+        out["mask"][at][:n] = 1.0
+        out["test_x"][at][:] = proto[y_te] + data.noise * \
+            rng.standard_normal((T, F)).astype(np.float32)
+        out["test_y"][at][:] = y_te
+    return out
+
+
+@pytest.fixture(params=[
+    pytest.param({}, id="default"),
+    # n below the shard count, 3 test rows, 4 classes
+    pytest.param(dict(min_size=1, max_size=4, test_size=3,
+                      shards_per_device=3, n_classes=4, alpha=0.1),
+                 id="small")])
+def stream_shape(request):
+    return request.param
+
+
+@pytest.mark.parametrize("part", ["dirichlet", "shard", "iid"])
+class TestGatherStream:
+    """``gather`` is bit for bit the per-device definition of a device's
+    stream (``_gather_per_device``) for every partition, whatever the
+    shape of the ids and the sizes of the devices."""
+
+    N = 10**6
+
+    def _data(self, part, shape):
+        return LazyFederatedData(n_devices=self.N, seed=3, partition=part,
+                                 **shape)
+
+    def _check(self, data, ids):
+        got, want = data.gather(ids), _gather_per_device(data, ids)
+        assert set(got) == set(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype, k
+            assert got[k].shape == want[k].shape, k
+            assert np.array_equal(got[k], want[k]), k
+
+    def test_duplicate_ids(self, part, stream_shape):
+        self._check(self._data(part, stream_shape),
+                    [5, 900_001, 5, 42, 900_001, 5])
+
+    def test_round_by_cohort_ids(self, part, stream_shape):
+        ids = np.random.default_rng(1).integers(0, self.N, (6, 5))
+        ids[3, 2] = ids[0, 0]
+        self._check(self._data(part, stream_shape), ids)
+
+    def test_single_id(self, part, stream_shape):
+        self._check(self._data(part, stream_shape), [self.N - 1])
+
+    def test_devices_at_min_and_max_size(self, part, stream_shape):
+        data = self._data(part, stream_shape)
+        cand = np.arange(2000)
+        sizes = data.gather_sizes(cand)
+        ids = [cand[sizes == data.min_size][0],
+               cand[sizes == data.max_size][0]]
+        self._check(data, ids)
+
+
+def test_gather_digest_is_pinned():
+    """The lazy cohort's bits for seed 3, alpha 0.5: a change to any
+    device's stream (the order or the kind of its draws) changes them."""
+    g = LazyFederatedData(n_devices=10**6, seed=3, alpha=0.5).gather(
+        [0, 7, 63, 10**6 - 1])
+    h = hashlib.sha256()
+    for k in sorted(g):
+        h.update(f"{k}:{g[k].dtype.str}:{g[k].shape}".encode())
+        h.update(np.ascontiguousarray(g[k]).tobytes())
+    assert h.hexdigest() == \
+        "e6847d9c3c980871317ca757401d314675dcc66722e21eef30744c3b04021ef3"
 
 
 # --------------------------------------------------------------------------

@@ -673,6 +673,20 @@ class TestRecorder:
                 "eval/cohort", "eval/device", "eval/fetch"} == set(
                     snap["spans"])
 
+    def test_synth_devices_counts_unique_cohort_ids(self):
+        """``gather/synthesize`` counts the unique devices it synthesized;
+        the eval cohort, gathered under ``eval/cohort``, is not counted."""
+        tprof.reset()
+        with tprof.recording():
+            res = fed.run(MCLR, _LAZY_DATA, _lazy_cfg(), ROUNDS,
+                          fleet=_LAZY_FLEET)
+        snap = tprof.snapshot()
+        tprof.reset()
+        assert snap["spans"]["gather/synthesize"]["count"] == 1
+        assert snap["counters"]["gather/synthesize"]["synth_devices"] == len(
+            np.unique(res.ids))
+        assert "synth_devices" not in snap["counters"].get("eval/cohort", {})
+
 
 # --------------------------------------------------------------------------
 # 5. network byte series consistent with the event plans
